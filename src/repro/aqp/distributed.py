@@ -50,7 +50,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.state import MomentState
@@ -225,11 +224,11 @@ def make_sharded_fold(mesh: Mesh, dp_axes: Sequence[str], num_groups: int,
         return out, jax.lax.psum(h.hist, dp)
 
     rep_state = jax.tree.map(lambda _: P(), MomentState(0, 0, 0, 0, 0))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         round_fn, mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=(rep_state if not with_hist else (rep_state, P())),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 

@@ -247,9 +247,10 @@ class EngineConfig:
             every K rounds on a deterministic replicated round counter —
             between merges there is zero cross-shard communication of
             any kind. Termination is merge-then-confirm (it always
-            reads fully-merged stats) and is observed at most K-1
-            rounds after the round that would have stopped the K=1
-            loop. Between merges each shard accumulates its raw
+            reads fully-merged stats): never earlier than the K=1
+            loop, but possibly more than K-1 rounds later, since the
+            intervals are intersected only at merges and the K=1 loop
+            may stop on a look the cadence skips. Between merges each shard accumulates its raw
             additive fold delta locally and the reported intervals stay
             frozen at their last merged values — stale by at most K
             rounds but still anytime-valid (the ``sync_every`` trick,

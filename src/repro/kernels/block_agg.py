@@ -9,21 +9,29 @@ matmuls on the MXU** (DESIGN.md §3):
     dsum[g]  = sum_r (v_r - c) * 1[gid_r == g] * mask_r
     dsq[g]   = sum_r (v_r - c)^2 * 1[gid_r == g] * mask_r
 
-computed as one ``(3, R) @ (R, Gt)`` MXU matmul per (row-tile, group-tile),
-plus VPU min/max trees for the RangeTrim extremes.  ``c`` is a fixed
-centering constant (the catalog midpoint) so f32 accumulation does not
-cancel; the exact shifted-moment identity recovers Welford ``(mean, m2)``
-downstream (``ops.grouped_moments``).
+computed as one ``(8, R) x (Gt, R)^T`` MXU matmul per (row-tile,
+group-tile) (rows 0-2 carry ones, ``v - c`` and ``(v - c)^2``; the rest
+are zero padding to a full sublane tile), plus lane-axis min/max
+reductions for the RangeTrim extremes.  ``c`` is a fixed centering
+constant (the catalog midpoint) so f32 accumulation does not cancel; the
+exact shifted-moment identity recovers Welford ``(mean, m2)`` downstream
+(``ops.grouped_moments``).
+
+Tile layout (what Mosaic accepts): every row tile is loaded as a
+``(1, R)`` vector with the rows on the lane axis, and the group one-hot
+is built *transposed*, ``(Gt, R)``, from a sublane iota.  Nothing is
+ever reshaped from lanes into a column, and both matmuls contract over
+the last (lane) dims.  The matmuls run at ``Precision.HIGHEST`` so the
+f32 moment sums are not demoted to one bf16 pass.
 
 Grid = (group_tiles, row_tiles) with row_tiles minor: TPU grids execute
 sequentially, so each group tile's output block is revisited across row
 tiles and accumulated in place (`@pl.when(r == 0)` initializes).
 
-VMEM budget per program (defaults ROW_TILE=2048, GROUP_TILE=256):
-  values/gids/mask tiles       3 * 2048 * 4 B   =  24 KiB
-  one-hot                      2048 * 256 * 4 B =   2 MiB
-  rows + outputs               ~40 KiB
-comfortably under the ~16 MiB/core VMEM of TPU v5e.
+VMEM at the defaults (ROW_TILE=2048, GROUP_TILE=256): the one-hot is
+256 * 2048 * 4 B = 2 MiB of f32, and a v5e compile accepts the kernel
+with 1.56 MiB of scoped VMEM (``docs/kernels.md``) — well under the
+16 MiB scoped default of TPU v5e.
 """
 
 from __future__ import annotations
@@ -37,29 +45,43 @@ from jax.experimental import pallas as pl
 ROW_TILE = 2048   # rows per grid step (must be a multiple of 128)
 GROUP_TILE = 256  # groups per grid step (must be a multiple of 128)
 
+# contract the lane (row) axis of both operands: (M, R) x (N, R) -> (M, N)
+NT_DIMS = (((1,), (1,)), ((), ()))
+
+
+def block_index(*idx):
+    """An index map's block indices as int32. Under ``jax_enable_x64``
+    (the device round loop's mode) a literal ``0`` traces as int64, and
+    Mosaic refuses an index map that returns int64."""
+    return tuple(jnp.asarray(i, jnp.int32) for i in idx)
+
 
 def tile_moments(v, gid, m, center, gbase, gt):
     """Per-tile moment math shared by this kernel and the fused scan
     superkernel (:mod:`repro.kernels.fused_scan`).
 
-    Inputs are flat (R,) tile vectors; returns the MXU partial
-    ``(3, gt)`` = (count, dsum, dsq), the VPU min/max partials
-    ``(1, gt)``, and the masked group one-hot ``(R, gt)`` so callers can
-    reuse it (the fused kernel feeds it to the histogram matmul).
+    Inputs are ``(1, R)`` tile rows (rows on lanes); returns the MXU
+    partial ``(3, gt)`` = (count, dsum, dsq), the min/max partials
+    ``(gt, 1)``, and the masked transposed group one-hot ``(gt, R)`` so
+    callers can reuse it (the fused kernel feeds it to the histogram
+    matmul).
     """
-    group_ids = gbase + jax.lax.broadcasted_iota(jnp.int32, (1, gt), 1)
-    onehot = (gid[:, None] == group_ids).astype(jnp.float32) * m[:, None]
+    rt = v.shape[1]
+    group_ids = gbase + jax.lax.broadcasted_iota(jnp.int32, (gt, rt), 0)
+    onehot = (gid == group_ids).astype(jnp.float32) * m         # (Gt, R)
 
     dv = v - center
-    rows = jnp.stack([jnp.ones_like(v), dv, dv * dv])          # (3, R)
-    partial = jax.lax.dot(rows, onehot,
-                          preferred_element_type=jnp.float32)  # (3, Gt) MXU
+    row = jax.lax.broadcasted_iota(jnp.int32, (8, rt), 0)
+    rows = jnp.where(row == 0, 1.0,
+                     jnp.where(row == 1, dv,
+                               jnp.where(row == 2, dv * dv, 0.0)))  # (8, R)
+    partial = jax.lax.dot_general(
+        rows, onehot, NT_DIMS, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)[:3]                 # (3, Gt) MXU
 
     sel = onehot > 0.0
-    vmin_p = jnp.min(jnp.where(sel, v[:, None], jnp.inf), axis=0,
-                     keepdims=True)
-    vmax_p = jnp.max(jnp.where(sel, v[:, None], -jnp.inf), axis=0,
-                     keepdims=True)
+    vmin_p = jnp.min(jnp.where(sel, v, jnp.inf), axis=1, keepdims=True)
+    vmax_p = jnp.max(jnp.where(sel, v, -jnp.inf), axis=1, keepdims=True)
     return partial, vmin_p, vmax_p, onehot
 
 
@@ -70,11 +92,9 @@ def _kernel(center_ref, values_ref, gids_ref, mask_ref,
     gt = sums_ref.shape[1]
 
     c = center_ref[0, 0]
-    v = values_ref[...].reshape(-1)
-    gid = gids_ref[...].reshape(-1)
-    m = mask_ref[...].reshape(-1).astype(jnp.float32)
-
-    partial, vmin_p, vmax_p, _ = tile_moments(v, gid, m, c, g * gt, gt)
+    m = mask_ref[...].astype(jnp.float32)
+    partial, vmin_p, vmax_p, _ = tile_moments(
+        values_ref[...], gids_ref[...], m, c, g * gt, gt)
 
     @pl.when(r == 0)
     def _init():
@@ -99,32 +119,26 @@ def block_agg(values: jax.Array, gids: jax.Array, mask: jax.Array,
     """
     n = values.shape[0]
     assert n % row_tile == 0 and num_groups % group_tile == 0
-    lanes = 128
-    v2 = values.astype(jnp.float32).reshape(n // lanes, lanes)
-    g2 = gids.astype(jnp.int32).reshape(n // lanes, lanes)
-    m2 = mask.astype(jnp.float32).reshape(n // lanes, lanes)
-    rt = row_tile // lanes
+    v2 = values.astype(jnp.float32).reshape(1, n)
+    g2 = gids.astype(jnp.int32).reshape(1, n)
+    m2 = mask.astype(jnp.float32).reshape(1, n)
     grid = (num_groups // group_tile, n // row_tile)
     c = jnp.asarray(center, jnp.float32).reshape(1, 1)
+    row_spec = pl.BlockSpec((1, row_tile), lambda g, r: block_index(0, r))
+    col_spec = pl.BlockSpec((group_tile, 1), lambda g, r: block_index(g, 0))
 
-    return pl.pallas_call(
+    sums, vmin, vmax = pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda g, r: (0, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, r: (r, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, r: (r, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, r: (r, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((3, group_tile), lambda g, r: (0, g)),
-            pl.BlockSpec((1, group_tile), lambda g, r: (0, g)),
-            pl.BlockSpec((1, group_tile), lambda g, r: (0, g)),
-        ],
+        in_specs=[pl.BlockSpec((1, 1), lambda g, r: block_index(0, 0)),
+                  row_spec, row_spec, row_spec],
+        out_specs=[pl.BlockSpec((3, group_tile), lambda g, r: block_index(0, g)),
+                   col_spec, col_spec],
         out_shape=[
             jax.ShapeDtypeStruct((3, num_groups), jnp.float32),
-            jax.ShapeDtypeStruct((1, num_groups), jnp.float32),
-            jax.ShapeDtypeStruct((1, num_groups), jnp.float32),
+            jax.ShapeDtypeStruct((num_groups, 1), jnp.float32),
+            jax.ShapeDtypeStruct((num_groups, 1), jnp.float32),
         ],
         interpret=interpret,
     )(c, v2, g2, m2)
+    return sums, vmin.reshape(1, num_groups), vmax.reshape(1, num_groups)

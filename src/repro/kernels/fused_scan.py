@@ -82,9 +82,9 @@ Backends (same selector as :mod:`repro.kernels.ops`):
   * ``impl='interpret'`` — the same superkernel under the Pallas
     interpreter (CPU-testable).
 
-VMEM per program at the defaults (ROW_TILE=1024, GROUP_TILE=128,
-nbins<=2048): group one-hot 0.5 MiB + bin one-hot <= 8 MiB + hist output
-block <= 1 MiB — under the ~16 MiB/core budget of TPU v5e.
+VMEM at the defaults (ROW_TILE=1024, GROUP_TILE=128): a v5e compile of
+:func:`fused_fold` accepts 3.6-4.1 MiB of scoped VMEM at 1024 bins and
+4.6 MiB at 2048 (``docs/kernels.md``), under the 16 MiB scoped default.
 """
 
 from __future__ import annotations
@@ -95,7 +95,6 @@ from typing import Callable, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.state import MomentState, merge_moments
@@ -110,8 +109,9 @@ GROUP_TILE = 128  # groups per grid step (multiple of 128)
 
 def _fold_kernel(scale_ref, values_ref, gids_ref, mask_ref,
                  sums_ref, vmin_ref, vmax_ref, hist_ref):
-    """Moments + histogram in one pass: the group one-hot is built once
-    per (group, row) tile and feeds both MXU matmuls."""
+    """Moments + histogram in one pass: the transposed group one-hot
+    ``(Gt, R)`` is built once per (group, row) tile and feeds both MXU
+    matmuls."""
     r = pl.program_id(1)
     g = pl.program_id(0)
     gt = sums_ref.shape[1]
@@ -122,12 +122,10 @@ def _fold_kernel(scale_ref, values_ref, gids_ref, mask_ref,
     inv_width = scale_ref[0, 2]
     nbins_data = scale_ref[0, 3]
 
-    v = values_ref[...].reshape(-1)
-    gid = gids_ref[...].reshape(-1)
-    m = mask_ref[...].reshape(-1).astype(jnp.float32)
-
+    v = values_ref[...]
+    m = mask_ref[...].astype(jnp.float32)
     partial, vmin_p, vmax_p, onehot_g = _block_agg.tile_moments(
-        v, gid, m, c, g * gt, gt)
+        v, gids_ref[...], m, c, g * gt, gt)
     hpartial = _hist.tile_hist(v, onehot_g, a, inv_width, nbins_data, 0, kt)
 
     @pl.when(r == 0)
@@ -156,46 +154,46 @@ def fused_fold(values: jax.Array, gids: jax.Array, mask: jax.Array,
     Returns ``(sums (3, G), vmin (1, G), vmax (1, G), hist (G, nbins))``.
     Grid = (group_tiles, row_tiles), row minor: each (group, bin) output
     block is revisited across row tiles and accumulated in place while
-    the pipeline prefetches the next row tile (double buffering).
+    the pipeline prefetches the next row tile (double buffering). Row
+    tiles are ``(1, row_tile)`` lane vectors (the layout of
+    :mod:`repro.kernels.block_agg`).
     """
     n = values.shape[0]
     assert n % row_tile == 0 and num_groups % group_tile == 0
     assert nbins % 128 == 0
-    lanes = 128
-    v2 = values.astype(jnp.float32).reshape(n // lanes, lanes)
-    g2 = gids.astype(jnp.int32).reshape(n // lanes, lanes)
-    m2 = mask.astype(jnp.float32).reshape(n // lanes, lanes)
-    rt = row_tile // lanes
+    v2 = values.astype(jnp.float32).reshape(1, n)
+    g2 = gids.astype(jnp.int32).reshape(1, n)
+    m2 = mask.astype(jnp.float32).reshape(1, n)
     grid = (num_groups // group_tile, n // row_tile)
     inv_width = float(nbins) / max(float(b) - float(a), 1e-30)
     scale = jnp.stack([jnp.asarray(center, jnp.float32),
                        jnp.asarray(a, jnp.float32),
                        jnp.asarray(inv_width, jnp.float32),
                        jnp.asarray(float(nbins), jnp.float32)]).reshape(1, 4)
+    bi = _block_agg.block_index
+    row_spec = pl.BlockSpec((1, row_tile), lambda g, r: bi(0, r))
+    col_spec = pl.BlockSpec((group_tile, 1), lambda g, r: bi(g, 0))
 
-    return pl.pallas_call(
+    sums, vmin, vmax, hist = pl.pallas_call(
         _fold_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda g, r: (0, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, r: (r, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, r: (r, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, r: (r, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, 4), lambda g, r: bi(0, 0)),
+                  row_spec, row_spec, row_spec],
         out_specs=[
-            pl.BlockSpec((3, group_tile), lambda g, r: (0, g)),
-            pl.BlockSpec((1, group_tile), lambda g, r: (0, g)),
-            pl.BlockSpec((1, group_tile), lambda g, r: (0, g)),
-            pl.BlockSpec((group_tile, nbins), lambda g, r: (g, 0)),
+            pl.BlockSpec((3, group_tile), lambda g, r: bi(0, g)),
+            col_spec, col_spec,
+            pl.BlockSpec((group_tile, nbins), lambda g, r: bi(g, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((3, num_groups), jnp.float32),
-            jax.ShapeDtypeStruct((1, num_groups), jnp.float32),
-            jax.ShapeDtypeStruct((1, num_groups), jnp.float32),
+            jax.ShapeDtypeStruct((num_groups, 1), jnp.float32),
+            jax.ShapeDtypeStruct((num_groups, 1), jnp.float32),
             jax.ShapeDtypeStruct((num_groups, nbins), jnp.float32),
         ],
         interpret=interpret,
     )(scale, v2, g2, m2)
+    return (sums, vmin.reshape(1, num_groups), vmax.reshape(1, num_groups),
+            hist)
 
 
 def _pad_groups(x, mult):
@@ -286,6 +284,15 @@ def _fold(v, g, m, center, a, b, num_groups, nbins, use_hist, impl,
         if hist is not None:
             hist = jax.lax.psum(hist, shard_axes)
     return kops.moments_from_sums(sums, vmin, vmax, center), hist
+
+
+def _pmin_pmax_f64(vmin, vmax, axes):
+    """Cross-shard min / max of the f64 pending extremes. XLA:TPU lowers
+    only the sum all-reduce for f64, so every shard gathers all shards'
+    values and reduces them itself: exact, and the same on every
+    shard."""
+    return (jax.lax.all_gather(vmin, axes).min(axis=0),
+            jax.lax.all_gather(vmax, axes).max(axis=0))
 
 
 def _budget_select(flags: jax.Array, pos: jax.Array, nb, window: int,
@@ -650,9 +657,13 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
     pending, decided from the replicated ``pend_rounds`` counter. No
     per-round hint, no scalar ``pmax`` — between merges there is zero
     cross-shard communication. Termination is merge-then-confirm
-    (decisions only ever read fully-merged stats) and is observed at
-    most K-1 rounds after the round that would have stopped the K=1
-    loop. Every dispatch flushes its pending delta on exit, so host
+    (decisions only ever read fully-merged stats). It never comes
+    before the K=1 loop's, but can come more than K-1 rounds after it:
+    the intervals are intersected only at merges, a subset of the K=1
+    looks, and a later look's interval need not be narrower than an
+    earlier one (RangeTrim's lower bound on a few rows can sit above
+    its lower bound on more), so the K=1 loop may stop on a look the
+    cadence skips. Every dispatch flushes its pending delta on exit, so host
     syncs, ``on_sync`` snapshots and termination always observe
     fully-merged state. With ``merge_every=1`` (default) this path is
     not even traced — the per-round-merge loop above survives bitwise
@@ -741,8 +752,7 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
         once (the schedule stays a subset of the K=1 one and the union
         bound over ``delta`` holds)."""
         sums = jax.lax.psum(c.pend_sums, shard.axes)
-        vmin = jax.lax.pmin(c.pend_vmin, shard.axes)
-        vmax = jax.lax.pmax(c.pend_vmax, shard.axes)
+        vmin, vmax = _pmin_pmax_f64(c.pend_vmin, c.pend_vmax, shard.axes)
         dstate = kops.moments_from_sums(sums, vmin, vmax, center)
         state = merge_moments(c.state, dstate)
         hist = (c.hist + jax.lax.psum(c.pend_hist, shard.axes)
@@ -873,13 +883,13 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
         values=data, gids=data, mask=data, words=rep, order_pad=rep,
         static_ok=rep, presence=rep, presence_total=rep, cum_rows=rep)
     carry_spec = _query_carry_spec(use_hist, cadence)
-    # check_rep=False: replication of the carry holds by construction
+    # check_vma=False: replication of the carry holds by construction
     # (replicated inputs -> replicated selection/accounting; the fold
     # delta is re-replicated by its psum) but the checker cannot see
     # through while_loop + axis_index.
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         chunk_body, mesh=shard.mesh, in_specs=(bufs_spec, carry_spec),
-        out_specs=carry_spec, check_rep=False))
+        out_specs=carry_spec, check_vma=False))
 
 
 class SlotSpec(NamedTuple):
@@ -1294,8 +1304,8 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         for s, spec in enumerate(slot_specs):
             sc = c.slots[s]
             sums = jax.lax.psum(sc.pend_sums, shard.axes)
-            vmin = jax.lax.pmin(sc.pend_vmin, shard.axes)
-            vmax = jax.lax.pmax(sc.pend_vmax, shard.axes)
+            vmin, vmax = _pmin_pmax_f64(sc.pend_vmin, sc.pend_vmax,
+                                        shard.axes)
             dstate = kops.moments_from_sums(sums, vmin, vmax,
                                             spec.center)
             state = merge_moments(sc.state, dstate)
@@ -1453,8 +1463,8 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
     carry_spec = _pass_carry_spec(slot_specs,
                                   [len(fns) for fns in refresh_fns],
                                   cadence)
-    # check_rep=False: see build_query_loop — carry replication holds by
+    # check_vma=False: see build_query_loop — carry replication holds by
     # construction but is opaque to the checker.
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         chunk_body, mesh=shard.mesh, in_specs=(bufs_spec, carry_spec),
-        out_specs=carry_spec, check_rep=False))
+        out_specs=carry_spec, check_vma=False))

@@ -2,15 +2,19 @@
 
 hist[g, k] = sum_r mask_r * 1[gid_r == g] * 1[bin(v_r) == k]
 
-Reformulated for the MXU as a product of two one-hots per tile:
+Reformulated for the MXU as a product of two one-hots per tile, both
+built transposed with the rows on the lane axis (the layout Mosaic
+accepts; see :mod:`repro.kernels.block_agg`):
 
-    hist_tile = onehot_groups.T @ onehot_bins     # (Gt, R) @ (R, Kt)
+    hist_tile = onehot_groups @ onehot_bins.T     # (Gt, R) x (Kt, R)^T
 
 Grid = (group_tiles, bin_tiles, row_tiles), row minor; the (g, k) output
 block is revisited across row tiles and accumulated in place.
 
 VMEM per program (ROW_TILE=1024, GROUP_TILE=128, BIN_TILE=512):
-  onehot_bins 1024*512*4 = 2 MiB, onehot_groups 1024*128*4 = 0.5 MiB.
+  onehot_bins 512*1024*4 = 2 MiB, onehot_groups 128*1024*4 = 0.5 MiB;
+  a v5e compile accepts the kernel with 2.8 MiB of scoped VMEM
+  (``docs/kernels.md``).
 """
 
 from __future__ import annotations
@@ -21,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.block_agg import NT_DIMS, block_index
+
 ROW_TILE = 1024
 GROUP_TILE = 128
 BIN_TILE = 512
@@ -30,16 +36,19 @@ def tile_hist(v, onehot_g, a, inv_width, nbins, kbase, kt):
     """Per-tile histogram matmul shared by this kernel and the fused scan
     superkernel.
 
-    ``onehot_g`` is the masked (R, Gt) group one-hot (the same matrix the
-    moment matmul consumes, so the fused kernel builds it once); returns
-    the (Gt, kt) partial for bin tile ``[kbase, kbase + kt)``.
+    ``v`` is the ``(1, R)`` value row and ``onehot_g`` the masked
+    transposed ``(Gt, R)`` group one-hot (the same matrix the moment
+    matmul consumes, so the fused kernel builds it once); returns the
+    ``(Gt, kt)`` partial for bin tile ``[kbase, kbase + kt)``.
     """
     bin_idx = jnp.clip(((v - a) * inv_width), 0.0, nbins - 1.0
-                       ).astype(jnp.int32)
-    bins_tile = kbase + jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1)
-    onehot_b = (bin_idx[:, None] == bins_tile).astype(jnp.float32)
-    return jax.lax.dot(onehot_g.T, onehot_b,
-                       preferred_element_type=jnp.float32)  # (Gt, Kt)
+                       ).astype(jnp.int32)                       # (1, R)
+    bins_tile = kbase + jax.lax.broadcasted_iota(
+        jnp.int32, (kt, v.shape[1]), 0)
+    onehot_b = (bin_idx == bins_tile).astype(jnp.float32)        # (Kt, R)
+    return jax.lax.dot_general(onehot_g, onehot_b, NT_DIMS,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(scale_ref, values_ref, gids_ref, mask_ref, hist_ref):
@@ -52,12 +61,11 @@ def _kernel(scale_ref, values_ref, gids_ref, mask_ref, hist_ref):
     inv_width = scale_ref[0, 1]
     nbins = scale_ref[0, 2]
 
-    v = values_ref[...].reshape(-1)
-    gid = gids_ref[...].reshape(-1)
-    m = mask_ref[...].reshape(-1).astype(jnp.float32)
-
-    gids_tile = g * gt + jax.lax.broadcasted_iota(jnp.int32, (1, gt), 1)
-    onehot_g = (gid[:, None] == gids_tile).astype(jnp.float32) * m[:, None]
+    v = values_ref[...]
+    m = mask_ref[...].astype(jnp.float32)
+    gids_tile = g * gt + jax.lax.broadcasted_iota(
+        jnp.int32, (gt, v.shape[1]), 0)
+    onehot_g = (gids_ref[...] == gids_tile).astype(jnp.float32) * m
     partial = tile_hist(v, onehot_g, a, inv_width, nbins, k * kt, kt)
 
     @pl.when(r == 0)
@@ -85,26 +93,21 @@ def grouped_hist(values: jax.Array, gids: jax.Array, mask: jax.Array,
     assert n % row_tile == 0
     assert num_groups % group_tile == 0 and nbins % bin_tile == 0
     nbins_data = nbins_data or nbins
-    lanes = 128
-    v2 = values.astype(jnp.float32).reshape(n // lanes, lanes)
-    g2 = gids.astype(jnp.int32).reshape(n // lanes, lanes)
-    m2 = mask.astype(jnp.float32).reshape(n // lanes, lanes)
-    rt = row_tile // lanes
+    v2 = values.astype(jnp.float32).reshape(1, n)
+    g2 = gids.astype(jnp.int32).reshape(1, n)
+    m2 = mask.astype(jnp.float32).reshape(1, n)
     inv_width = float(nbins_data) / max(float(b) - float(a), 1e-30)
     scale = jnp.asarray([[a, inv_width, float(nbins_data)]], jnp.float32)
     grid = (num_groups // group_tile, nbins // bin_tile, n // row_tile)
+    row_spec = pl.BlockSpec((1, row_tile), lambda g, k, r: block_index(0, r))
 
     return pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda g, k, r: (0, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, k, r: (r, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, k, r: (r, 0)),
-            pl.BlockSpec((rt, lanes), lambda g, k, r: (r, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, 3), lambda g, k, r: block_index(0, 0)),
+                  row_spec, row_spec, row_spec],
         out_specs=pl.BlockSpec((group_tile, bin_tile),
-                               lambda g, k, r: (g, k)),
+                               lambda g, k, r: block_index(g, k)),
         out_shape=jax.ShapeDtypeStruct((num_groups, nbins), jnp.float32),
         interpret=interpret,
     )(scale, v2, g2, m2)

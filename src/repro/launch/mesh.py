@@ -3,27 +3,16 @@
 ``make_production_mesh()`` is a FUNCTION (not a module-level constant) so
 importing this module never touches jax device state — required for the
 dry-run's device-count override to work.
-
-``jax.sharding.AxisType`` (and ``jax.make_mesh``'s ``axis_types`` kwarg)
-only exist on newer jax releases; on older installs we fall back to a plain
-mesh, which behaves identically for the Auto axis type used here.
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -20,7 +20,6 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 
 from repro.configs import get  # noqa: E402
 from repro.configs.base import ShapeConfig  # noqa: E402
@@ -106,8 +105,8 @@ def main():
     def body(xs):
         return compressed_psum(xs, ("data",))
 
-    out = jax.jit(shard_map(body, mesh=mesh3, in_specs=P("data"),
-                            out_specs=P("data"), check_rep=False))(x)
+    out = jax.jit(jax.shard_map(body, mesh=mesh3, in_specs=P("data"),
+                            out_specs=P("data"), check_vma=False))(x)
     want = np.asarray(x).sum(axis=0)
     got = np.asarray(out)[0]
     scale = np.abs(np.asarray(x)).max() / 127

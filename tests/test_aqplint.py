@@ -240,16 +240,20 @@ def test_collectives_flags_psum_outside_shard_map(tmp_path):
     assert codes(found) == ["AQP401"]
 
 
-def test_collectives_accepts_psum_under_shard_map(tmp_path):
-    found = lint(tmp_path, {"mod.py": """
+@pytest.mark.parametrize("imp, call", [
+    ("", "jax.shard_map"),
+    ("from jax import shard_map", "shard_map"),
+])
+def test_collectives_accepts_psum_under_shard_map(tmp_path, imp, call):
+    found = lint(tmp_path, {"mod.py": f"""
         import jax
-        from jax.experimental.shard_map import shard_map
+        {imp}
 
         def build(mesh, specs):
             def fold(x):
                 return jax.lax.psum(x, "shards")
-            return shard_map(fold, mesh=mesh, in_specs=specs,
-                             out_specs=specs)
+            return {call}(fold, mesh=mesh, in_specs=specs,
+                          out_specs=specs)
     """}, only={"collectives"})
     assert found == []
 
@@ -257,7 +261,7 @@ def test_collectives_accepts_psum_under_shard_map(tmp_path):
 def test_collectives_flags_unknown_and_missing_axis(tmp_path):
     found = lint(tmp_path, {"mod.py": """
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def build(mesh, specs):
             def fold(x):
@@ -272,7 +276,7 @@ def test_collectives_flags_unknown_and_missing_axis(tmp_path):
 def test_collectives_flags_pending_fold_off_cadence(tmp_path):
     files = {"mod.py": """
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def build(mesh, specs):
             def {name}(c):

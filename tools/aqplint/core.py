@@ -373,10 +373,7 @@ _TRACING_CALLEES = {
     "jax.experimental.pallas.pallas_call", "pallas.pallas_call",
     "pl.pallas_call", "pallas_call",
 }
-_SHARD_CALLEES = {
-    "jax.experimental.shard_map.shard_map", "shard_map",
-    "jax.experimental.shard_map", "smap",
-}
+_SHARD_CALLEES = {"jax.shard_map", "shard_map"}
 #: closures passed under these parameter-name patterns are traced by
 #: convention (the engine hands CI-refresh closures to the loop builders)
 _CALLBACK_PARAM_RE = re.compile(r"(_fn|_fns|_src)$")
