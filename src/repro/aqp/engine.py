@@ -68,6 +68,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.aqp import distributed as adist
 from repro.aqp.bitmap import (BlockBitmap, build_bitmap, pack_mask,
@@ -1271,7 +1272,24 @@ class FastFrame:
             :class:`~repro.aqp.query.QueryResult` with per-group
             estimates, anytime-valid ``(1 - q.delta)`` intervals and scan
             metrics.
+
+        Each call records host spans (a ``jax.profiler.TraceAnnotation``
+        each, seen only while a profiler trace is on): ``aqp:run`` around
+        the call, and inside it, on the device-loop path and in order,
+        ``aqp:views`` (the scan views and query intervals),
+        ``aqp:upload`` (scan order, the device loop and its inputs),
+        ``aqp:loop`` (the device loop's dispatches and syncs),
+        ``aqp:writeback``, ``aqp:recovery`` and ``aqp:result``. The host
+        loop and the exact sweep record ``aqp:run``, ``aqp:views``,
+        ``aqp:recovery`` (not the exact sweep) and ``aqp:result``.
         """
+        with TraceAnnotation("aqp:run"):
+            return self._run(q, sampling, start_block, seed, max_rounds,
+                             on_sync)
+
+    def _run(self, q: AggQuery, sampling: str, start_block: Optional[int],
+             seed: int, max_rounds: int,
+             on_sync: Optional[Callable]) -> QueryResult:
         t0 = time.perf_counter()
         cfg = self.config
         sc = self.scramble
@@ -1283,13 +1301,16 @@ class FastFrame:
             # single device) must fail loudly, not silently run unsharded
             cfg.resolve_shard_rows()
 
-        # scan order: random start, wrap around (paper §5.2)
-        start = (rng.integers(nb) if start_block is None else start_block)
-        order = (start + np.arange(nb)) % nb
-        cum_rows = np.cumsum(self._valid_counts[order])
+        def scan_order():
+            # random start, wrap around (paper §5.2)
+            start = (rng.integers(nb) if start_block is None
+                     else start_block)
+            order = (start + np.arange(nb)) % nb
+            return order, np.cumsum(self._valid_counts[order])
 
-        slot = _ScanViews(self, q)
-        qci = _QueryIntervals(self, q, slot)
+        with TraceAnnotation("aqp:views"):
+            slot = _ScanViews(self, q)
+            qci = _QueryIntervals(self, q, slot)
         metrics = {"skipped_static": 0, "skipped_active": 0,
                    "probes": slot.probes0}
 
@@ -1306,29 +1327,39 @@ class FastFrame:
             # ---- device-resident round loop (tentpole path): the whole
             # OptStop loop in lax.while_loop dispatches; one host sync
             # per chunk, full writeback at termination -----------------
-            probe = skipping and slot.group_bm is not None
-            shards = self.block_shards()
-            key = ("run", q.scan_signature(), q.agg, q.bounder,
-                   q.rangetrim, q.delta, repr(q.stop), probe, lookahead,
-                   max_rounds, cfg.sync_every or cfg.chunk_rounds,
-                   (shards.n_shards, shards.shard_rows,
-                    shards.merge_every)
-                   if shards is not None else None)
-            dloop = self.device_loops.get_or_build(
-                key,
-                lambda: _DeviceLoop(self, q, slot, qci, probe, lookahead,
-                                    max_rounds, shards))
-            dloop.set_order(order, cum_rows)
-            carry = dloop.run(dloop.init_carry(slot, qci), on_sync)
-            dloop.writeback(carry, slot, qci, metrics)
-            pos = int(carry.pos)
-            rounds = int(carry.rounds)
-            stopped_early = bool(carry.stopped_early)
-            rounds = self._recovery_pass(slot, [qci], rounds, max_rounds)
-            qci.collapse_exact()
-            return qci.result(rounds, pos, cum_rows, metrics, t0,
-                              stopped_early)
+            with TraceAnnotation("aqp:upload"):
+                order, cum_rows = scan_order()
+                probe = skipping and slot.group_bm is not None
+                shards = self.block_shards()
+                key = ("run", q.scan_signature(), q.agg, q.bounder,
+                       q.rangetrim, q.delta, repr(q.stop), probe,
+                       lookahead, max_rounds,
+                       cfg.sync_every or cfg.chunk_rounds,
+                       (shards.n_shards, shards.shard_rows,
+                        shards.merge_every)
+                       if shards is not None else None)
+                dloop = self.device_loops.get_or_build(
+                    key,
+                    lambda: _DeviceLoop(self, q, slot, qci, probe,
+                                        lookahead, max_rounds, shards))
+                dloop.set_order(order, cum_rows)
+                carry = dloop.init_carry(slot, qci)
+            with TraceAnnotation("aqp:loop"):
+                carry = dloop.run(carry, on_sync)
+            with TraceAnnotation("aqp:writeback"):
+                dloop.writeback(carry, slot, qci, metrics)
+                pos = int(carry.pos)
+                rounds = int(carry.rounds)
+                stopped_early = bool(carry.stopped_early)
+            with TraceAnnotation("aqp:recovery"):
+                rounds = self._recovery_pass(slot, [qci], rounds,
+                                             max_rounds)
+            with TraceAnnotation("aqp:result"):
+                qci.collapse_exact()
+                return qci.result(rounds, pos, cum_rows, metrics, t0,
+                                  stopped_early)
 
+        order, cum_rows = scan_order()
         active_words = (jnp.asarray(pack_mask(qci.active))
                         if slot.gcol is not None else None)
         fscan = None
@@ -1387,11 +1418,13 @@ class FastFrame:
                 active_words = jnp.asarray(pack_mask(qci.active))
 
         if not exact_mode:
-            rounds = self._recovery_pass(slot, [qci], rounds, max_rounds)
+            with TraceAnnotation("aqp:recovery"):
+                rounds = self._recovery_pass(slot, [qci], rounds,
+                                             max_rounds)
 
-        qci.collapse_exact()
-        if exact_mode:
-            stopped_early = False
-
-        return qci.result(rounds, pos, cum_rows, metrics, t0,
-                          stopped_early)
+        with TraceAnnotation("aqp:result"):
+            qci.collapse_exact()
+            if exact_mode:
+                stopped_early = False
+            return qci.result(rounds, pos, cum_rows, metrics, t0,
+                              stopped_early)

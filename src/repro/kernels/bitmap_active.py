@@ -61,4 +61,5 @@ def active_blocks(bitmap: jax.Array, active_words: jax.Array, *,
         out_specs=pl.BlockSpec((block_tile, 1), lambda i: block_index(i, 0)),
         out_shape=jax.ShapeDtypeStruct((nblocks, 1), jnp.int32),
         interpret=interpret,
+        name="active_blocks",
     )(_as_i32(bitmap), _as_i32(active_words).reshape(1, w))

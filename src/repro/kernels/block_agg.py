@@ -140,5 +140,6 @@ def block_agg(values: jax.Array, gids: jax.Array, mask: jax.Array,
             jax.ShapeDtypeStruct((num_groups, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="block_agg",
     )(c, v2, g2, m2)
     return sums, vmin.reshape(1, num_groups), vmax.reshape(1, num_groups)

@@ -85,6 +85,17 @@ Backends (same selector as :mod:`repro.kernels.ops`):
 VMEM at the defaults (ROW_TILE=1024, GROUP_TILE=128): a v5e compile of
 :func:`fused_fold` accepts 3.6-4.1 MiB of scoped VMEM at 1024 bins and
 4.6 MiB at 2048 (``docs/kernels.md``), under the 16 MiB scoped default.
+
+**Named scopes** (:data:`SCOPES`): the shared round helpers below wrap
+their work in ``jax.named_scope``, so every operation of a round loop
+carries its phase in its HLO ``op_name`` and a profiler trace can put
+each device operation under one of them: ``select`` (window slice,
+activity probe, budgeted selection), ``gather`` (block ids and the three
+slab gathers), ``fold`` (the moment / histogram kernels), ``merge`` (the
+f64 running-state merge, the histogram add, the cross-shard
+collectives), ``account`` (taint, coverage and scan metrics) and
+``refresh`` (CI refresh and the stopping test). Scopes are metadata
+only: they change neither the computation nor its results.
 """
 
 from __future__ import annotations
@@ -105,6 +116,7 @@ from repro.kernels import ops as kops
 
 ROW_TILE = 1024   # rows per grid step (multiple of 128)
 GROUP_TILE = 128  # groups per grid step (multiple of 128)
+SCOPES = ("select", "gather", "fold", "merge", "account", "refresh")
 
 
 def _fold_kernel(scale_ref, values_ref, gids_ref, mask_ref,
@@ -191,6 +203,7 @@ def fused_fold(values: jax.Array, gids: jax.Array, mask: jax.Array,
             jax.ShapeDtypeStruct((num_groups, nbins), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_fold",
     )(scale, v2, g2, m2)
     return (sums, vmin.reshape(1, num_groups), vmax.reshape(1, num_groups),
             hist)
@@ -233,33 +246,36 @@ def _fold_local(v, g, m, center, a, b, num_groups, nbins, use_hist, impl):
     ``pmin`` / ``pmax``) — either per round inside :func:`_fold` or, on
     a collective cadence, accumulated in the loop carry's f64 pending
     slots and merged every ``ShardInfo.merge_every`` rounds."""
-    if impl == "ref" or not use_hist:
-        # No histogram: the plain block_agg kernel already is the fused
-        # moment pass; ref: XLA segment ops (bitwise-identical to the
-        # per-block reference path, which calls the same functions).
-        sums, vmin, vmax = kops.grouped_sums(v, g, m, num_groups, center,
-                                             impl=impl)
-        hist = None
-        if use_hist:
-            hist = kops.grouped_hist(v, g, m, num_groups, a, b, nbins=nbins,
-                                     impl=impl).hist
-    else:
-        gpad = _pad_groups(num_groups, GROUP_TILE)
-        kpad = _pad_groups(nbins, 128)
-        n = v.shape[0]
-        rpad = (-n) % ROW_TILE
-        if rpad:
-            v = jnp.concatenate([v, jnp.zeros(rpad, v.dtype)])
-            g = jnp.concatenate([g, jnp.zeros(rpad, g.dtype)])
-            m = jnp.concatenate([m, jnp.zeros(rpad, m.dtype)])
-        sums, vmin, vmax, hist = fused_fold(
-            v, g, m, jnp.asarray(center, jnp.float32), a=a, b=b,
-            num_groups=gpad, nbins=kpad, interpret=(impl == "interpret"))
-        sums = sums[:, :num_groups]
-        vmin = vmin[:, :num_groups]
-        vmax = vmax[:, :num_groups]
-        hist = hist[:num_groups, :nbins]
-    return sums, vmin, vmax, hist
+    with jax.named_scope("fold"):
+        if impl == "ref" or not use_hist:
+            # No histogram: the plain block_agg kernel already is the
+            # fused moment pass; ref: XLA segment ops (bitwise-identical to
+            # the per-block reference path, which calls the same
+            # functions).
+            sums, vmin, vmax = kops.grouped_sums(v, g, m, num_groups,
+                                                 center, impl=impl)
+            hist = None
+            if use_hist:
+                hist = kops.grouped_hist(v, g, m, num_groups, a, b,
+                                         nbins=nbins, impl=impl).hist
+        else:
+            gpad = _pad_groups(num_groups, GROUP_TILE)
+            kpad = _pad_groups(nbins, 128)
+            n = v.shape[0]
+            rpad = (-n) % ROW_TILE
+            if rpad:
+                v = jnp.concatenate([v, jnp.zeros(rpad, v.dtype)])
+                g = jnp.concatenate([g, jnp.zeros(rpad, g.dtype)])
+                m = jnp.concatenate([m, jnp.zeros(rpad, m.dtype)])
+            sums, vmin, vmax, hist = fused_fold(
+                v, g, m, jnp.asarray(center, jnp.float32), a=a, b=b,
+                num_groups=gpad, nbins=kpad,
+                interpret=(impl == "interpret"))
+            sums = sums[:, :num_groups]
+            vmin = vmin[:, :num_groups]
+            vmax = vmax[:, :num_groups]
+            hist = hist[:num_groups, :nbins]
+        return sums, vmin, vmax, hist
 
 
 def _fold(v, g, m, center, a, b, num_groups, nbins, use_hist, impl,
@@ -278,12 +294,14 @@ def _fold(v, g, m, center, a, b, num_groups, nbins, use_hist, impl,
                                          impl)
     if shard_axes:
         # one collective set per round: O(groups) bytes across the mesh
-        sums = jax.lax.psum(sums, shard_axes)
-        vmin = jax.lax.pmin(vmin, shard_axes)
-        vmax = jax.lax.pmax(vmax, shard_axes)
-        if hist is not None:
-            hist = jax.lax.psum(hist, shard_axes)
-    return kops.moments_from_sums(sums, vmin, vmax, center), hist
+        with jax.named_scope("merge"):
+            sums = jax.lax.psum(sums, shard_axes)
+            vmin = jax.lax.pmin(vmin, shard_axes)
+            vmax = jax.lax.pmax(vmax, shard_axes)
+            if hist is not None:
+                hist = jax.lax.psum(hist, shard_axes)
+    with jax.named_scope("fold"):
+        return kops.moments_from_sums(sums, vmin, vmax, center), hist
 
 
 def _pmin_pmax_f64(vmin, vmax, axes):
@@ -324,6 +342,18 @@ def _gather_blocks(take: jax.Array, win: jax.Array, window: int,
     tvalid = take_idx < window
     blk = jnp.where(tvalid, win[jnp.minimum(take_idx, window - 1)], 0)
     return blk, tvalid, take_idx
+
+
+def _gather_rows(values, gids, mask, take, win, window: int, budget: int):
+    """The round's rows: the selected blocks' ids (``_gather_blocks``) and
+    the flattened ``(budget * block_rows,)`` value, group-code and mask
+    rows gathered from the device slabs, padding lanes masked out."""
+    with jax.named_scope("gather"):
+        blk, tvalid, _ = _gather_blocks(take, win, window, budget)
+        v = values[blk].reshape(-1)
+        g = gids[blk].reshape(-1)
+        m = (mask[blk] * tvalid[:, None].astype(jnp.float32)).reshape(-1)
+    return v, g, m
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -368,10 +398,7 @@ def fused_round(values: jax.Array, gids: jax.Array, mask: jax.Array,
         flags = ok
 
     take, new_pos = _budget_select(flags, pos, nb, window, budget)
-    blk, tvalid, _ = _gather_blocks(take, win, window, budget)
-    v = values[blk].reshape(-1)
-    g = gids[blk].reshape(-1)
-    m = (mask[blk] * tvalid[:, None].astype(jnp.float32)).reshape(-1)
+    v, g, m = _gather_rows(values, gids, mask, take, win, window, budget)
 
     state, hist = _fold(v, g, m, center, a, b, num_groups, nbins,
                         use_hist, impl)
@@ -452,10 +479,8 @@ def fused_round_multi(mask: jax.Array, order_pad: jax.Array,
         fl = ok[None, :] & act
         flags = fl.any(axis=0)
         take, new_p = _budget_select(flags, p, le, window, budget)
-        blk, tvalid, _ = _gather_blocks(take, win, window, budget)
-        m = (mask[blk] * tvalid[:, None].astype(jnp.float32)).reshape(-1)
-        v = values[s][blk].reshape(-1)
-        g = gids[s][blk].reshape(-1)
+        v, g, m = _gather_rows(values[s], gids[s], mask, take, win, window,
+                               budget)
         st, h = _fold(v, g, m, center, a, b, num_groups, nbins,
                       use_hist, impl)
         states.append(st)
@@ -489,8 +514,45 @@ def _merge_f64(state: MomentState, delta: MomentState) -> MomentState:
     Same formula in the same order: counts (integral sums) stay exact;
     mean/m2 may differ from the host by the final ulp where XLA
     contracts a mul+add into an FMA."""
-    return merge_moments(
-        state, MomentState(*(jnp.asarray(f, jnp.float64) for f in delta)))
+    with jax.named_scope("merge"):
+        return merge_moments(
+            state,
+            MomentState(*(jnp.asarray(f, jnp.float64) for f in delta)))
+
+
+def _merge_round(state: MomentState, hist, dstate: MomentState, dhist,
+                 use_hist: bool):
+    """Fold one round's merged delta into the f64 running state and
+    histogram (``hist`` passes through unchanged without ``use_hist``)."""
+    state = _merge_f64(state, dstate)
+    with jax.named_scope("merge"):
+        if use_hist:
+            hist = hist + jnp.asarray(dhist, jnp.float64)
+    return state, hist
+
+
+def _pend_round(c, dsums, dvmin, dvmax, dhist, use_hist: bool) -> dict:
+    """Add one round's raw local fold to the pending slots of a query or
+    slot carry ``c`` (collective cadence); returns the updated slots."""
+    with jax.named_scope("merge"):
+        return dict(
+            pend_sums=c.pend_sums + jnp.asarray(dsums, jnp.float64),
+            pend_vmin=jnp.minimum(
+                c.pend_vmin, jnp.asarray(dvmin, jnp.float64).reshape(-1)),
+            pend_vmax=jnp.maximum(
+                c.pend_vmax, jnp.asarray(dvmax, jnp.float64).reshape(-1)),
+            pend_hist=(c.pend_hist + jnp.asarray(dhist, jnp.float64)
+                       if use_hist else None))
+
+
+def _zeroed_pending(c, use_hist: bool) -> dict:
+    """The pending slots of a query or slot carry ``c``, emptied by a
+    merge."""
+    return dict(
+        pend_sums=jnp.zeros_like(c.pend_sums),
+        pend_vmin=jnp.full_like(c.pend_vmin, jnp.inf),
+        pend_vmax=jnp.full_like(c.pend_vmax, -jnp.inf),
+        pend_hist=jnp.zeros_like(c.pend_hist) if use_hist else None)
 
 
 def _probe_cost(flags: jax.Array, pos: jax.Array, nb: int, window: int,
@@ -575,16 +637,50 @@ def _round_scan(bufs, pos, flags_src, *, nb: int, window: int,
     ``bound`` overrides the cursor limit (a carousel pass's horizon can
     exceed ``nb``); ``wrap`` slices the order at ``pos % nb`` — the
     order pad must then be wrap-filled (``order[:window]``)."""
-    offs = jnp.arange(window, dtype=jnp.int32)
-    lim = nb if bound is None else bound
-    in_range = (pos + offs) < lim
-    start = jax.lax.rem(pos, jnp.int32(nb)) if wrap else pos
-    win = jax.lax.dynamic_slice(bufs.order_pad, (start,), (window,))
-    ok = bufs.static_ok[win] & in_range
-    flags = flags_src(ok, win)
-    take, new_pos = _budget_select(flags, pos, lim, window, budget)
-    covmask = offs < (new_pos - pos)
+    with jax.named_scope("select"):
+        offs = jnp.arange(window, dtype=jnp.int32)
+        lim = nb if bound is None else bound
+        in_range = (pos + offs) < lim
+        start = jax.lax.rem(pos, jnp.int32(nb)) if wrap else pos
+        win = jax.lax.dynamic_slice(bufs.order_pad, (start,), (window,))
+        ok = bufs.static_ok[win] & in_range
+        flags = flags_src(ok, win)
+        take, new_pos = _budget_select(flags, pos, lim, window, budget)
+        covmask = offs < (new_pos - pos)
     return win, ok, flags, take, new_pos, covmask
+
+
+def _account(c, presence, presence_total, scan, *, lim, probe: bool,
+             window: int, budget: int, lookahead: int, cover_cap: int
+             ) -> dict:
+    """One round's skip / taint / coverage / metric accounting of a query
+    or slot carry ``c`` (the device twin of ``engine._fused_accounting``,
+    ``_ScanViews.ingest_delta`` and ``_ScanViews.update_exact``) from
+    ``_round_scan``'s tuple ``scan``; ``lim`` is the cursor limit.
+    Returns the updated carry fields."""
+    win, ok, flags, take, new_pos, covmask = scan
+    i64 = jnp.int64
+    with jax.named_scope("account"):
+        act_skip = ok & covmask & ~(flags & covmask)
+        pres_win = presence[win]
+        tainted = c.tainted | (pres_win & act_skip[:, None]).any(axis=0)
+        seen_presence = c.seen_presence + (
+            pres_win & take[:, None]).sum(axis=0, dtype=jnp.int32)
+        cov = seen_presence >= presence_total
+        cov = cov | ((new_pos >= lim) & ~tainted)
+        probes = c.probes
+        if probe:
+            probes = probes + _probe_cost(flags, c.pos, lim, window,
+                                          budget, lookahead, cover_cap)
+        return dict(
+            seen_presence=seen_presence, tainted=tainted,
+            exact=c.exact | cov,
+            processed=c.processed.at[win].max(take),
+            blocks_fetched=c.blocks_fetched + take.sum(dtype=i64),
+            skipped_static=(c.skipped_static
+                            + (~ok & covmask).sum(dtype=i64)),
+            skipped_active=c.skipped_active + act_skip.sum(dtype=i64),
+            probes=probes)
 
 
 def _query_carry_spec(use_hist: bool, cadence: bool = False
@@ -670,75 +766,54 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
     as the oracle.
     """
     cadence = shard is not None and shard.merge_every > 1
+    acct_kw = dict(lim=nb, probe=probe, window=window, budget=budget,
+                   lookahead=lookahead, cover_cap=cover_cap)
 
-    def body(bufs, c: QueryLoopCarry) -> QueryLoopCarry:
-        k = c.rounds + 1
-
+    def scan_round(bufs, pos, sel_active):
         def flags_src(ok, win):
             if not probe:
                 return ok
-            aw = pack_active_device(c.active, n_words)
+            aw = pack_active_device(sel_active, n_words)
             act = kops.active_blocks(bufs.words[win], aw, impl=impl) > 0
             return ok & act
 
-        win, ok, flags, take, new_pos, covmask = _round_scan(
-            bufs, c.pos, flags_src, nb=nb, window=window, budget=budget)
-        blk, tvalid, take_idx = _gather_blocks(take, win, window, budget)
+        return _round_scan(bufs, pos, flags_src, nb=nb, window=window,
+                           budget=budget)
+
+    def refresh(bufs, c: QueryLoopCarry, k, pos, state, hist, tainted,
+                exact) -> dict:
+        """CI refresh + stopping condition (engine-supplied) at round
+        ``k`` with the cursor at ``pos``."""
+        with jax.named_scope("refresh"):
+            r = jnp.where(pos > 0, bufs.cum_rows[jnp.maximum(pos - 1, 0)],
+                          0).astype(jnp.float64)
+            lo, hi, est, refreshed, active = refresh_fn(
+                k, r, state, hist, tainted, exact, c.lo, c.hi, c.est,
+                c.refreshed, c.active)
+            live = active.any()
+            stopped_early = c.stopped_early | (~live & (pos < nb))
+        return dict(lo=lo, hi=hi, est=est, refreshed=refreshed,
+                    active=active, live=live, stopped_early=stopped_early)
+
+    def body(bufs, c: QueryLoopCarry) -> QueryLoopCarry:
+        k = c.rounds + 1
+        scan = scan_round(bufs, c.pos, c.active)
+        win, ok, flags, take, new_pos, covmask = scan
         # Under shard_map the local slab is this shard's row slice of
         # every block, so the global block ids gather exactly the
         # shard's 1/n_shards of the selection — no translation needed.
-        v = bufs.values[blk].reshape(-1)
-        g = bufs.gids[blk].reshape(-1)
-        m = (bufs.mask[blk]
-             * tvalid[:, None].astype(jnp.float32)).reshape(-1)
+        v, g, m = _gather_rows(bufs.values, bufs.gids, bufs.mask, take, win,
+                               window, budget)
         dstate, dhist = _fold(v, g, m, center, a, b, num_groups, nbins,
                               use_hist, impl,
                               shard_axes=shard.axes if shard else None)
-        state = _merge_f64(c.state, dstate)
-        hist = (c.hist + jnp.asarray(dhist, jnp.float64) if use_hist
-                else c.hist)
-
-        # -- accounting (twin of engine._fused_accounting + ingest) ------
-        okc = ok & covmask
-        flagsc = flags & covmask
-        act_skip = okc & ~flagsc
-        pres_win = bufs.presence[win]
-        tainted = c.tainted | (pres_win & act_skip[:, None]).any(axis=0)
-        skipped_static = (c.skipped_static
-                          + (~ok & covmask).sum(dtype=jnp.int64))
-        skipped_active = c.skipped_active + act_skip.sum(dtype=jnp.int64)
-        probes = c.probes
-        if probe:
-            probes = probes + _probe_cost(flags, c.pos, nb, window,
-                                          budget, lookahead, cover_cap)
-        processed = c.processed.at[win].max(take)
-        blocks_fetched = c.blocks_fetched + take.sum(dtype=jnp.int64)
-        seen_presence = c.seen_presence + (
-            pres_win & take[:, None]).sum(axis=0, dtype=jnp.int32)
-
-        # -- coverage / exactness (twin of _ScanViews.update_exact) ------
-        cov = seen_presence >= bufs.presence_total
-        cov = cov | ((new_pos >= nb) & ~tainted)
-        exact = c.exact | cov
-
-        # -- CI refresh + stopping condition (engine-supplied) -----------
-        r = jnp.where(new_pos > 0,
-                      bufs.cum_rows[jnp.maximum(new_pos - 1, 0)],
-                      0).astype(jnp.float64)
-        lo, hi, est, refreshed, active = refresh_fn(
-            k, r, state, hist, tainted, exact, c.lo, c.hi, c.est,
-            c.refreshed, c.active)
-        live = active.any()
-        stopped_early = c.stopped_early | (~live & (new_pos < nb))
-
-        return QueryLoopCarry(
-            pos=new_pos, rounds=k, it=c.it + 1, live=live,
-            stopped_early=stopped_early, state=state, hist=hist,
-            processed=processed, seen_presence=seen_presence,
-            tainted=tainted, exact=exact, lo=lo, hi=hi, est=est,
-            refreshed=refreshed, active=active,
-            blocks_fetched=blocks_fetched, skipped_static=skipped_static,
-            skipped_active=skipped_active, probes=probes)
+        state, hist = _merge_round(c.state, c.hist, dstate, dhist, use_hist)
+        acct = _account(c, bufs.presence, bufs.presence_total, scan,
+                        **acct_kw)
+        return c._replace(
+            pos=new_pos, rounds=k, it=c.it + 1, state=state, hist=hist,
+            **acct, **refresh(bufs, c, k, new_pos, state, hist,
+                              acct["tainted"], acct["exact"]))
 
     # -- collective cadence (shard.merge_every = K > 1) ------------------
 
@@ -751,30 +826,19 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
         merges zero the pending slots, so each index is consumed at most
         once (the schedule stays a subset of the K=1 one and the union
         bound over ``delta`` holds)."""
-        sums = jax.lax.psum(c.pend_sums, shard.axes)
-        vmin, vmax = _pmin_pmax_f64(c.pend_vmin, c.pend_vmax, shard.axes)
-        dstate = kops.moments_from_sums(sums, vmin, vmax, center)
-        state = merge_moments(c.state, dstate)
-        hist = (c.hist + jax.lax.psum(c.pend_hist, shard.axes)
-                if use_hist else c.hist)
-        r = jnp.where(c.pos > 0,
-                      bufs.cum_rows[jnp.maximum(c.pos - 1, 0)],
-                      0).astype(jnp.float64)
-        lo, hi, est, refreshed, active = refresh_fn(
-            c.rounds, r, state, hist, c.tainted, c.exact, c.lo, c.hi,
-            c.est, c.refreshed, c.active)
-        live = active.any()
-        stopped_early = c.stopped_early | (~live & (c.pos < nb))
+        with jax.named_scope("merge"):
+            sums = jax.lax.psum(c.pend_sums, shard.axes)
+            vmin, vmax = _pmin_pmax_f64(c.pend_vmin, c.pend_vmax,
+                                        shard.axes)
+            dstate = kops.moments_from_sums(sums, vmin, vmax, center)
+            state = merge_moments(c.state, dstate)
+            hist = (c.hist + jax.lax.psum(c.pend_hist, shard.axes)
+                    if use_hist else c.hist)
         return c._replace(
-            live=live, stopped_early=stopped_early, state=state,
-            hist=hist, lo=lo, hi=hi, est=est, refreshed=refreshed,
-            active=active,
-            pend_sums=jnp.zeros_like(c.pend_sums),
-            pend_vmin=jnp.full_like(c.pend_vmin, jnp.inf),
-            pend_vmax=jnp.full_like(c.pend_vmax, -jnp.inf),
-            pend_hist=(jnp.zeros_like(c.pend_hist) if use_hist
-                       else None),
-            pend_rounds=jnp.asarray(0, jnp.int32))
+            state=state, hist=hist, pend_rounds=jnp.asarray(0, jnp.int32),
+            **_zeroed_pending(c, use_hist),
+            **refresh(bufs, c, c.rounds, c.pos, state, hist, c.tainted,
+                      c.exact))
 
     def cadence_body(bufs, c: QueryLoopCarry) -> QueryLoopCarry:
         # Selection runs on the PRE-merge active mask, so this round's
@@ -789,63 +853,19 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
         c = jax.lax.cond(c.pend_rounds >= shard.merge_every,
                          functools.partial(_merge_refresh, bufs),
                          lambda x: x, c)
-        k = c.rounds + 1
-
-        def flags_src(ok, win):
-            if not probe:
-                return ok
-            aw = pack_active_device(sel_active, n_words)
-            act = kops.active_blocks(bufs.words[win], aw, impl=impl) > 0
-            return ok & act
-
-        win, ok, flags, take, new_pos, covmask = _round_scan(
-            bufs, c.pos, flags_src, nb=nb, window=window, budget=budget)
-        blk, tvalid, take_idx = _gather_blocks(take, win, window, budget)
-        v = bufs.values[blk].reshape(-1)
-        g = bufs.gids[blk].reshape(-1)
-        m = (bufs.mask[blk]
-             * tvalid[:, None].astype(jnp.float32)).reshape(-1)
+        scan = scan_round(bufs, c.pos, sel_active)
+        win, ok, flags, take, new_pos, covmask = scan
+        v, g, m = _gather_rows(bufs.values, bufs.gids, bufs.mask, take, win,
+                               window, budget)
         dsums, dvmin, dvmax, dhist = _fold_local(
             v, g, m, center, a, b, num_groups, nbins, use_hist, impl)
-        pend_sums = c.pend_sums + jnp.asarray(dsums, jnp.float64)
-        pend_vmin = jnp.minimum(
-            c.pend_vmin, jnp.asarray(dvmin, jnp.float64).reshape(-1))
-        pend_vmax = jnp.maximum(
-            c.pend_vmax, jnp.asarray(dvmax, jnp.float64).reshape(-1))
-        pend_hist = (c.pend_hist + jnp.asarray(dhist, jnp.float64)
-                     if use_hist else None)
-        pend_rounds = c.pend_rounds + 1
-
-        # -- accounting: replicated, every round (same as the K=1 body) --
-        okc = ok & covmask
-        flagsc = flags & covmask
-        act_skip = okc & ~flagsc
-        pres_win = bufs.presence[win]
-        tainted = c.tainted | (pres_win & act_skip[:, None]).any(axis=0)
-        skipped_static = (c.skipped_static
-                          + (~ok & covmask).sum(dtype=jnp.int64))
-        skipped_active = c.skipped_active + act_skip.sum(dtype=jnp.int64)
-        probes_m = c.probes
-        if probe:
-            probes_m = probes_m + _probe_cost(flags, c.pos, nb, window,
-                                              budget, lookahead,
-                                              cover_cap)
-        processed = c.processed.at[win].max(take)
-        blocks_fetched = c.blocks_fetched + take.sum(dtype=jnp.int64)
-        seen_presence = c.seen_presence + (
-            pres_win & take[:, None]).sum(axis=0, dtype=jnp.int32)
-        cov = seen_presence >= bufs.presence_total
-        cov = cov | ((new_pos >= nb) & ~tainted)
-        exact = c.exact | cov
-
+        # accounting: replicated, every round (same as the K=1 body)
         return c._replace(
-            pos=new_pos, rounds=k, it=c.it + 1, processed=processed,
-            seen_presence=seen_presence, tainted=tainted, exact=exact,
-            blocks_fetched=blocks_fetched, skipped_static=skipped_static,
-            skipped_active=skipped_active, probes=probes_m,
-            pend_sums=pend_sums, pend_vmin=pend_vmin,
-            pend_vmax=pend_vmax, pend_hist=pend_hist,
-            pend_rounds=pend_rounds)
+            pos=new_pos, rounds=c.rounds + 1, it=c.it + 1,
+            pend_rounds=c.pend_rounds + 1,
+            **_pend_round(c, dsums, dvmin, dvmax, dhist, use_hist),
+            **_account(c, bufs.presence, bufs.presence_total, scan,
+                       **acct_kw))
 
     def flush(bufs, carry: QueryLoopCarry) -> QueryLoopCarry:
         # every dispatch exits fully merged: termination / sync_every
@@ -1147,31 +1167,16 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         """Slot-local coverage / taint / metric accounting for one round
         (twin of the solo loop's accounting block); returns the updated
         SlotCarry fields as a dict."""
-        win, ok, flags, take, new_pos, covmask = scan
         le = lap_ends[s]
-        okc = ok & covmask
-        act_skip = okc & ~(flags & covmask)
-        pres_win = bufs.presence[s][win]
-        tainted = sc.tainted | (pres_win & act_skip[:, None]).any(axis=0)
-        seen_presence = sc.seen_presence + (
-            pres_win & take[:, None]).sum(axis=0, dtype=i32)
-        cov = seen_presence >= bufs.presence_total[s]
-        cov = cov | ((new_pos >= le) & ~tainted)
-        probes = sc.probes
-        if spec.probe:
-            probes = probes + _probe_cost(flags, sc.pos, le, window,
-                                          budget, lookahead, cover_cap)
-        return dict(
-            seen_presence=seen_presence, tainted=tainted,
-            exact=sc.exact | cov,
-            processed=sc.processed.at[win].max(take),
-            blocks_fetched=sc.blocks_fetched + take.sum(dtype=i64),
-            skipped_static=(sc.skipped_static
-                            + (~ok & covmask).sum(dtype=i64)),
-            skipped_active=sc.skipped_active + act_skip.sum(dtype=i64),
-            probes=probes,
-            lap_rounds=jnp.where((sc.pos < le) & (new_pos >= le), k,
-                                 sc.lap_rounds))
+        acct = _account(sc, bufs.presence[s], bufs.presence_total[s], scan,
+                        lim=le, probe=spec.probe, window=window,
+                        budget=budget, lookahead=lookahead,
+                        cover_cap=cover_cap)
+        new_pos = scan[4]
+        with jax.named_scope("account"):
+            acct["lap_rounds"] = jnp.where(
+                (sc.pos < le) & (new_pos >= le), k, sc.lap_rounds)
+        return acct
 
     def _slot_rows(bufs, s, p_end):
         """Rows the slot's cursor has covered, as the f64 ``r`` of its
@@ -1186,6 +1191,54 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
             + bufs.cum_rows[pm1 % nb],
             jnp.asarray(0, i64))
         return (rows_abs - row_offsets[s]).astype(jnp.float64)
+
+    def _slot_refresh(bufs, s, queries, k_s, p_end, state, hist, tainted,
+                      exact, metrics, live, n_live):
+        """Every query of slot ``s``: CI refresh / stop test at slot round
+        ``k_s`` with the slot's cursor at ``p_end``, and the finish-time
+        snapshots (``metrics``: the slot's scan counters) of queries that
+        finish now. A frozen slot (``live`` False) stops refreshing: a
+        lapped slot's solo twin exited the loop at exhaustion, and its
+        still-active queries await the host recovery pass. Returns the
+        new query carries and the pass's live-query count."""
+        le = lap_ends[s]
+        with jax.named_scope("refresh"):
+            r_s = _slot_rows(bufs, s, p_end)
+            out = []
+            for qi, qc in enumerate(queries):
+                nlo, nhi, nest, nrefr, nact = refresh_fns[s][qi](
+                    k_s, r_s, state, hist, tainted, exact, qc.lo, qc.hi,
+                    qc.est, qc.refreshed, qc.active)
+                fin = qc.finished
+                skip = fin | ~live
+                keep = lambda old, new: jnp.where(skip, old, new)
+                active = keep(qc.active, nact)
+                now_fin = live & ~fin & ~active.any()
+                n_live = n_live - now_fin.astype(i32)
+                snap = lambda new, old: jnp.where(now_fin, new, old)
+                out.append(qc._replace(
+                    lo=keep(qc.lo, nlo), hi=keep(qc.hi, nhi),
+                    est=keep(qc.est, nest),
+                    refreshed=keep(qc.refreshed, nrefr),
+                    active=active, finished=fin | now_fin,
+                    stopped_early=snap(p_end < le, qc.stopped_early),
+                    finish_rounds=snap(k_s, qc.finish_rounds),
+                    finish_pos=snap(p_end, qc.finish_pos),
+                    finish_blocks_fetched=snap(
+                        metrics["blocks_fetched"],
+                        qc.finish_blocks_fetched),
+                    finish_skipped_static=snap(
+                        metrics["skipped_static"],
+                        qc.finish_skipped_static),
+                    finish_skipped_active=snap(
+                        metrics["skipped_active"],
+                        qc.finish_skipped_active),
+                    finish_probes=snap(metrics["probes"],
+                                       qc.finish_probes),
+                    snap_counts=snap(state.count, qc.snap_counts),
+                    snap_exact=snap(exact, qc.snap_exact),
+                    snap_tainted=snap(tainted, qc.snap_tainted)))
+        return tuple(out), n_live
 
     def body(bufs, c: PassCarry) -> PassCarry:
         k = c.rounds + 1
@@ -1205,83 +1258,44 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
             slot_live = (sc.pos < le) & any_unfin
             scan = _slot_select(bufs, sc, s, spec, c.queries)
             win, ok, flags, take, new_pos, covmask = scan
-            blk, tvalid, _ = _gather_blocks(take, win, window, budget)
             # Under shard_map the local slab is this shard's row slice
             # of every block, so the slot's global block ids gather
             # exactly the shard's 1/n_shards of its selection.
-            v = bufs.values[s][blk].reshape(-1)
-            g = bufs.gids[s][blk].reshape(-1)
-            m = (bufs.mask[blk]
-                 * tvalid[:, None].astype(jnp.float32)).reshape(-1)
+            v, g, m = _gather_rows(bufs.values[s], bufs.gids[s], bufs.mask,
+                                   take, win, window, budget)
             dstate, dhist = _fold(v, g, m, spec.center, spec.a, spec.b,
                                   spec.num_groups, spec.nbins,
                                   spec.use_hist, impl,
                                   shard_axes=shard.axes if shard else None)
-            state = _merge_f64(sc.state, dstate)
-            hist = (sc.hist + jnp.asarray(dhist, jnp.float64)
-                    if spec.use_hist else sc.hist)
+            state, hist = _merge_round(sc.state, sc.hist, dstate, dhist,
+                                       spec.use_hist)
             acct = _slot_account(bufs, sc, s, spec, k, scan)
             tainted, exact = acct["tainted"], acct["exact"]
 
             frz = lambda new, old: jnp.where(slot_live, new, old)
-            new_slots.append(SlotCarry(
-                pos=frz(new_pos, sc.pos),
-                state=jax.tree.map(frz, state, sc.state),
-                hist=(frz(hist, sc.hist) if spec.use_hist else None),
-                seen_presence=frz(acct["seen_presence"],
-                                  sc.seen_presence),
-                tainted=frz(tainted, sc.tainted),
-                exact=frz(exact, sc.exact),
-                processed=frz(acct["processed"], sc.processed),
-                blocks_fetched=frz(acct["blocks_fetched"],
-                                   sc.blocks_fetched),
-                skipped_static=frz(acct["skipped_static"],
-                                   sc.skipped_static),
-                skipped_active=frz(acct["skipped_active"],
-                                   sc.skipped_active),
-                probes=frz(acct["probes"], sc.probes),
-                lap_rounds=frz(acct["lap_rounds"], sc.lap_rounds)))
+            with jax.named_scope("account"):
+                new_slots.append(SlotCarry(
+                    pos=frz(new_pos, sc.pos),
+                    state=jax.tree.map(frz, state, sc.state),
+                    hist=(frz(hist, sc.hist) if spec.use_hist else None),
+                    seen_presence=frz(acct["seen_presence"],
+                                      sc.seen_presence),
+                    tainted=frz(tainted, sc.tainted),
+                    exact=frz(exact, sc.exact),
+                    processed=frz(acct["processed"], sc.processed),
+                    blocks_fetched=frz(acct["blocks_fetched"],
+                                       sc.blocks_fetched),
+                    skipped_static=frz(acct["skipped_static"],
+                                       sc.skipped_static),
+                    skipped_active=frz(acct["skipped_active"],
+                                       sc.skipped_active),
+                    probes=frz(acct["probes"], sc.probes),
+                    lap_rounds=frz(acct["lap_rounds"], sc.lap_rounds)))
 
-            r_s = _slot_rows(bufs, s, new_pos)
-            k_s = k - round_offsets[s]
-            slot_queries = []
-            for qi, qc in enumerate(c.queries[s]):
-                nlo, nhi, nest, nrefr, nact = refresh_fns[s][qi](
-                    k_s, r_s, state, hist, tainted, exact, qc.lo, qc.hi,
-                    qc.est, qc.refreshed, qc.active)
-                fin = qc.finished
-                # frozen slots stop refreshing (a lapped slot's solo
-                # twin exited the loop at exhaustion); queries still
-                # active there await the host recovery pass
-                skip = fin | ~slot_live
-                lo = jnp.where(skip, qc.lo, nlo)
-                hi = jnp.where(skip, qc.hi, nhi)
-                est = jnp.where(skip, qc.est, nest)
-                refreshed = jnp.where(skip, qc.refreshed, nrefr)
-                active = jnp.where(skip, qc.active, nact)
-                now_fin = slot_live & ~fin & ~active.any()
-                n_live = n_live - now_fin.astype(i32)
-                snap = lambda new, old: jnp.where(now_fin, new, old)
-                slot_queries.append(PassQueryCarry(
-                    lo=lo, hi=hi, est=est, refreshed=refreshed,
-                    active=active, finished=fin | now_fin,
-                    stopped_early=snap(new_pos < le, qc.stopped_early),
-                    finish_rounds=snap(k_s, qc.finish_rounds),
-                    finish_pos=snap(new_pos, qc.finish_pos),
-                    finish_blocks_fetched=snap(
-                        acct["blocks_fetched"],
-                        qc.finish_blocks_fetched),
-                    finish_skipped_static=snap(
-                        acct["skipped_static"],
-                        qc.finish_skipped_static),
-                    finish_skipped_active=snap(
-                        acct["skipped_active"],
-                        qc.finish_skipped_active),
-                    finish_probes=snap(acct["probes"], qc.finish_probes),
-                    snap_counts=snap(state.count, qc.snap_counts),
-                    snap_exact=snap(exact, qc.snap_exact),
-                    snap_tainted=snap(tainted, qc.snap_tainted)))
-            new_queries.append(tuple(slot_queries))
+            slot_queries, n_live = _slot_refresh(
+                bufs, s, c.queries[s], k - round_offsets[s], new_pos, state,
+                hist, tainted, exact, acct, slot_live, n_live)
+            new_queries.append(slot_queries)
 
         return PassCarry(
             rounds=k, it=c.it + 1, n_live=n_live,
@@ -1303,55 +1317,23 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         n_live = c.n_live
         for s, spec in enumerate(slot_specs):
             sc = c.slots[s]
-            sums = jax.lax.psum(sc.pend_sums, shard.axes)
-            vmin, vmax = _pmin_pmax_f64(sc.pend_vmin, sc.pend_vmax,
-                                        shard.axes)
-            dstate = kops.moments_from_sums(sums, vmin, vmax,
-                                            spec.center)
-            state = merge_moments(sc.state, dstate)
-            hist = (sc.hist + jax.lax.psum(sc.pend_hist, shard.axes)
-                    if spec.use_hist else sc.hist)
+            with jax.named_scope("merge"):
+                sums = jax.lax.psum(sc.pend_sums, shard.axes)
+                vmin, vmax = _pmin_pmax_f64(sc.pend_vmin, sc.pend_vmax,
+                                            shard.axes)
+                dstate = kops.moments_from_sums(sums, vmin, vmax,
+                                                spec.center)
+                state = merge_moments(sc.state, dstate)
+                hist = (sc.hist + jax.lax.psum(sc.pend_hist, shard.axes)
+                        if spec.use_hist else sc.hist)
             new_slots.append(sc._replace(
                 state=state, hist=hist,
-                pend_sums=jnp.zeros_like(sc.pend_sums),
-                pend_vmin=jnp.full_like(sc.pend_vmin, jnp.inf),
-                pend_vmax=jnp.full_like(sc.pend_vmax, -jnp.inf),
-                pend_hist=(jnp.zeros_like(sc.pend_hist)
-                           if spec.use_hist else None)))
-            r_s = _slot_rows(bufs, s, sc.pos)
-            k_s = c.rounds - round_offsets[s]
-            slot_queries = []
-            for qi, qc in enumerate(c.queries[s]):
-                nlo, nhi, nest, nrefr, nact = refresh_fns[s][qi](
-                    k_s, r_s, state, hist, sc.tainted, sc.exact,
-                    qc.lo, qc.hi, qc.est, qc.refreshed, qc.active)
-                fin = qc.finished
-                lo = jnp.where(fin, qc.lo, nlo)
-                hi = jnp.where(fin, qc.hi, nhi)
-                est = jnp.where(fin, qc.est, nest)
-                refreshed = jnp.where(fin, qc.refreshed, nrefr)
-                active = jnp.where(fin, qc.active, nact)
-                now_fin = ~fin & ~active.any()
-                n_live = n_live - now_fin.astype(i32)
-                snap = lambda new, old: jnp.where(now_fin, new, old)
-                slot_queries.append(qc._replace(
-                    lo=lo, hi=hi, est=est, refreshed=refreshed,
-                    active=active, finished=fin | now_fin,
-                    stopped_early=snap(sc.pos < lap_ends[s],
-                                       qc.stopped_early),
-                    finish_rounds=snap(k_s, qc.finish_rounds),
-                    finish_pos=snap(sc.pos, qc.finish_pos),
-                    finish_blocks_fetched=snap(
-                        sc.blocks_fetched, qc.finish_blocks_fetched),
-                    finish_skipped_static=snap(
-                        sc.skipped_static, qc.finish_skipped_static),
-                    finish_skipped_active=snap(
-                        sc.skipped_active, qc.finish_skipped_active),
-                    finish_probes=snap(sc.probes, qc.finish_probes),
-                    snap_counts=snap(state.count, qc.snap_counts),
-                    snap_exact=snap(sc.exact, qc.snap_exact),
-                    snap_tainted=snap(sc.tainted, qc.snap_tainted)))
-            new_queries.append(tuple(slot_queries))
+                **_zeroed_pending(sc, spec.use_hist)))
+            slot_queries, n_live = _slot_refresh(
+                bufs, s, c.queries[s], c.rounds - round_offsets[s], sc.pos,
+                state, hist, sc.tainted, sc.exact, sc._asdict(),
+                jnp.asarray(True), n_live)
+            new_queries.append(slot_queries)
         return c._replace(
             n_live=n_live, slots=tuple(new_slots),
             queries=tuple(new_queries),
@@ -1378,44 +1360,37 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
             slot_live = (sc.pos < lap_ends[s]) & any_unfin
             scan = _slot_select(bufs, sc, s, spec, sel_queries)
             win, ok, flags, take, new_pos, covmask = scan
-            blk, tvalid, _ = _gather_blocks(take, win, window, budget)
-            v = bufs.values[s][blk].reshape(-1)
-            g = bufs.gids[s][blk].reshape(-1)
-            m = (bufs.mask[blk]
-                 * tvalid[:, None].astype(jnp.float32)).reshape(-1)
+            v, g, m = _gather_rows(bufs.values[s], bufs.gids[s], bufs.mask,
+                                   take, win, window, budget)
             dsums, dvmin, dvmax, dhist = _fold_local(
                 v, g, m, spec.center, spec.a, spec.b, spec.num_groups,
                 spec.nbins, spec.use_hist, impl)
-            pend_sums = sc.pend_sums + jnp.asarray(dsums, jnp.float64)
-            pend_vmin = jnp.minimum(
-                sc.pend_vmin, jnp.asarray(dvmin, jnp.float64).reshape(-1))
-            pend_vmax = jnp.maximum(
-                sc.pend_vmax, jnp.asarray(dvmax, jnp.float64).reshape(-1))
-            pend_hist = (sc.pend_hist + jnp.asarray(dhist, jnp.float64)
-                         if spec.use_hist else None)
+            pend = _pend_round(sc, dsums, dvmin, dvmax, dhist,
+                               spec.use_hist)
             acct = _slot_account(bufs, sc, s, spec, k, scan)
 
             frz = lambda new, old: jnp.where(slot_live, new, old)
-            new_slots.append(sc._replace(
-                pos=frz(new_pos, sc.pos),
-                seen_presence=frz(acct["seen_presence"],
-                                  sc.seen_presence),
-                tainted=frz(acct["tainted"], sc.tainted),
-                exact=frz(acct["exact"], sc.exact),
-                processed=frz(acct["processed"], sc.processed),
-                blocks_fetched=frz(acct["blocks_fetched"],
-                                   sc.blocks_fetched),
-                skipped_static=frz(acct["skipped_static"],
-                                   sc.skipped_static),
-                skipped_active=frz(acct["skipped_active"],
-                                   sc.skipped_active),
-                probes=frz(acct["probes"], sc.probes),
-                lap_rounds=frz(acct["lap_rounds"], sc.lap_rounds),
-                pend_sums=frz(pend_sums, sc.pend_sums),
-                pend_vmin=frz(pend_vmin, sc.pend_vmin),
-                pend_vmax=frz(pend_vmax, sc.pend_vmax),
-                pend_hist=(frz(pend_hist, sc.pend_hist)
-                           if spec.use_hist else None)))
+            with jax.named_scope("account"):
+                new_slots.append(sc._replace(
+                    pos=frz(new_pos, sc.pos),
+                    seen_presence=frz(acct["seen_presence"],
+                                      sc.seen_presence),
+                    tainted=frz(acct["tainted"], sc.tainted),
+                    exact=frz(acct["exact"], sc.exact),
+                    processed=frz(acct["processed"], sc.processed),
+                    blocks_fetched=frz(acct["blocks_fetched"],
+                                       sc.blocks_fetched),
+                    skipped_static=frz(acct["skipped_static"],
+                                       sc.skipped_static),
+                    skipped_active=frz(acct["skipped_active"],
+                                       sc.skipped_active),
+                    probes=frz(acct["probes"], sc.probes),
+                    lap_rounds=frz(acct["lap_rounds"], sc.lap_rounds),
+                    pend_sums=frz(pend["pend_sums"], sc.pend_sums),
+                    pend_vmin=frz(pend["pend_vmin"], sc.pend_vmin),
+                    pend_vmax=frz(pend["pend_vmax"], sc.pend_vmax),
+                    pend_hist=(frz(pend["pend_hist"], sc.pend_hist)
+                               if spec.use_hist else None)))
 
         return c._replace(
             rounds=k, it=c.it + 1, slots=tuple(new_slots),

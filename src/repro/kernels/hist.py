@@ -110,4 +110,5 @@ def grouped_hist(values: jax.Array, gids: jax.Array, mask: jax.Array,
                                lambda g, k, r: block_index(g, k)),
         out_shape=jax.ShapeDtypeStruct((num_groups, nbins), jnp.float32),
         interpret=interpret,
+        name="grouped_hist",
     )(scale, v2, g2, m2)

@@ -60,7 +60,9 @@ injectable :class:`Clock` and a deterministic event heap. Under
 ``round_cost_s`` per round, and the entire interleaving is captured in
 ``scheduler.log`` — replaying the same workload yields an identical log
 (asserted by ``tests/test_scheduler.py``). :class:`WallClock` swaps in
-real timestamps for production use; nothing in the loop sleeps, and
+real timestamps for production use: a step's finish, log and snapshot
+times are the clock's reading once the step has run (a ticket's latency
+holds the wall time of its steps); nothing in the loop sleeps, and
 deadline events fire through the same heap (requeued behind the next
 actionable event until the wall clock actually reaches them).
 
@@ -547,6 +549,11 @@ class QueryScheduler:
         times the pass's degradation multiplier."""
         return self.round_cost_s * ps.cost_mult
 
+    def _step_end(self, t: float, cost_s: float) -> float:
+        """When a step that started at ``t`` ended: ``t + cost_s`` on a
+        virtual clock, the clock's reading on a real one."""
+        return t + cost_s if self._clock_virtual() else self.clock.now()
+
     def _step_pass(self, t: float, ps: _PassState) -> None:
         r0 = ps.pas.rounds
         hook = self.fault_hook
@@ -564,7 +571,8 @@ class QueryScheduler:
             return
         ps.fails = 0
         ps.steps_since_ckpt += 1
-        t_done = t + (ps.pas.rounds - r0) * self._round_cost(ps)
+        t_done = self._step_end(t, (ps.pas.rounds - r0)
+                                * self._round_cost(ps))
         if skew:
             self._log(t, "skew", round(float(skew), 9))
             t_done += float(skew)
@@ -751,6 +759,7 @@ class QueryScheduler:
 
     def _finish_pass(self, t: float, ps: _PassState) -> None:
         ps.pas.finish()
+        t = self._step_end(t, 0.0)
         for tk in ps.running:
             if tk.status != "running":
                 continue

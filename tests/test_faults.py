@@ -18,9 +18,12 @@ fault never produces a wrong answer, only a later or wider one.
   * fault schedules are pure functions of their seed and the whole
     chaos interleaving replays to an identical event log.
 
-All timing virtual (SimClock) except the wall-clock deadline test,
-which needs real elapsed time to fire the deadline path.
+All timing virtual (SimClock) except the two wall-clock tests: the
+deadline path needs real elapsed time to fire, and the finish stamps are
+read from the real clock.
 """
+
+import time
 
 import numpy as np
 import pytest
@@ -511,6 +514,43 @@ def test_wallclock_deadline_freezes_partial(ds, scramble):
     assert tk.result.stopped_early
     assert_sound(ds, q, tk.result)
     assert "finish-partial" in [ev[2] for ev in sched.log]
+
+
+class _StepTimer:
+    """A fault hook that injects nothing: it pauses every step by
+    ``pause_s`` and records each step's ``(start, end)`` clock readings."""
+
+    def __init__(self, pause_s: float):
+        self.pause_s = pause_s
+        self.steps = []
+
+    def before_step(self, sched, pas, t):
+        self._t0 = sched.clock.now()
+
+    def after_step(self, sched, pas, t):
+        time.sleep(self.pause_s)
+        self.steps.append((self._t0, sched.clock.now()))
+        return None
+
+
+def test_wallclock_finish_is_read_after_the_step(scramble):
+    """Under WallClock a ticket's finish time is the clock's reading once
+    the step that finished it has run, not ``round_cost_s`` per round
+    after the step began: its latency holds the step's wall time, and
+    the log carries the same stamp."""
+    q = AggQuery(agg="avg", column="dep_delay",
+                 stop=AbsoluteWidth(eps=2.0), delta=1e-9)
+    hook = _StepTimer(pause_s=0.02)
+    sched = QueryScheduler(FrameServer(fresh_frame(scramble)), WallClock(),
+                           seed=1, round_cost_s=1e-25, fault_hook=hook)
+    tk = sched.submit(q)
+    sched.run_until_idle()
+    assert tk.status == "done" and hook.steps
+    start, end = [st for st in hook.steps if st[0] <= tk.finish_t][-1]
+    assert tk.finish_t >= end
+    assert tk.latency >= end - start >= hook.pause_s
+    assert (round(tk.finish_t, 9), "finish") in [(ev[0], ev[2])
+                                                 for ev in sched.log]
 
 
 def test_simclock_deadline_rejects_queued(scramble):

@@ -126,3 +126,46 @@ def test_query_loop_compiles(one_chip, x64, bounder):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "f64" in text
+
+
+@pytest.mark.parametrize("kernel", sorted(FOLDS))
+def test_fold_kernel_carries_its_name(one_chip, kernel):
+    """Each Pallas kernel names itself (as ``FOLDS`` keys it) in the
+    lowered program, so the chip's trace and its compiler logs tell the
+    kernels apart."""
+    low = jax.jit(FOLDS[kernel](GROUPS[0])).lower(
+        _shape(one_chip, (ROWS,), jnp.float32),
+        _shape(one_chip, (ROWS,), jnp.int32),
+        _shape(one_chip, (ROWS,), jnp.float32))
+    assert f'kernel_name = "{kernel}"' in low.as_text()
+
+
+@pytest.mark.parametrize("bounder, kernels", [
+    ("bernstein", ("active_blocks", "block_agg")),
+    ("anderson_dkw", ("active_blocks", "fused_fold")),
+])
+def test_query_loop_names_scopes_and_kernels(one_chip, x64, bounder,
+                                             kernels):
+    """The lowered f64 round loop, as the chip runs it: every operation's
+    name stack holds its phase (``fused_scan.SCOPES``), and the Pallas
+    kernels it calls carry their names."""
+    ds = flights.generate(n_rows=64 * 1024, seed=0)
+    sc = build_scramble(ds.columns, catalog=ds.catalog, block_rows=1024,
+                        seed=1)
+    frame = FastFrame(sc, EngineConfig(impl="pallas", shard_rows=False))
+    q = fq.f_q9(bounder=bounder, rangetrim=bounder == "bernstein")
+    slot = engine._ScanViews(frame, q)
+    qci = engine._QueryIntervals(frame, q, slot)
+    loop = engine._DeviceLoop(frame, q, slot, qci, probe=True,
+                              lookahead=1024, max_rounds=100_000)
+    order = np.arange(sc.n_blocks)
+    loop.set_order(order, np.cumsum(frame._valid_counts[order]))
+    as_shapes = lambda tree: jax.tree.map(
+        lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+    text = loop._chunk_fn.lower(as_shapes(loop.bufs),
+                                as_shapes(loop.init_carry(slot, qci))
+                                ).as_text(debug_info=True)
+    for scope in fused_scan.SCOPES:
+        assert f"/{scope}/" in text, scope
+    for name in kernels:
+        assert f'kernel_name = "{name}"' in text, name

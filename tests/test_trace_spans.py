@@ -1,0 +1,137 @@
+"""The engine's own tracing: the host spans of ``FastFrame.run`` and the
+named scopes of the device round loops.
+
+Spans are ``jax.profiler.TraceAnnotation`` events, recorded here under a
+CPU profiler trace and read back with ``jax.profiler.ProfileData``.
+Scopes are ``jax.named_scope`` components of every HLO ``op_name``,
+checked in the lowered text of each round loop the engine builds: the
+single-query loop, its collective-cadence body (over a one-device mesh)
+and the serving pass loop.
+"""
+
+import re
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from repro.aqp import EngineConfig, FastFrame, build_scramble, engine
+from repro.aqp import flights_queries as fq
+from repro.aqp.distributed import build_block_shards
+from repro.data import flights
+from repro.kernels import fused_scan as kfused
+from repro.serve import FrameServer
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _x64(x64_module):
+    yield
+
+
+@pytest.fixture(scope="module")
+def scramble():
+    ds = flights.generate(n_rows=48 * 1024, seed=3)
+    return build_scramble(ds.columns, catalog=ds.catalog, block_rows=1024,
+                          seed=5)
+
+
+def _frame(scramble, **cfg):
+    return FastFrame(scramble, EngineConfig(round_blocks=4, **cfg))
+
+
+def _aqp_spans(log_dir):
+    """``[(name, start_ns, end_ns)]`` of every ``aqp:`` and ``test:``
+    event of the profile under ``log_dir``, by start."""
+    from jax.profiler import ProfileData
+
+    path = sorted(Path(log_dir).rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("aqp:", "test:")):
+                    out.append((e.name, e.start_ns, e.end_ns))
+    return sorted(out, key=lambda x: x[1])
+
+
+@pytest.mark.parametrize("mode, children", [
+    ("device_loop", ("aqp:views", "aqp:upload", "aqp:loop", "aqp:writeback",
+                     "aqp:recovery", "aqp:result")),
+    ("host_loop", ("aqp:views", "aqp:recovery", "aqp:result")),
+    ("exact", ("aqp:views", "aqp:result")),
+])
+def test_run_records_its_host_spans(scramble, tmp_path, mode, children):
+    frame = _frame(scramble, device_loop=mode == "device_loop")
+    q = fq.f_q9()
+    sampling = "exact" if mode == "exact" else "active_peek"
+    frame.run(q, sampling=sampling, start_block=0)   # compile untraced
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("test:caller"):
+            frame.run(q, sampling=sampling, start_block=7)
+    spans = _aqp_spans(tmp_path)
+    names = [n for n, _, _ in spans]
+    assert names == ["test:caller", "aqp:run", *children]
+    (_, c0, c1), (_, r0, r1) = spans[:2]
+    assert c0 <= r0 <= r1 <= c1
+    prev = r0
+    for _, s, e in spans[2:]:
+        assert prev <= s <= e <= r1
+        prev = e
+
+
+def _spy(monkeypatch, build_fn: str) -> list:
+    """Replace ``kfused.<build_fn>`` with one whose loops record their
+    lowered text (with the name stacks) at their first call."""
+    texts = []
+    build = getattr(kfused, build_fn)
+
+    def spied(**kw):
+        fn = build(**kw)
+
+        def call(*args):
+            if not texts:
+                texts.append(fn.lower(*args).as_text(debug_info=True))
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(kfused, build_fn, spied)
+    return texts
+
+
+def _scopes_in(text: str) -> set:
+    return {s for s in kfused.SCOPES if re.search(f"/{s}/", text)}
+
+
+def test_query_loop_carries_every_scope(scramble, monkeypatch):
+    texts = _spy(monkeypatch, "build_query_loop")
+    _frame(scramble, device_loop=True).run(fq.f_q9(), start_block=0)
+    assert _scopes_in(texts[0]) == set(kfused.SCOPES)
+
+
+def test_cadence_loop_carries_every_scope(scramble):
+    """The collective-cadence body (``merge_every`` > 1) over a
+    one-device mesh: the same scopes, ``merge`` around the pending-slot
+    collectives."""
+    frame = _frame(scramble, device_loop=True)
+    q = fq.f_q9()
+    slot = engine._ScanViews(frame, q)
+    qci = engine._QueryIntervals(frame, q, slot)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("shards",))
+    shards = build_block_shards(scramble.n_blocks, mesh, 1024, merge_every=2)
+    loop = engine._DeviceLoop(frame, q, slot, qci, probe=True,
+                              lookahead=1024, max_rounds=100_000,
+                              shards=shards)
+    order = np.arange(scramble.n_blocks)
+    loop.set_order(order, np.cumsum(frame._valid_counts[order]))
+    text = loop._chunk_fn.lower(loop.bufs, loop.init_carry(slot, qci)
+                                ).as_text(debug_info=True)
+    assert _scopes_in(text) == set(kfused.SCOPES)
+
+
+def test_pass_loop_carries_every_scope(scramble, monkeypatch):
+    texts = _spy(monkeypatch, "build_pass_loop")
+    server = FrameServer(_frame(scramble, device_loop=True))
+    server.run_batch([fq.f_q9(), fq.f_q2(8.0)], start_block=0)
+    assert _scopes_in(texts[0]) == set(kfused.SCOPES)
