@@ -29,11 +29,13 @@ def unpack_words(words: np.ndarray, cardinality: int) -> np.ndarray:
     """Inverse of the word packing: ``(B, W)`` uint32 words -> ``(B, C)``
     bool presence matrix. The engine uses this to turn a group bitmap
     into the per-block view-presence matrix that drives taint accounting
-    and exactness tracking."""
+    and exactness tracking. The bits are unpacked straight into the
+    ``(B, C)`` result (``count=``) and read as bool in place: no wider
+    intermediate and no copy."""
     u8 = words.astype("<u4").view(np.uint8)
     bits = np.unpackbits(u8.reshape(words.shape[0], -1), axis=1,
-                         bitorder="little")
-    return bits[:, :cardinality].astype(bool)
+                         count=cardinality, bitorder="little")
+    return bits.view(bool)
 
 
 def pack_mask(mask: np.ndarray) -> np.ndarray:

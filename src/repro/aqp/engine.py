@@ -371,7 +371,8 @@ class _ScanViews:
         self.presence = (unpack_words(self.group_bm.words, self.G)
                          if self.group_bm is not None
                          else np.ones((sc.n_blocks, 1), dtype=bool))
-        self.presence_total = self.presence.sum(axis=0)
+        # block counts fit int32, whose accumulator halves the pass
+        self.presence_total = self.presence.sum(axis=0, dtype=np.int32)
         self.valid = self.presence_total > 0
         self.state = init_moments_host((self.G,))
         self.hist = (np.zeros((self.G, frame.config.hist_bins), np.float64)

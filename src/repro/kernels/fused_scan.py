@@ -321,7 +321,9 @@ def _budget_select(flags: jax.Array, pos: jax.Array, nb, window: int,
     ``nb`` is the cursor limit — the static block count for a plain scan,
     or a traced i32 horizon for a carousel pass whose cursor runs past
     the scramble length (late joiners walk a wrapped lap).
-    Returns ``(take mask over the window, new_pos)``."""
+    Returns ``(take mask over the window, csum, new_pos)``, ``csum``
+    being the running count of ``flags`` that ``_gather_blocks``
+    compacts."""
     csum = jnp.cumsum(flags.astype(jnp.int32))
     take = flags & (csum <= budget)
     n_sel = csum[window - 1]
@@ -329,27 +331,37 @@ def _budget_select(flags: jax.Array, pos: jax.Array, nb, window: int,
     covered = jnp.where(n_sel >= budget, cut + 1,
                         jnp.minimum(jnp.int32(window),
                                     jnp.asarray(nb, jnp.int32) - pos))
-    return take, pos + covered
+    return take, csum, pos + covered
 
 
-def _gather_blocks(take: jax.Array, win: jax.Array, window: int,
+def _gather_blocks(csum: jax.Array, win: jax.Array, window: int,
                    budget: int):
     """Selected window positions -> padded block ids + padding-lane mask
     + window position per lane. Padding lanes point at block 0 with
     ``tvalid`` False (their rows are masked out of the fold) and
-    ``take_idx`` = window."""
-    take_idx = jnp.nonzero(take, size=budget, fill_value=window)[0]
+    ``take_idx`` = window.
+
+    ``csum`` is ``_budget_select``'s running count of the flags. Lane
+    ``j`` reads the ``j``-th selected position, which is the number of
+    positions whose count is at most ``j`` (``window`` once fewer than
+    ``j + 1`` are flagged): a dense ``budget x window`` compare and sum,
+    equal to ``jnp.nonzero(take, size=budget, fill_value=window)``.
+    ``nonzero`` lowers to a scatter-add of every window position into
+    ``budget`` bins, whose colliding updates the TPU runs one by one."""
+    lanes = jnp.arange(budget, dtype=jnp.int32)
+    take_idx = (csum[None, :] <= lanes[:, None]).sum(axis=1,
+                                                      dtype=jnp.int32)
     tvalid = take_idx < window
     blk = jnp.where(tvalid, win[jnp.minimum(take_idx, window - 1)], 0)
     return blk, tvalid, take_idx
 
 
-def _gather_rows(values, gids, mask, take, win, window: int, budget: int):
+def _gather_rows(values, gids, mask, csum, win, window: int, budget: int):
     """The round's rows: the selected blocks' ids (``_gather_blocks``) and
     the flattened ``(budget * block_rows,)`` value, group-code and mask
     rows gathered from the device slabs, padding lanes masked out."""
     with jax.named_scope("gather"):
-        blk, tvalid, _ = _gather_blocks(take, win, window, budget)
+        blk, tvalid, _ = _gather_blocks(csum, win, window, budget)
         v = values[blk].reshape(-1)
         g = gids[blk].reshape(-1)
         m = (mask[blk] * tvalid[:, None].astype(jnp.float32)).reshape(-1)
@@ -397,8 +409,8 @@ def fused_round(values: jax.Array, gids: jax.Array, mask: jax.Array,
     else:
         flags = ok
 
-    take, new_pos = _budget_select(flags, pos, nb, window, budget)
-    v, g, m = _gather_rows(values, gids, mask, take, win, window, budget)
+    _, csum, new_pos = _budget_select(flags, pos, nb, window, budget)
+    v, g, m = _gather_rows(values, gids, mask, csum, win, window, budget)
 
     state, hist = _fold(v, g, m, center, a, b, num_groups, nbins,
                         use_hist, impl)
@@ -478,8 +490,8 @@ def fused_round_multi(mask: jax.Array, order_pad: jax.Array,
                                        impl=impl) > 0
         fl = ok[None, :] & act
         flags = fl.any(axis=0)
-        take, new_p = _budget_select(flags, p, le, window, budget)
-        v, g, m = _gather_rows(values[s], gids[s], mask, take, win, window,
+        _, csum, new_p = _budget_select(flags, p, le, window, budget)
+        v, g, m = _gather_rows(values[s], gids[s], mask, csum, win, window,
                                budget)
         st, h = _fold(v, g, m, center, a, b, num_groups, nbins,
                       use_hist, impl)
@@ -645,9 +657,10 @@ def _round_scan(bufs, pos, flags_src, *, nb: int, window: int,
         win = jax.lax.dynamic_slice(bufs.order_pad, (start,), (window,))
         ok = bufs.static_ok[win] & in_range
         flags = flags_src(ok, win)
-        take, new_pos = _budget_select(flags, pos, lim, window, budget)
+        take, csum, new_pos = _budget_select(flags, pos, lim, window,
+                                             budget)
         covmask = offs < (new_pos - pos)
-    return win, ok, flags, take, new_pos, covmask
+    return win, ok, flags, take, csum, new_pos, covmask
 
 
 def _account(c, presence, presence_total, scan, *, lim, probe: bool,
@@ -658,7 +671,7 @@ def _account(c, presence, presence_total, scan, *, lim, probe: bool,
     ``_ScanViews.ingest_delta`` and ``_ScanViews.update_exact``) from
     ``_round_scan``'s tuple ``scan``; ``lim`` is the cursor limit.
     Returns the updated carry fields."""
-    win, ok, flags, take, new_pos, covmask = scan
+    win, ok, flags, take, _, new_pos, covmask = scan
     i64 = jnp.int64
     with jax.named_scope("account"):
         act_skip = ok & covmask & ~(flags & covmask)
@@ -798,11 +811,11 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
     def body(bufs, c: QueryLoopCarry) -> QueryLoopCarry:
         k = c.rounds + 1
         scan = scan_round(bufs, c.pos, c.active)
-        win, ok, flags, take, new_pos, covmask = scan
+        win, ok, flags, take, csum, new_pos, covmask = scan
         # Under shard_map the local slab is this shard's row slice of
         # every block, so the global block ids gather exactly the
         # shard's 1/n_shards of the selection — no translation needed.
-        v, g, m = _gather_rows(bufs.values, bufs.gids, bufs.mask, take, win,
+        v, g, m = _gather_rows(bufs.values, bufs.gids, bufs.mask, csum, win,
                                window, budget)
         dstate, dhist = _fold(v, g, m, center, a, b, num_groups, nbins,
                               use_hist, impl,
@@ -854,8 +867,8 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
                          functools.partial(_merge_refresh, bufs),
                          lambda x: x, c)
         scan = scan_round(bufs, c.pos, sel_active)
-        win, ok, flags, take, new_pos, covmask = scan
-        v, g, m = _gather_rows(bufs.values, bufs.gids, bufs.mask, take, win,
+        win, ok, flags, take, csum, new_pos, covmask = scan
+        v, g, m = _gather_rows(bufs.values, bufs.gids, bufs.mask, csum, win,
                                window, budget)
         dsums, dvmin, dvmax, dhist = _fold_local(
             v, g, m, center, a, b, num_groups, nbins, use_hist, impl)
@@ -1172,7 +1185,7 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
                         lim=le, probe=spec.probe, window=window,
                         budget=budget, lookahead=lookahead,
                         cover_cap=cover_cap)
-        new_pos = scan[4]
+        new_pos = scan[5]
         with jax.named_scope("account"):
             acct["lap_rounds"] = jnp.where(
                 (sc.pos < le) & (new_pos >= le), k, sc.lap_rounds)
@@ -1257,12 +1270,12 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
             # without selecting — spuriously tainting the views)
             slot_live = (sc.pos < le) & any_unfin
             scan = _slot_select(bufs, sc, s, spec, c.queries)
-            win, ok, flags, take, new_pos, covmask = scan
+            win, ok, flags, take, csum, new_pos, covmask = scan
             # Under shard_map the local slab is this shard's row slice
             # of every block, so the slot's global block ids gather
             # exactly the shard's 1/n_shards of its selection.
             v, g, m = _gather_rows(bufs.values[s], bufs.gids[s], bufs.mask,
-                                   take, win, window, budget)
+                                   csum, win, window, budget)
             dstate, dhist = _fold(v, g, m, spec.center, spec.a, spec.b,
                                   spec.num_groups, spec.nbins,
                                   spec.use_hist, impl,
@@ -1359,9 +1372,9 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
                 jnp.logical_or, [~qc.finished for qc in c.queries[s]])
             slot_live = (sc.pos < lap_ends[s]) & any_unfin
             scan = _slot_select(bufs, sc, s, spec, sel_queries)
-            win, ok, flags, take, new_pos, covmask = scan
+            win, ok, flags, take, csum, new_pos, covmask = scan
             v, g, m = _gather_rows(bufs.values[s], bufs.gids[s], bufs.mask,
-                                   take, win, window, budget)
+                                   csum, win, window, budget)
             dsums, dvmin, dvmax, dhist = _fold_local(
                 v, g, m, spec.center, spec.a, spec.b, spec.num_groups,
                 spec.nbins, spec.use_hist, impl)

@@ -6,7 +6,7 @@ import pytest
 
 from repro.aqp import (AggQuery, EngineConfig, Expression, FastFrame, Filter,
                        build_scramble)
-from repro.aqp.bitmap import build_bitmap, pack_mask
+from repro.aqp.bitmap import build_bitmap, pack_mask, unpack_words
 from repro.aqp.flights_queries import f_q1, f_q2, f_q5, f_q8, f_q9
 from repro.aqp.scramble import build_scramble
 from repro.core.optstop import (AbsoluteWidth, GroupsOrdered, ThresholdSide,
@@ -79,6 +79,16 @@ def test_pack_mask_roundtrip():
     words = pack_mask(mask)
     for c in range(77):
         assert bool((words[c // 32] >> (c % 32)) & 1) == bool(mask[c])
+
+
+@pytest.mark.parametrize("cardinality", [1, 32, 77, 200])
+def test_unpack_words_inverts_the_packing(cardinality):
+    rng = np.random.default_rng(cardinality)
+    masks = rng.random((9, cardinality)) < 0.3
+    words = np.stack([pack_mask(m) for m in masks])
+    got = unpack_words(words, cardinality)
+    assert got.dtype == bool and got.shape == masks.shape
+    np.testing.assert_array_equal(got, masks)
 
 
 # -- engine: exact mode --------------------------------------------------------

@@ -178,6 +178,37 @@ def test_fused_fold_matches_oracles():
                                np.asarray(want_h.hist))
 
 
+@pytest.mark.parametrize("n_flagged", ["none", "one", "budget-1", "budget",
+                                       "all"])
+@pytest.mark.parametrize("budget", [1, 64])
+@pytest.mark.parametrize("window", [64, 1024, 4096])
+def test_gather_compaction_equals_nonzero(window, budget, n_flagged):
+    """The round's compaction of the budgeted take into padded window
+    positions is ``jnp.nonzero(take, size=budget, fill_value=window)``
+    exactly, including a window whose flags all set (take cut at the
+    budget) and one with none."""
+    from repro.kernels import fused_scan
+
+    n = {"none": 0, "one": 1, "budget-1": budget - 1, "budget": budget,
+         "all": window}[n_flagged]
+    rng = np.random.default_rng(window + budget + n)
+    flags = np.zeros(window, bool)
+    flags[rng.choice(window, size=n, replace=False)] = True
+    win = jnp.asarray(rng.permutation(window).astype(np.int32))
+    take, csum, _ = fused_scan._budget_select(
+        jnp.asarray(flags), jnp.int32(0), window, window, budget)
+    blk, tvalid, take_idx = fused_scan._gather_blocks(csum, win, window,
+                                                      budget)
+    want = np.asarray(jnp.nonzero(take, size=budget, fill_value=window)[0])
+    assert take_idx.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(take_idx), want)
+    np.testing.assert_array_equal(np.asarray(tvalid), want < window)
+    np.testing.assert_array_equal(
+        np.asarray(blk),
+        np.where(want < window, np.asarray(win)[np.minimum(want, window - 1)],
+                 0))
+
+
 def test_fused_round_interpret_engine_close_to_ref():
     """The engine driven through the fused superkernel (interpret) agrees
     with the ref backend within f32 tile-order tolerance."""

@@ -81,7 +81,7 @@ def test_run_records_its_host_spans(scramble, tmp_path, mode, children):
         prev = e
 
 
-def _spy(monkeypatch, build_fn: str) -> list:
+def _spy(monkeypatch, build_fn: str, dialect: str = "stablehlo") -> list:
     """Replace ``kfused.<build_fn>`` with one whose loops record their
     lowered text (with the name stacks) at their first call."""
     texts = []
@@ -92,7 +92,8 @@ def _spy(monkeypatch, build_fn: str) -> list:
 
         def call(*args):
             if not texts:
-                texts.append(fn.lower(*args).as_text(debug_info=True))
+                texts.append(fn.lower(*args).as_text(dialect=dialect,
+                                                     debug_info=True))
             return fn(*args)
         return call
 
@@ -135,3 +136,26 @@ def test_pass_loop_carries_every_scope(scramble, monkeypatch):
     server = FrameServer(_frame(scramble, device_loop=True))
     server.run_batch([fq.f_q9(), fq.f_q2(8.0)], start_block=0)
     assert _scopes_in(texts[0]) == set(kfused.SCOPES)
+
+
+_SCATTER_OP_NAME = re.compile(r' scatter\(.*op_name="([^"]*)"')
+
+
+@pytest.mark.parametrize("build_fn", ["build_query_loop", "build_pass_loop"])
+def test_gather_scope_holds_no_scatter(scramble, monkeypatch, build_fn):
+    """The round's selected blocks are compacted without a scatter: the
+    TPU runs a scatter's colliding updates one by one, and
+    ``jnp.nonzero``'s scatter-add over the window cost more per round
+    than the fold. No HLO scatter of either round loop carries the
+    ``gather`` scope."""
+    texts = _spy(monkeypatch, build_fn, dialect="hlo")
+    frame = _frame(scramble, device_loop=True)
+    if build_fn == "build_query_loop":
+        frame.run(fq.f_q9(), start_block=0)
+    else:
+        FrameServer(frame).run_batch([fq.f_q9(), fq.f_q2(8.0)],
+                                     start_block=0)
+    assert "/gather/" in texts[0]
+    in_gather = [n for n in _SCATTER_OP_NAME.findall(texts[0])
+                 if "/gather/" in n]
+    assert in_gather == []

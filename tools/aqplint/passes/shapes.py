@@ -73,7 +73,7 @@ def _check_size(mod, f, node: ast.Call, findings: List[Finding]) -> None:
         message=(f"data-dependent-shape call `{leaf}` without `size=` "
                  "in jit-traced code — errors under jit, or retraces "
                  "per distinct count at the jit boundary; pass "
-                 "size=/fill_value= like _gather_blocks does")))
+                 "size=/fill_value=")))
 
 
 # -- AQP502 ------------------------------------------------------------------
