@@ -111,24 +111,52 @@ def test_query_loop_carries_every_scope(scramble, monkeypatch):
     assert _scopes_in(texts[0]) == set(kfused.SCOPES)
 
 
-def test_cadence_loop_carries_every_scope(scramble):
-    """The collective-cadence body (``merge_every`` > 1) over a
-    one-device mesh: the same scopes, ``merge`` around the pending-slot
-    collectives."""
+def _sharded_lowered(scramble, q, merge_every: int):
+    """The sharded round loop of ``q`` over a one-device mesh, lowered."""
     frame = _frame(scramble, device_loop=True)
-    q = fq.f_q9()
     slot = engine._ScanViews(frame, q)
     qci = engine._QueryIntervals(frame, q, slot)
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("shards",))
-    shards = build_block_shards(scramble.n_blocks, mesh, 1024, merge_every=2)
+    shards = build_block_shards(scramble.n_blocks, mesh, 1024,
+                                merge_every=merge_every)
     loop = engine._DeviceLoop(frame, q, slot, qci, probe=True,
                               lookahead=1024, max_rounds=100_000,
                               shards=shards)
     order = np.arange(scramble.n_blocks)
     loop.set_order(order, np.cumsum(frame._valid_counts[order]))
-    text = loop._chunk_fn.lower(loop.bufs, loop.init_carry(slot, qci)
-                                ).as_text(debug_info=True)
+    return loop._chunk_fn.lower(loop.bufs, loop.init_carry(slot, qci))
+
+
+def test_cadence_loop_carries_every_scope(scramble):
+    """The collective-cadence body (``merge_every`` > 1) over a
+    one-device mesh: the same scopes, ``merge`` around the pending-slot
+    collectives."""
+    text = _sharded_lowered(scramble, fq.f_q9(), 2).as_text(debug_info=True)
     assert _scopes_in(text) == set(kfused.SCOPES)
+
+
+_COLLECTIVE = re.compile(
+    r"= .*?\b(?:all-reduce|all-gather|reduce-scatter|collective-permute"
+    r"|all-to-all)(?:-start|-done)?\(.*")
+
+
+@pytest.mark.parametrize("merge_every", [1, 2])
+@pytest.mark.parametrize("bounder", ["bernstein", "anderson_dkw"])
+def test_sharded_collectives_are_in_the_merge_scope(scramble, merge_every,
+                                                    bounder):
+    """Every collective of the sharded round loop, in the optimized HLO
+    (the all-reduces stay there over a one-device mesh), carries the
+    ``merge`` scope, so a trace's collective time is the merge's: at
+    ``merge_every`` 1 one ``psum``, ``pmin`` and ``pmax`` a round, the
+    histogram's ``psum`` combined with the sums'."""
+    q = fq.f_q9(bounder=bounder, rangetrim=bounder == "bernstein")
+    text = _sharded_lowered(scramble, q, merge_every).compile().as_text()
+    found = _COLLECTIVE.findall(text)
+    assert found
+    if merge_every == 1:
+        assert len(found) == 3
+    for line in found:
+        assert re.search(r'op_name="[^"]*/merge/', line), line
 
 
 def test_pass_loop_carries_every_scope(scramble, monkeypatch):
