@@ -368,11 +368,8 @@ class _ScanViews:
         self.static_ok, self.probes0 = frame._static_ok(q)
         self.group_bm = (frame.bitmap(self.gcol) if self.gcol is not None
                          else None)
-        self.presence = (unpack_words(self.group_bm.words, self.G)
-                         if self.group_bm is not None
-                         else np.ones((sc.n_blocks, 1), dtype=bool))
-        # block counts fit int32, whose accumulator halves the pass
-        self.presence_total = self.presence.sum(axis=0, dtype=np.int32)
+        self.presence, self.presence_total = frame._group_presence(
+            self.gcol, self.G)
         self.valid = self.presence_total > 0
         self.state = init_moments_host((self.G,))
         self.hist = (np.zeros((self.G, frame.config.hist_bins), np.float64)
@@ -748,14 +745,17 @@ class _DeviceLoop:
         # the jitted loop compiles once per query shape). When sharded,
         # the three data slabs are row-sharded over the mesh and every
         # other buffer is placed replicated.
+        # The block-id buffers are padded here, on the host, once per
+        # loop: see kfused.pad_blocks.
         rep = lambda a: adist.place_replicated(shards, a)
+        pad = lambda a: rep(kfused.pad_blocks(a, window))
         self._base_bufs = kfused.QueryLoopBuffers(
             values=frame._device_values(slot.value_src, shards),
             gids=frame._device_gids(slot.gcol, shards),
             mask=frame._device_mask(q.filters, shards),
-            words=rep(words),
-            order_pad=None, static_ok=rep(slot.static_ok),
-            presence=rep(slot.presence),
+            words=pad(words) if probe else rep(words),
+            order_pad=None, static_ok=pad(slot.static_ok),
+            presence=pad(slot.presence),
             presence_total=rep(slot.presence_total.astype(np.int32)),
             cum_rows=None)
         refresh_fn = _make_device_refresh(
@@ -772,13 +772,13 @@ class _DeviceLoop:
             refresh_fn=refresh_fn,
             shard=shards.info if shards is not None else None)
 
-    def set_order(self, order: np.ndarray, cum_rows: np.ndarray) -> None:
-        """Install this run's scan order (the only run-dependent input)."""
-        opad = np.zeros(self.nb + self.window, np.int32)
-        opad[:self.nb] = order
+    def set_order(self, start: int, cum_rows: np.ndarray) -> None:
+        """Install this run's scan order, the rotation starting at block
+        ``start`` (the only run-dependent input)."""
         rep = lambda a: adist.place_replicated(self.shards, a)
         self.bufs = self._base_bufs._replace(
-            order_pad=rep(opad),
+            order_pad=rep(kfused.rotation_order_pad(start, self.nb,
+                                                    self.window)),
             cum_rows=rep(cum_rows.astype(np.int64)))
 
     def init_carry(self, slot: _ScanViews,
@@ -885,6 +885,8 @@ class FastFrame:
         # the traced lax.while_loop instead of recompiling per run.
         # Public: the serving layer hangs its compiled pass loops here.
         self.device_loops = LRUCache(cap)
+        # host (blocks × groups) presence matrices by group column
+        self._presence = LRUCache(cap)
         self._block_shards: Optional[adist.BlockShards] = None
         self._shards_resolved = False
 
@@ -911,6 +913,27 @@ class FastFrame:
         if column not in self._bitmaps:
             self._bitmaps[column] = build_bitmap(self.scramble, column)
         return self._bitmaps[column]
+
+    def _group_presence(self, gcol: Optional[str], G: int
+                        ) -> Tuple[np.ndarray, np.ndarray]:
+        """The read-only ``(n_blocks, G)`` bool presence matrix of group
+        column ``gcol`` (one all-true view without a GROUP BY) and its
+        per-group block counts. Both depend on the column alone, so they
+        are cached: unpacking a 606M-row table's 200-group bitmap and
+        summing it takes some 0.18 s of host time a query."""
+
+        def build():
+            if gcol is None:
+                presence = np.ones((self.scramble.n_blocks, 1), dtype=bool)
+            else:
+                presence = unpack_words(self.bitmap(gcol).words, G)
+            # block counts fit int32, whose accumulator halves the pass
+            total = presence.sum(axis=0, dtype=np.int32)
+            presence.setflags(write=False)
+            total.setflags(write=False)
+            return presence, total
+
+        return self._presence.get_or_build(gcol, build)
 
     def _composite_group(self, cols: Tuple[str, ...]) -> Tuple[str, int]:
         """Synthesize (and cache) a composite group-code column.
@@ -1304,10 +1327,10 @@ class FastFrame:
 
         def scan_order():
             # random start, wrap around (paper §5.2)
-            start = (rng.integers(nb) if start_block is None
-                     else start_block)
+            start = int(rng.integers(nb) if start_block is None
+                        else start_block) % nb
             order = (start + np.arange(nb)) % nb
-            return order, np.cumsum(self._valid_counts[order])
+            return start, order, np.cumsum(self._valid_counts[order])
 
         with TraceAnnotation("aqp:views"):
             slot = _ScanViews(self, q)
@@ -1329,7 +1352,7 @@ class FastFrame:
             # OptStop loop in lax.while_loop dispatches; one host sync
             # per chunk, full writeback at termination -----------------
             with TraceAnnotation("aqp:upload"):
-                order, cum_rows = scan_order()
+                start, _, cum_rows = scan_order()
                 probe = skipping and slot.group_bm is not None
                 shards = self.block_shards()
                 key = ("run", q.scan_signature(), q.agg, q.bounder,
@@ -1343,7 +1366,7 @@ class FastFrame:
                     key,
                     lambda: _DeviceLoop(self, q, slot, qci, probe,
                                         lookahead, max_rounds, shards))
-                dloop.set_order(order, cum_rows)
+                dloop.set_order(start, cum_rows)
                 carry = dloop.init_carry(slot, qci)
             with TraceAnnotation("aqp:loop"):
                 carry = dloop.run(carry, on_sync)
@@ -1360,7 +1383,7 @@ class FastFrame:
                 return qci.result(rounds, pos, cum_rows, metrics, t0,
                                   stopped_early)
 
-        order, cum_rows = scan_order()
+        _, order, cum_rows = scan_order()
         active_words = (jnp.asarray(pack_mask(qci.active))
                         if slot.gcol is not None else None)
         fscan = None

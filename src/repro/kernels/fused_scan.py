@@ -105,6 +105,7 @@ from typing import Callable, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -578,23 +579,88 @@ def _probe_cost(flags: jax.Array, pos: jax.Array, nb: int, window: int,
     csum_excl = jnp.concatenate([jnp.zeros(1, i32), csum[:-1]])
     n_batches = -(-window // lookahead)
     starts = jnp.arange(n_batches, dtype=i32) * lookahead
-    probed = ((csum_excl[starts] < budget) & (starts < win_len)
+    # each batch's first position: a strided slice (indexing with
+    # ``starts`` would lower to a gather)
+    at_starts = jax.lax.slice(csum_excl, (0,), (window,), (lookahead,))
+    probed = ((at_starts < budget) & (starts < win_len)
               & (starts < cover_cap))
     ends = jnp.minimum(starts + lookahead, win_len)
     return jnp.where(probed, ends - starts, 0).sum().astype(jnp.int64)
 
 
+# -- the round's window as slices ---------------------------------------------
+#
+# Every scan order is a rotation, ``order = (start + arange(nb)) % nb``, so
+# a round's window of block ids is ``(b0 + i) % nb`` for every in-range
+# lane ``i``, ``b0`` being the block at the cursor: one contiguous range
+# of block ids that wraps at most once. The loops' block-id buffers
+# (static verdicts, bitmap words, presence) are uploaded with ``window``
+# rows appended, row ``nb + i`` repeating row ``i % nb`` (:func:`pad_blocks`),
+# so each window read is one ``dynamic_slice`` at ``b0`` and the
+# ``processed`` update one ``dynamic_update_slice``. Lanes past the
+# cursor limit read some other block's row; every use of them is masked
+# by ``ok`` / ``take`` / ``covmask``, which are false there. At most
+# ``nb`` lanes are in range, so in-range lanes never repeat a block.
+
+
+def pad_blocks(x: np.ndarray, window: int) -> np.ndarray:
+    """Host-side block-id buffer (leading axis ``nb``) with ``window``
+    rows appended, row ``nb + i`` repeating row ``i % nb`` (tiled, since
+    ``window`` can exceed ``nb``): rows ``[b0, b0 + window)`` are then
+    the rows of blocks ``(b0 + i) % nb`` for any ``b0 < nb``."""
+    x = np.asarray(x)
+    return np.concatenate([x, x[np.arange(window) % x.shape[0]]])
+
+
+def rotation_order_pad(start: int, nb: int, window: int) -> np.ndarray:
+    """The scan order ``(start + arange(nb)) % nb`` as the loops' i32
+    ``order_pad``, wrap-filled to ``nb + window`` entries. Only a
+    rotation keeps each round's window one contiguous range of block
+    ids, so this is the one way to build a round loop's order."""
+    if not 0 <= start < nb:
+        raise ValueError(f"scan start {start} outside [0, {nb})")
+    return pad_blocks((start + np.arange(nb, dtype=np.int64)) % nb,
+                      window).astype(np.int32)
+
+
+def _window_rows(buf: jax.Array, b0, window: int) -> jax.Array:
+    """The window's rows of a :func:`pad_blocks` buffer, in scan order."""
+    return jax.lax.dynamic_slice_in_dim(buf, b0, window, axis=0)
+
+
+def _pad_processed(processed: jax.Array, window: int) -> jax.Array:
+    """A carry's ``(nb,)`` processed mask in the loop's padded form: the
+    ``window`` rows past ``nb`` start empty."""
+    with jax.named_scope("account"):
+        return jnp.concatenate([processed,
+                                jnp.zeros(window, processed.dtype)])
+
+
+def _fold_processed(processed: jax.Array, nb: int) -> jax.Array:
+    """The padded processed mask back in its ``(nb,)`` form: row
+    ``nb + i`` is block ``i % nb``."""
+    with jax.named_scope("account"):
+        tail = processed[nb:]
+        laps = -(-tail.shape[0] // nb)
+        tail = jnp.pad(tail, (0, laps * nb - tail.shape[0]))
+        return processed[:nb] | tail.reshape(laps, nb).any(axis=0)
+
+
 class QueryLoopBuffers(NamedTuple):
     """Device-resident inputs of the single-query loop (constant across
-    rounds; passed as jit arguments so reuse never retraces)."""
+    rounds; passed as jit arguments so reuse never retraces). The
+    block-id buffers ``words``, ``static_ok`` and ``presence`` are
+    :func:`pad_blocks`-padded, ``order_pad`` is
+    :func:`rotation_order_pad`'s."""
 
     values: jax.Array          # (nb, block_rows) f32 value column
     gids: jax.Array            # (nb, block_rows) i32 group codes
     mask: jax.Array            # (nb, block_rows) f32 predicate*valid
-    words: jax.Array           # (nb, W) uint32 group-bitmap words
-    order_pad: jax.Array       # (nb + window,) i32 scan order
-    static_ok: jax.Array       # (nb,) bool static prefilter
-    presence: jax.Array        # (nb, G) bool view-presence matrix
+    words: jax.Array           # (nb + window, W) uint32 group-bitmap words
+                               # (a (1, 1) placeholder without the probe)
+    order_pad: jax.Array       # (nb + window,) i32 scan order (rotation)
+    static_ok: jax.Array       # (nb + window,) bool static prefilter
+    presence: jax.Array        # (nb + window, G) bool view presence
     presence_total: jax.Array  # (G,) i32 blocks containing each view
     cum_rows: jax.Array        # (nb,) i64 cumulative valid rows in order
 
@@ -643,20 +709,21 @@ def _round_scan(bufs, pos, flags_src, *, nb: int, window: int,
                 wrap: bool = False):
     """Shared per-round cursor/selection plumbing: window slice, static
     verdicts, caller-supplied activity flags, budgeted selection and the
-    covered-range accounting masks. ``flags_src(ok, win)`` returns the
-    activity-tested flags for this round.
+    covered-range accounting masks. ``flags_src(ok, b0)`` returns the
+    activity-tested flags for this round, ``b0`` being the window's
+    first block id (its rows of a padded buffer: :func:`_window_rows`).
 
     ``bound`` overrides the cursor limit (a carousel pass's horizon can
-    exceed ``nb``); ``wrap`` slices the order at ``pos % nb`` — the
-    order pad must then be wrap-filled (``order[:window]``)."""
+    exceed ``nb``); ``wrap`` slices the order at ``pos % nb``."""
     with jax.named_scope("select"):
         offs = jnp.arange(window, dtype=jnp.int32)
         lim = nb if bound is None else bound
         in_range = (pos + offs) < lim
         start = jax.lax.rem(pos, jnp.int32(nb)) if wrap else pos
         win = jax.lax.dynamic_slice(bufs.order_pad, (start,), (window,))
-        ok = bufs.static_ok[win] & in_range
-        flags = flags_src(ok, win)
+        b0 = win[0]
+        ok = _window_rows(bufs.static_ok, b0, window) & in_range
+        flags = flags_src(ok, b0)
         take, csum, new_pos = _budget_select(flags, pos, lim, window,
                                              budget)
         covmask = offs < (new_pos - pos)
@@ -670,12 +737,15 @@ def _account(c, presence, presence_total, scan, *, lim, probe: bool,
     or slot carry ``c`` (the device twin of ``engine._fused_accounting``,
     ``_ScanViews.ingest_delta`` and ``_ScanViews.update_exact``) from
     ``_round_scan``'s tuple ``scan``; ``lim`` is the cursor limit.
-    Returns the updated carry fields."""
+    ``presence`` and ``c.processed`` are in their padded forms
+    (:func:`pad_blocks`, :func:`_pad_processed`). Returns the updated
+    carry fields."""
     win, ok, flags, take, _, new_pos, covmask = scan
     i64 = jnp.int64
     with jax.named_scope("account"):
+        b0 = win[0]
         act_skip = ok & covmask & ~(flags & covmask)
-        pres_win = presence[win]
+        pres_win = _window_rows(presence, b0, window)
         tainted = c.tainted | (pres_win & act_skip[:, None]).any(axis=0)
         seen_presence = c.seen_presence + (
             pres_win & take[:, None]).sum(axis=0, dtype=jnp.int32)
@@ -688,7 +758,9 @@ def _account(c, presence, presence_total, scan, *, lim, probe: bool,
         return dict(
             seen_presence=seen_presence, tainted=tainted,
             exact=c.exact | cov,
-            processed=c.processed.at[win].max(take),
+            processed=jax.lax.dynamic_update_slice(
+                c.processed, _window_rows(c.processed, b0, window) | take,
+                (b0,)),
             blocks_fetched=c.blocks_fetched + take.sum(dtype=i64),
             skipped_static=(c.skipped_static
                             + (~ok & covmask).sum(dtype=i64)),
@@ -783,11 +855,12 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
                    lookahead=lookahead, cover_cap=cover_cap)
 
     def scan_round(bufs, pos, sel_active):
-        def flags_src(ok, win):
+        def flags_src(ok, b0):
             if not probe:
                 return ok
             aw = pack_active_device(sel_active, n_words)
-            act = kops.active_blocks(bufs.words[win], aw, impl=impl) > 0
+            act = kops.active_blocks(_window_rows(bufs.words, b0, window),
+                                     aw, impl=impl) > 0
             return ok & act
 
         return _round_scan(bufs, pos, flags_src, nb=nb, window=window,
@@ -899,13 +972,16 @@ def build_query_loop(*, nb: int, window: int, budget: int, center: float,
 
     def chunk_body(bufs: QueryLoopBuffers,
                    carry: QueryLoopCarry) -> QueryLoopCarry:
-        carry = carry._replace(it=jnp.asarray(0, jnp.int32))
+        carry = carry._replace(
+            it=jnp.asarray(0, jnp.int32),
+            processed=_pad_processed(carry.processed, window))
         carry = jax.lax.while_loop(cond,
                                    functools.partial(loop_body, bufs),
                                    carry)
         if cadence:
             carry = flush(bufs, carry)
-        return carry
+        return carry._replace(processed=_fold_processed(carry.processed,
+                                                        nb))
 
     if shard is None:
         return jax.jit(chunk_body)
@@ -943,13 +1019,13 @@ class PassLoopBuffers(NamedTuple):
     fields are length-S tuples."""
 
     mask: jax.Array            # (nb, block_rows) shared predicate mask
-    order_pad: jax.Array       # (nb + window,) i32
-    static_ok: jax.Array       # (nb,) bool
+    order_pad: jax.Array       # (nb + window,) i32 (rotation_order_pad)
+    static_ok: jax.Array       # (nb + window,) bool (pad_blocks)
     cum_rows: jax.Array        # (nb,) i64
     values: Tuple[jax.Array, ...]          # per-slot value columns
     gids: Tuple[jax.Array, ...]            # per-slot group codes
-    words: Tuple[jax.Array, ...]           # per-slot bitmap words
-    presence: Tuple[jax.Array, ...]        # per-slot (nb, G_s) bool
+    words: Tuple[jax.Array, ...]           # per-slot (nb + window, W_s)
+    presence: Tuple[jax.Array, ...]        # per-slot (nb + window, G_s)
     presence_total: Tuple[jax.Array, ...]  # per-slot (G_s,) i32
 
 
@@ -1161,7 +1237,7 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         the slot's activity flags (union over its queries), budgeted
         take. Returns ``_round_scan``'s tuple."""
 
-        def flags_src(ok, win):
+        def flags_src(ok, b0):
             if spec.probe:
                 rows = [pack_active_device(qc.active, spec.n_words)
                         for qc in sel_queries[s]]
@@ -1169,8 +1245,9 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
                 rows = [(~qc.finished).astype(jnp.uint32).reshape(1)
                         for qc in sel_queries[s]]
             stack = jnp.stack(rows)
-            act = kops.active_blocks_multi(bufs.words[s][win], stack,
-                                           impl=impl) > 0
+            act = kops.active_blocks_multi(
+                _window_rows(bufs.words[s], b0, window), stack,
+                impl=impl) > 0
             return (ok[None, :] & act).any(axis=0)
 
         return _round_scan(bufs, sc.pos, flags_src, nb=nb, window=window,
@@ -1430,13 +1507,19 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         return go
 
     def chunk_body(bufs: PassLoopBuffers, carry: PassCarry) -> PassCarry:
-        carry = carry._replace(it=jnp.asarray(0, jnp.int32))
+        carry = carry._replace(
+            it=jnp.asarray(0, jnp.int32),
+            slots=tuple(sc._replace(
+                processed=_pad_processed(sc.processed, window))
+                for sc in carry.slots))
         carry = jax.lax.while_loop(cond,
                                    functools.partial(loop_body, bufs),
                                    carry)
         if cadence:
             carry = flush(bufs, carry)
-        return carry
+        return carry._replace(slots=tuple(
+            sc._replace(processed=_fold_processed(sc.processed, nb))
+            for sc in carry.slots))
 
     if shard is None:
         return jax.jit(chunk_body)

@@ -134,7 +134,7 @@ class _SlotExec:
     selection are replicated computations)."""
 
     def __init__(self, frame: FastFrame, rep_q: AggQuery, skipping: bool,
-                 queries: Sequence[AggQuery], shards=None,
+                 queries: Sequence[AggQuery], window: int, shards=None,
                  anchor: int = 0, join_round: int = 0,
                  row_offset: int = 0):
         use_hist_any = any(q.needs_hist for q in queries)
@@ -157,7 +157,8 @@ class _SlotExec:
         nb = frame.scramble.n_blocks
         words = (v.group_bm.words if self.probe
                  else np.ones((nb, 1), np.uint32))
-        self.words = adist.place_replicated(shards, words)
+        self.words = adist.place_replicated(
+            shards, kfused.pad_blocks(words, window))
         self.meta = (v.G, frame.config.hist_bins, v.use_hist,
                      float(v.a), float(v.b), float(v.center))
         self.metrics = {"skipped_static": 0, "skipped_active": 0,
@@ -236,12 +237,10 @@ class SharedPass:
         # admissions push the horizon past nb (static dispatch shapes
         # forever). For the non-wrap path the tail is invisible — the
         # in-range mask zeroes every lane past the cursor limit.
-        opad = np.zeros(self.nb + self.window, np.int32)
-        opad[:self.nb] = self.order
-        opad[self.nb:] = self.order[np.arange(self.window) % self.nb]
         rep = lambda a: adist.place_replicated(self.shards, a)
         self._rep = rep
-        self.order_pad_dev = rep(opad)
+        self.order_pad_dev = rep(kfused.rotation_order_pad(
+            int(self.start) % self.nb, self.nb, self.window))
         self.mask_dev = None      # set on first admit (needs a query)
         self.static_ok_dev = None
 
@@ -327,7 +326,8 @@ class SharedPass:
                 slot.qcis.extend(new)
             else:
                 slot = _SlotExec(
-                    frame, qs[0], self.skipping, qs, self.shards,
+                    frame, qs[0], self.skipping, qs, self.window,
+                    self.shards,
                     anchor=self.pos, join_round=self.rounds,
                     row_offset=self._rows_at(self.pos))
                 if self.pos > 0:
@@ -342,7 +342,8 @@ class SharedPass:
         if self.mask_dev is None:
             self.mask_dev = frame._device_mask(queries[0].filters,
                                                self.shards)
-            self.static_ok_dev = self._rep(self.slots[0].views.static_ok)
+            self.static_ok_dev = self._rep(kfused.pad_blocks(
+                self.slots[0].views.static_ok, self.window))
         return [out_qcis[id(q)] for q in queries]
 
     # -- retire ----------------------------------------------------------------
@@ -419,7 +420,8 @@ class SharedPass:
         frame = self.frame
         for sc in cp.slots:
             slot = _SlotExec(frame, sc.queries[0], self.skipping,
-                             sc.queries, self.shards, anchor=sc.anchor,
+                             sc.queries, self.window, self.shards,
+                             anchor=sc.anchor,
                              join_round=sc.join_round,
                              row_offset=sc.row_offset)
             slot.lap_done_round = sc.lap_done_round
@@ -447,7 +449,8 @@ class SharedPass:
         if self.slots and self.mask_dev is None:
             self.mask_dev = frame._device_mask(
                 self.slots[0].qcis[0].q.filters, self.shards)
-            self.static_ok_dev = self._rep(self.slots[0].views.static_ok)
+            self.static_ok_dev = self._rep(kfused.pad_blocks(
+                self.slots[0].views.static_ok, self.window))
 
     def freeze_partial(self, q: AggQuery) -> QueryResult:
         """Finalize ``q`` NOW from its current interval state: the
@@ -695,7 +698,9 @@ class SharedPass:
                 anchors=tuple(s.anchor for s in slots),
                 round_offsets=tuple(s.join_round for s in slots),
                 row_offsets=tuple(s.row_offset for s in slots))
-            presence = tuple(rep(s.views.presence) for s in slots)
+            presence = tuple(rep(kfused.pad_blocks(s.views.presence,
+                                                   self.window))
+                             for s in slots)
             presence_total = tuple(
                 rep(s.views.presence_total.astype(np.int32))
                 for s in slots)

@@ -180,6 +180,24 @@ def scenario_uneven_tail():
     assert r_sh.exact.all()
 
 
+def scenario_last_block_start():
+    """A scan that starts at the last block: every round's window wraps
+    from block nb - 1 to block 0 at its first lane (probe on, exhaustion
+    so every block is processed), bitwise on exactly-representable data,
+    for the query loop and for the pass loop."""
+    sc = _integer_scramble()
+    nb = sc.n_blocks
+    q = AggQuery(agg="avg", column="v", group_by="g",
+                 stop=AbsoluteWidth(eps=1e-9), delta=1e-9)  # never fires
+    r_sh, r_or = run_pair(sc, q, start=nb - 1)
+    assert_sharded_matches_oracle(r_sh, r_or, bitwise_ci=True)
+    assert r_sh.exact.all() and r_sh.blocks_fetched == nb
+    (r_pass,) = FrameServer(FastFrame(sc, EngineConfig(
+        shard_rows=True, **CFG))).run_batch([q], start_block=nb - 1,
+                                            seed=1)
+    assert_sharded_matches_oracle(r_pass, r_or, bitwise_ci=True)
+
+
 def scenario_server_pass():
     """A mixed FrameServer batch through the sharded pass loop (per-slot
     cursors, per-slot collective folds, finish-time snapshots)."""
@@ -418,6 +436,7 @@ ALL = [
     scenario_exhaustion_bitwise,
     scenario_early_stop_bitwise,
     scenario_uneven_tail,
+    scenario_last_block_start,
     scenario_server_pass,
     scenario_carousel_sharded_lap,
     scenario_cadence_superset_sync,

@@ -91,6 +91,30 @@ def test_unpack_words_inverts_the_packing(cardinality):
     np.testing.assert_array_equal(got, masks)
 
 
+@pytest.mark.parametrize("group_by", [None, "airline", ("origin",
+                                                        "airline")])
+def test_group_presence_is_cached_and_read_only(frame, group_by):
+    """A query's presence matrix is the group column's bitmap unpacked,
+    with its per-group block counts; one frame unpacks it once and every
+    later query of the column reads the same read-only arrays."""
+    from repro.aqp import engine
+
+    q = AggQuery(agg="avg", column="dep_delay", group_by=group_by,
+                 stop=AbsoluteWidth(eps=1.0))
+    first, again = (engine._ScanViews(frame, q) for _ in range(2))
+    assert again.presence is first.presence
+    assert again.presence_total is first.presence_total
+    assert not first.presence.flags.writeable
+    assert not first.presence_total.flags.writeable
+    if group_by is None:
+        want = np.ones((frame.scramble.n_blocks, 1), bool)
+    else:
+        want = unpack_words(frame.bitmap(first.gcol).words, first.G)
+    np.testing.assert_array_equal(first.presence, want)
+    np.testing.assert_array_equal(first.presence_total,
+                                  want.sum(axis=0, dtype=np.int32))
+
+
 # -- engine: exact mode --------------------------------------------------------
 
 

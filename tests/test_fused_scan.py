@@ -5,9 +5,12 @@ bookkeeping (tainted / exact) and scan metrics — across randomized query
 shapes, including activity-skipped (tainted) and exhausted (exact) views.
 """
 
+from typing import NamedTuple
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.aqp import (AggQuery, EngineConfig, Expression, FastFrame,
@@ -207,6 +210,173 @@ def test_gather_compaction_equals_nonzero(window, budget, n_flagged):
         np.asarray(blk),
         np.where(want < window, np.asarray(win)[np.minimum(want, window - 1)],
                  0))
+
+
+# -- the round's window as slices ---------------------------------------------
+
+
+class _Acct(NamedTuple):
+    """The fields of a query or slot carry that ``_account`` reads."""
+
+    pos: jnp.ndarray
+    tainted: jnp.ndarray
+    seen_presence: jnp.ndarray
+    exact: jnp.ndarray
+    processed: jnp.ndarray
+    blocks_fetched: jnp.ndarray
+    skipped_static: jnp.ndarray
+    skipped_active: jnp.ndarray
+    probes: jnp.ndarray
+
+
+def _gather_round(order_pad, static_ok, words, presence, presence_total, c,
+                  aw, *, nb, window, budget, lookahead, cover_cap, probe,
+                  lim, wrap):
+    """The gather/scatter form of ``_round_scan`` + ``_account``: every
+    window read indexes the block-id buffers with the window's block ids,
+    and ``processed`` is updated by a scatter."""
+    from repro.kernels import fused_scan
+    from repro.kernels import ops
+
+    i32, i64 = jnp.int32, jnp.int64
+    offs = jnp.arange(window, dtype=i32)
+    in_range = (c.pos + offs) < lim
+    start = jax.lax.rem(c.pos, i32(nb)) if wrap else c.pos
+    win = jax.lax.dynamic_slice(order_pad, (start,), (window,))
+    ok = static_ok[win] & in_range
+    flags = ok
+    if probe:
+        flags = ok & (ops.active_blocks(words[win], aw, impl="ref") > 0)
+    take, _, new_pos = fused_scan._budget_select(flags, c.pos, lim, window,
+                                                 budget)
+    covmask = offs < (new_pos - c.pos)
+    act_skip = ok & covmask & ~(flags & covmask)
+    pres_win = presence[win]
+    tainted = c.tainted | (pres_win & act_skip[:, None]).any(axis=0)
+    seen = c.seen_presence + (pres_win & take[:, None]).sum(axis=0,
+                                                            dtype=i32)
+    cov = (seen >= presence_total) | ((new_pos >= lim) & ~tainted)
+    probes = c.probes
+    if probe:
+        win_len = jnp.minimum(i32(window), i32(lim) - c.pos)
+        csum = jnp.cumsum(flags.astype(i32))
+        csum_excl = jnp.concatenate([jnp.zeros(1, i32), csum[:-1]])
+        starts = jnp.arange(-(-window // lookahead), dtype=i32) * lookahead
+        probed = ((csum_excl[starts] < budget) & (starts < win_len)
+                  & (starts < cover_cap))
+        ends = jnp.minimum(starts + lookahead, win_len)
+        probes = probes + jnp.where(probed, ends - starts, 0).sum().astype(
+            i64)
+    return c._replace(
+        pos=new_pos, tainted=tainted, seen_presence=seen,
+        exact=c.exact | cov, processed=c.processed.at[win].max(take),
+        blocks_fetched=c.blocks_fetched + take.sum(dtype=i64),
+        skipped_static=c.skipped_static + (~ok & covmask).sum(dtype=i64),
+        skipped_active=c.skipped_active + act_skip.sum(dtype=i64),
+        probes=probes)
+
+
+def _slice_round(bufs, presence, presence_total, c, aw, *, nb, window,
+                 budget, lookahead, cover_cap, probe, lim, wrap):
+    """``_round_scan`` + ``_account`` as the loops call them, on padded
+    buffers, with the loops' activity test."""
+    from repro.kernels import fused_scan
+    from repro.kernels import ops
+
+    def flags_src(ok, b0):
+        if not probe:
+            return ok
+        rows = fused_scan._window_rows(bufs.words, b0, window)
+        return ok & (ops.active_blocks(rows, aw, impl="ref") > 0)
+
+    scan = fused_scan._round_scan(
+        bufs, c.pos, flags_src, nb=nb, window=window, budget=budget,
+        bound=None if lim == nb else lim, wrap=wrap)
+    acct = fused_scan._account(
+        c, presence, presence_total, scan, lim=lim, probe=probe,
+        window=window, budget=budget, lookahead=lookahead,
+        cover_cap=cover_cap)
+    return c._replace(pos=scan[5], **acct)
+
+
+# (nb, window, start, pos, anchor): anchor None is the query loop (cursor
+# limit nb), else a pass-loop slot whose lap is [anchor, anchor + nb)
+_WINDOWS = {
+    "start-0": (300, 256, 0, 0, None),
+    "start-last": (300, 256, 299, 0, None),
+    "crosses-last-block": (300, 256, 200, 0, None),
+    "last-partial-window": (300, 256, 37, 200, None),
+    "window-past-nb": (40, 128, 13, 0, None),
+    "slot-lap-past-nb": (300, 256, 37, 250, 170),
+}
+
+
+@pytest.mark.parametrize("G", [1, 14, 1600])
+@pytest.mark.parametrize("probe", [False, True])
+@pytest.mark.parametrize("case", sorted(_WINDOWS))
+def test_window_slices_equal_gathers(x64, case, probe, G):
+    """Rounds read through ``dynamic_slice`` at the window's first block,
+    on the padded block-id buffers, and ``processed`` kept padded and
+    folded back to ``(nb,)``, give bitwise what the gather/scatter form
+    gives: every carry field, round after round, until the cursor limit.
+    The cases cover a rotation that starts at block 0, at the last block,
+    one whose windows cross block nb - 1 to 0, the last partial window, a
+    window longer than the scramble, and a served slot whose lap runs
+    past nb."""
+    from repro.kernels import fused_scan
+
+    nb, window, start, pos, anchor = _WINDOWS[case]
+    wrap = anchor is not None
+    lim = nb if anchor is None else anchor + nb
+    budget, lookahead, cover_cap = 16, 64, window
+    rng = np.random.default_rng([nb, start, pos, G, probe])
+    n_words = -(-G // 32)
+    static_ok = rng.random(nb) < 0.8
+    words = rng.integers(0, 2 ** 32, (nb, n_words), dtype=np.uint32)
+    presence = rng.random((nb, G)) < min(0.5, 4.0 / G + 0.05)
+    presence_total = jnp.asarray(presence.sum(axis=0, dtype=np.int32))
+    active = rng.random(G) < 0.3
+    active[0] = True
+    aw = fused_scan.pack_active_device(jnp.asarray(active), n_words)
+    order_pad = fused_scan.rotation_order_pad(start, nb, window)
+    np.testing.assert_array_equal(order_pad[:nb],
+                                  (start + np.arange(nb)) % nb)
+    bufs = fused_scan.PassLoopBuffers(
+        mask=None, order_pad=jnp.asarray(order_pad),
+        static_ok=jnp.asarray(fused_scan.pad_blocks(static_ok, window)),
+        cum_rows=None, values=None, gids=None,
+        words=jnp.asarray(fused_scan.pad_blocks(words, window)),
+        presence=None, presence_total=None)
+    presence_pad = jnp.asarray(fused_scan.pad_blocks(presence, window))
+    i64 = lambda v: jnp.asarray(v, jnp.int64)
+    c_ref = _Acct(
+        pos=jnp.asarray(pos, jnp.int32),
+        tainted=jnp.asarray(rng.random(G) < 0.1),
+        seen_presence=jnp.zeros(G, jnp.int32),
+        exact=jnp.asarray(rng.random(G) < 0.1),
+        processed=jnp.asarray(rng.random(nb) < 0.05),
+        blocks_fetched=i64(0), skipped_static=i64(0), skipped_active=i64(0),
+        probes=i64(0))
+    c = c_ref._replace(
+        processed=fused_scan._pad_processed(c_ref.processed, window))
+    kw = dict(nb=nb, window=window, budget=budget, lookahead=lookahead,
+              cover_cap=cover_cap, probe=probe, lim=lim, wrap=wrap)
+    rounds = 0
+    while int(c_ref.pos) < lim:
+        c_ref = _gather_round(jnp.asarray(order_pad),
+                              jnp.asarray(static_ok), jnp.asarray(words),
+                              jnp.asarray(presence), presence_total, c_ref,
+                              aw, **kw)
+        c = _slice_round(bufs, presence_pad, presence_total, c, aw, **kw)
+        got = c._replace(
+            processed=fused_scan._fold_processed(c.processed, nb))
+        for f in _Acct._fields:
+            a, b = np.asarray(getattr(got, f)), np.asarray(getattr(c_ref, f))
+            assert a.dtype == b.dtype and a.shape == b.shape, f
+            np.testing.assert_array_equal(a, b, err_msg=f"{f}, round "
+                                          f"{rounds}")
+        rounds += 1
+    assert int(c_ref.blocks_fetched) > 0
 
 
 def test_fused_round_interpret_engine_close_to_ref():

@@ -65,15 +65,20 @@ SINGLE_QUERIES = [
 ]
 
 
+@pytest.mark.parametrize("start", ["first", "last"])
 @pytest.mark.parametrize("name,q,sampling", SINGLE_QUERIES,
                          ids=[s[0] for s in SINGLE_QUERIES])
-def test_served_single_query_bitwise_equals_run(ds, name, q, sampling):
+def test_served_single_query_bitwise_equals_run(ds, name, q, sampling,
+                                                start):
     """A batch of one must be indistinguishable from FastFrame.run —
-    results AND scan metrics (fresh frames so cache state matches)."""
-    r_run = fresh_frame(ds).run(q, sampling=sampling, seed=1,
-                                start_block=0)
+    results AND scan metrics (fresh frames so cache state matches) —
+    from the first block and from the last, where every window wraps
+    to block 0."""
+    frame = fresh_frame(ds)
+    start_block = 0 if start == "first" else frame.scramble.n_blocks - 1
+    r_run = frame.run(q, sampling=sampling, seed=1, start_block=start_block)
     r_srv = FrameServer(fresh_frame(ds)).run_batch(
-        [q], sampling=sampling, seed=1, start_block=0)[0]
+        [q], sampling=sampling, seed=1, start_block=start_block)[0]
     assert_bitwise_equal(r_srv, r_run)
 
 

@@ -36,7 +36,8 @@ def _scenarios():
 @pytest.mark.parametrize("name", [
     "scenario_groupby_topk", "scenario_filtered_sum", "scenario_taint",
     "scenario_exhaustion_bitwise", "scenario_early_stop_bitwise",
-    "scenario_uneven_tail", "scenario_server_pass",
+    "scenario_uneven_tail", "scenario_last_block_start",
+    "scenario_server_pass",
     "scenario_carousel_sharded_lap",
     "scenario_cadence_superset_sync", "scenario_cadence_merge_confirm",
     "scenario_cadence_exhaustion", "scenario_cadence_early_stop",

@@ -117,7 +117,7 @@ def test_query_loop_compiles(one_chip, x64, bounder):
     loop = engine._DeviceLoop(frame, q, slot, qci, probe=True,
                               lookahead=1024, max_rounds=100_000)
     order = np.arange(sc.n_blocks)
-    loop.set_order(order, np.cumsum(frame._valid_counts[order]))
+    loop.set_order(0, np.cumsum(frame._valid_counts[order]))
     carry = loop.init_carry(slot, qci)
     as_shapes = lambda tree: jax.tree.map(
         lambda x: _shape(one_chip, x.shape, x.dtype), tree)
@@ -159,7 +159,7 @@ def test_query_loop_names_scopes_and_kernels(one_chip, x64, bounder,
     loop = engine._DeviceLoop(frame, q, slot, qci, probe=True,
                               lookahead=1024, max_rounds=100_000)
     order = np.arange(sc.n_blocks)
-    loop.set_order(order, np.cumsum(frame._valid_counts[order]))
+    loop.set_order(0, np.cumsum(frame._valid_counts[order]))
     as_shapes = lambda tree: jax.tree.map(
         lambda x: _shape(one_chip, x.shape, x.dtype), tree)
     text = loop._chunk_fn.lower(as_shapes(loop.bufs),

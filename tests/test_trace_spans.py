@@ -81,9 +81,11 @@ def test_run_records_its_host_spans(scramble, tmp_path, mode, children):
         prev = e
 
 
-def _spy(monkeypatch, build_fn: str, dialect: str = "stablehlo") -> list:
+def _spy(monkeypatch, build_fn: str, dialect: str = "stablehlo",
+         compiled: bool = False) -> list:
     """Replace ``kfused.<build_fn>`` with one whose loops record their
-    lowered text (with the name stacks) at their first call."""
+    lowered text (with the name stacks) at their first call, and with
+    ``compiled`` their optimized HLO after it."""
     texts = []
     build = getattr(kfused, build_fn)
 
@@ -92,8 +94,10 @@ def _spy(monkeypatch, build_fn: str, dialect: str = "stablehlo") -> list:
 
         def call(*args):
             if not texts:
-                texts.append(fn.lower(*args).as_text(dialect=dialect,
-                                                     debug_info=True))
+                low = fn.lower(*args)
+                texts.append(low.as_text(dialect=dialect, debug_info=True))
+                if compiled:
+                    texts.append(low.compile().as_text())
             return fn(*args)
         return call
 
@@ -123,7 +127,7 @@ def _sharded_lowered(scramble, q, merge_every: int):
                               lookahead=1024, max_rounds=100_000,
                               shards=shards)
     order = np.arange(scramble.n_blocks)
-    loop.set_order(order, np.cumsum(frame._valid_counts[order]))
+    loop.set_order(0, np.cumsum(frame._valid_counts[order]))
     return loop._chunk_fn.lower(loop.bufs, loop.init_carry(slot, qci))
 
 
@@ -187,3 +191,39 @@ def test_gather_scope_holds_no_scatter(scramble, monkeypatch, build_fn):
     in_gather = [n for n in _SCATTER_OP_NAME.findall(texts[0])
                  if "/gather/" in n]
     assert in_gather == []
+
+
+_GATHER_OP_NAME = re.compile(r' gather\(.*op_name="([^"]*)"')
+
+
+@pytest.mark.parametrize("loop", ["build_query_loop", "build_pass_loop",
+                                  "cadence"])
+def test_select_and_account_read_the_window_as_slices(scramble, monkeypatch,
+                                                      loop):
+    """Every scan order is a rotation, so each window read of a block-id
+    buffer is one ``dynamic_slice`` and the ``processed`` update one
+    ``dynamic_update_slice``: no HLO gather of the query loop, its
+    collective-cadence body or the pass loop carries the ``select`` or
+    ``account`` scope, and no scatter carries ``account``. The TPU runs a
+    window-long gather at tens of GB/s and a scatter one update at a
+    time."""
+    if loop == "cadence":
+        low = _sharded_lowered(scramble, fq.f_q9(), 2)
+        texts = [low.as_text(dialect="hlo", debug_info=True),
+                 low.compile().as_text()]
+    else:
+        texts = _spy(monkeypatch, loop, dialect="hlo", compiled=True)
+        frame = _frame(scramble, device_loop=True)
+        if loop == "build_query_loop":
+            frame.run(fq.f_q9(), start_block=0)
+        else:
+            FrameServer(frame).run_batch([fq.f_q9(), fq.f_q2(8.0)],
+                                         start_block=0)
+    for text in texts:   # as lowered, and as compiled
+        assert "/select/" in text and "/account/" in text
+        gathers = _GATHER_OP_NAME.findall(text)
+        assert any("/gather/" in n for n in gathers)
+        assert [n for n in gathers
+                if re.search("/(select|account)/", n)] == []
+        assert [n for n in _SCATTER_OP_NAME.findall(text)
+                if "/account/" in n] == []
