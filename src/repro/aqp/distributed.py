@@ -4,7 +4,8 @@ This module is deliberately thin: the *computation* of the sharded scan
 lives in :mod:`repro.kernels.fused_scan` (the round body runs under
 ``shard_map`` with the per-round fold delta merged by ``psum`` / ``pmin``
 / ``pmax`` inside the ``lax.while_loop`` carry — see
-:func:`repro.kernels.fused_scan.build_query_loop`). What lives here is
+:func:`repro.kernels.fused_scan.build_pass_loop`, the one round loop
+of ``FastFrame.run`` and ``SharedPass``). What lives here is
 everything the engine needs to *feed* that path:
 
   * :func:`make_aqp_mesh` — flatten the local devices (or an explicit

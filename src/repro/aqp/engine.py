@@ -63,7 +63,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -185,7 +185,9 @@ class EngineConfig:
         device_loop: keep the *whole* round loop device-resident — fold,
             float64 state merge, CI refresh (the ``*_device`` bounder
             twins) and stop test all run inside one ``lax.while_loop``
-            dispatch, syncing to host only at termination or every
+            dispatch (:func:`repro.kernels.fused_scan.build_pass_loop`,
+            run as a pass of one slot holding one query), syncing to
+            host only at termination or every
             ``sync_every`` rounds. Requires ``fused=True`` and 64-bit
             JAX types (:func:`repro.core.state.require_x64`; a clear
             error is raised otherwise — silent float32 demotion would
@@ -621,20 +623,18 @@ class _FusedScan:
         self.gids = frame._device_gids(gcol)
         self.mask = frame._device_mask(q.filters)
         self.words = (jnp.asarray(group_bm.words) if group_bm is not None
-                      else jnp.zeros((1, 1), jnp.uint32))
+                      else None)
         opad = np.zeros(nb + self.window, np.int32)
         opad[:nb] = order
         self.order_pad = jnp.asarray(opad)
         self.static_ok = jnp.asarray(static_ok)
-        self._dummy_active = jnp.zeros(self.words.shape[1], jnp.uint32)
 
     def round(self, pos: int, active_words):
         """One fused round from cursor ``pos``. Returns host-side
         ``(moment_delta, hist_delta, ok, flags, new_pos)``."""
-        aw = active_words if active_words is not None else self._dummy_active
         state, hist, ok, flags, new_pos = kfused.fused_round(
             self.values, self.gids, self.mask, self.words, self.order_pad,
-            self.static_ok, jnp.asarray(pos, jnp.int32), aw,
+            self.static_ok, jnp.asarray(pos, jnp.int32), active_words,
             nb=self.nb, window=self.window, budget=self.budget,
             center=self.center, a=self.a, b=self.b, num_groups=self.G,
             nbins=self.nbins, use_hist=self.use_hist, probe=self.probe,
@@ -650,9 +650,8 @@ def _make_device_refresh(q: AggQuery, qci: "_QueryIntervals",
     one query — the device twin of ``_QueryIntervals.refresh`` +
     ``collapse_exact`` + ``update_active``, with the query's static
     configuration (bounder, delta schedule, stopping condition, valid
-    mask) baked in. Passed as ``refresh_fn`` to
-    :func:`repro.kernels.fused_scan.build_query_loop` /
-    :func:`~repro.kernels.fused_scan.build_pass_loop`."""
+    mask) baked in. One of the ``refresh_fns`` of
+    :func:`repro.kernels.fused_scan.build_pass_loop`."""
     bounder = qci.bounder
     delta_view = qci.delta_view
     known_n = qci.known_n
@@ -690,35 +689,214 @@ def _host_copy(x, dtype=None) -> np.ndarray:
     return np.array(x, dtype=dtype)
 
 
-def _restore_views_from_carry(slot: _ScanViews, state: MomentState, hist,
-                              processed, seen_presence, tainted, exact,
-                              blocks_fetched, metrics: Dict[str, int],
-                              skipped_static, skipped_active) -> None:
-    """Copy a device-loop carry's shared fold/coverage/soundness state
-    back into a host-side :class:`_ScanViews` + metrics dict — the one
-    writeback used by both the single-query loop and the serving pass,
-    so recovery / result construction always run on identical state."""
-    slot.state = MomentState(*(_host_copy(f, np.float64) for f in state))
-    if slot.use_hist:
-        slot.hist = _host_copy(hist, np.float64)
-    slot.processed = _host_copy(processed)
-    slot.seen_presence = _host_copy(seen_presence, np.int64)
-    slot.tainted = _host_copy(tainted)
-    slot.exact = _host_copy(exact)
-    slot.blocks_fetched = int(blocks_fetched)
-    metrics["skipped_static"] += int(skipped_static)
-    metrics["skipped_active"] += int(skipped_active)
+class _PassSlot:
+    """One slot of a device pass: a scan's views, the intervals of the
+    queries that share it, the slot's cursor, lap and scan metrics, and
+    its device columns. :func:`_build_pass` compiles the round loop of a
+    list of slots, :func:`_pass_carry` packs their state into its carry
+    and :func:`_write_back` reads the carry back into them, so the
+    recovery pass and result construction run the exact host-loop code
+    on identical state. ``FastFrame.run`` drives a pass of one slot
+    holding one query; :class:`repro.serve.SharedPass` one of many.
+
+    ``anchor`` is the pass-coordinate position where the slot was
+    admitted (its lap is ``[anchor, anchor + n_blocks)``), ``join_round``
+    the pass round count at admission and ``row_offset`` the valid rows
+    before the anchor; all three are 0 for a solo run. ``words`` are the
+    padded group-bitmap words of a probe slot, None otherwise."""
+
+    def __init__(self, views: _ScanViews, qcis: Sequence["_QueryIntervals"],
+                 probe: bool, values, gids, words, anchor: int = 0,
+                 join_round: int = 0, row_offset: int = 0):
+        self.views = views
+        self.qcis = list(qcis)
+        self.probe = probe
+        self.values, self.gids, self.words = values, gids, words
+        self.anchor = anchor
+        self.join_round = join_round
+        self.row_offset = row_offset
+        self.pos = anchor              # this slot's cursor, pass coords
+        self.lap_done_round = None     # pass round when the lap completed
+        self.metrics = {"skipped_static": 0, "skipped_active": 0,
+                        "probes": views.probes0}
+
+    def spec(self, nbins: int) -> kfused.SlotSpec:
+        """The slot's static configuration for the round kernels."""
+        v = self.views
+        return kfused.SlotSpec(
+            num_groups=v.G, nbins=nbins, use_hist=v.use_hist,
+            a=float(v.a), b=float(v.b), center=float(v.center),
+            probe=self.probe,
+            n_words=v.group_bm.n_words if self.probe else 0)
+
+
+def _slot_columns(frame: "FastFrame", views: _ScanViews, probe: bool,
+                  window: int, shards: Optional[adist.BlockShards]):
+    """A slot's device columns ``(values, gids, words)``: the value and
+    group-code slabs from the frame's caches (row-sharded over the mesh
+    when ``shards`` is given) and, for a probe slot, its replicated
+    :func:`~repro.kernels.fused_scan.pad_blocks`-padded bitmap words."""
+    words = (adist.place_replicated(
+        shards, kfused.pad_blocks(views.group_bm.words, window))
+        if probe else None)
+    return (frame._device_values(views.value_src, shards),
+            frame._device_gids(views.gcol, shards), words)
+
+
+def _build_pass(frame: "FastFrame", slots: Sequence[_PassSlot], *,
+                window: int, lookahead: int, max_rounds: int,
+                chunk: Optional[int],
+                shards: Optional[adist.BlockShards]):
+    """The jitted pass loop of ``slots``
+    (:func:`~repro.kernels.fused_scan.build_pass_loop`) and the slots'
+    padded presence buffers. Both depend only on the slots' static
+    configuration, so callers cache them on the frame."""
+    cfg = frame.config
+    rep = lambda a: adist.place_replicated(shards, a)
+    refresh_fns = tuple(
+        tuple(_make_device_refresh(qc.q, qc, s.views.a, s.views.b,
+                                   qc.use_hist, float(qc.R), s.views.valid)
+              for qc in s.qcis)
+        for s in slots)
+    chunk_fn = kfused.build_pass_loop(
+        nb=frame.scramble.n_blocks, window=window, budget=cfg.round_blocks,
+        impl=kops.resolve_impl(cfg.impl), lookahead=lookahead,
+        cover_cap=cfg.round_blocks * cfg.cover_cap_factor,
+        max_rounds=max_rounds, chunk=chunk,
+        slot_specs=tuple(s.spec(cfg.hist_bins) for s in slots),
+        refresh_fns=refresh_fns,
+        shard=shards.info if shards is not None else None,
+        anchors=tuple(s.anchor for s in slots),
+        round_offsets=tuple(s.join_round for s in slots),
+        row_offsets=tuple(s.row_offset for s in slots))
+    presence = tuple(rep(kfused.pad_blocks(s.views.presence, window))
+                     for s in slots)
+    presence_total = tuple(rep(s.views.presence_total.astype(np.int32))
+                           for s in slots)
+    return chunk_fn, presence, presence_total
+
+
+def _pass_buffers(slots: Sequence[_PassSlot], mask, order_pad, static_ok,
+                  cum_rows, presence, presence_total
+                  ) -> kfused.PassLoopBuffers:
+    """The pass loop's inputs: the pass-wide buffers and each slot's
+    columns and presence."""
+    return kfused.PassLoopBuffers(
+        mask=mask, order_pad=order_pad, static_ok=static_ok,
+        cum_rows=cum_rows, values=tuple(s.values for s in slots),
+        gids=tuple(s.gids for s in slots),
+        words=tuple(s.words for s in slots), presence=presence,
+        presence_total=presence_total)
+
+
+def _pass_carry(slots: Sequence[_PassSlot], *, rounds: int, n_live: int,
+                nbins: int, shards: Optional[adist.BlockShards]
+                ) -> kfused.PassCarry:
+    """The pass loop's carry from the slots' host state: each slot's
+    cursor, fold, coverage and metrics held absolute (written back as
+    they come out), each query's running intervals."""
+    cadence = shards is not None and shards.merge_every > 1
+    f64 = lambda x: jnp.asarray(x, jnp.float64)
+    i32 = lambda v: jnp.asarray(v, jnp.int32)
+    i64 = lambda v: jnp.asarray(v, jnp.int64)
+
+    def pending(v: _ScanViews) -> dict:
+        # collective-cadence pending slots: empty local delta
+        if not cadence:
+            return {}
+        return dict(
+            pend_sums=jnp.zeros((3, v.G), jnp.float64),
+            pend_vmin=jnp.full((v.G,), np.inf, jnp.float64),
+            pend_vmax=jnp.full((v.G,), -np.inf, jnp.float64),
+            pend_hist=(jnp.zeros((v.G, nbins), jnp.float64)
+                       if v.use_hist else None))
+
+    slot_carries = tuple(
+        kfused.SlotCarry(
+            pos=i32(s.pos),
+            state=MomentState(*(f64(x) for x in s.views.state)),
+            hist=(f64(s.views.hist) if s.views.use_hist else None),
+            seen_presence=jnp.asarray(
+                s.views.seen_presence.astype(np.int32)),
+            tainted=jnp.asarray(s.views.tainted),
+            exact=jnp.asarray(s.views.exact),
+            processed=jnp.asarray(s.views.processed),
+            blocks_fetched=i64(s.views.blocks_fetched),
+            skipped_static=i64(s.metrics["skipped_static"]),
+            skipped_active=i64(s.metrics["skipped_active"]),
+            probes=i64(s.metrics["probes"]),
+            lap_rounds=i32(s.lap_done_round
+                           if s.lap_done_round is not None else -1),
+            **pending(s.views))
+        for s in slots)
+    query_carries = tuple(
+        tuple(kfused.PassQueryCarry(
+            lo=f64(qc.lo), hi=f64(qc.hi), est=f64(qc.est),
+            refreshed=jnp.asarray(qc.refreshed),
+            active=jnp.asarray(qc.active & ~np.asarray(qc.finished)),
+            finished=jnp.asarray(bool(qc.finished)),
+            stopped_early=jnp.asarray(False),
+            finish_rounds=i32(0), finish_pos=i32(0),
+            finish_blocks_fetched=i64(0), finish_skipped_static=i64(0),
+            finish_skipped_active=i64(0), finish_probes=i64(0),
+            snap_counts=jnp.zeros(s.views.G, jnp.float64),
+            snap_exact=jnp.zeros(s.views.G, bool),
+            snap_tainted=jnp.zeros(s.views.G, bool))
+            for qc in s.qcis)
+        for s in slots)
+    return kfused.PassCarry(
+        rounds=i32(rounds), it=i32(0), n_live=i32(n_live),
+        slots=slot_carries, queries=query_carries,
+        **(dict(pend_rounds=i32(0)) if cadence else {}))
+
+
+def _write_back(slots: Sequence[_PassSlot], carry: kfused.PassCarry
+                ) -> List[Tuple[_PassSlot, "_QueryIntervals",
+                                kfused.PassQueryCarry]]:
+    """Copy a pass carry back into the slots: each slot's cursor, lap,
+    fold/coverage/soundness state and metrics, and the intervals of each
+    query that was unfinished when the carry was packed. Returns
+    ``(slot, query, query carry)`` for the queries that finished in the
+    loop, whose finish-time snapshots the carry holds."""
+    host = _host_copy
+    newly = []
+    for s, sc, qcars in zip(slots, carry.slots, carry.queries):
+        v = s.views
+        v.state = MomentState(*(host(f, np.float64) for f in sc.state))
+        if v.use_hist:
+            v.hist = host(sc.hist, np.float64)
+        v.processed = host(sc.processed)
+        v.seen_presence = host(sc.seen_presence, np.int64)
+        v.tainted = host(sc.tainted)
+        v.exact = host(sc.exact)
+        v.blocks_fetched = int(sc.blocks_fetched)
+        for f in ("skipped_static", "skipped_active", "probes"):
+            s.metrics[f] = int(getattr(sc, f))
+        s.pos = int(sc.pos)
+        if s.pos >= v.lap_end and s.lap_done_round is None:
+            s.lap_done_round = int(sc.lap_rounds)
+        for qc, qcar in zip(s.qcis, qcars):
+            if qc.finished:
+                continue   # its carry stayed frozen
+            qc.lo = host(qcar.lo, np.float64)
+            qc.hi = host(qcar.hi, np.float64)
+            qc.est = host(qcar.est, np.float64)
+            qc.refreshed = host(qcar.refreshed)
+            qc.active = host(qcar.active)
+            qc.finished = bool(qcar.finished)
+            if qc.finished:
+                newly.append((s, qc, qcar))
+    return newly
 
 
 class _DeviceLoop:
-    """Device-resident round-loop driver for one query (the tentpole):
-    assembles the :class:`~repro.kernels.fused_scan.QueryLoopBuffers`,
-    builds the jitted ``lax.while_loop`` chunk function, runs dispatches
-    of up to ``sync_every``/``chunk_rounds`` rounds (one scalar host sync
-    between dispatches), and writes the final carry back into the
-    host-side :class:`_ScanViews` / :class:`_QueryIntervals` so the
-    recovery pass and result construction are byte-for-byte the code the
-    host loop uses."""
+    """``FastFrame.run``'s device round loop: the pass loop of one slot
+    holding one query at anchor 0 (:func:`_build_pass`), with the slot's
+    device buffers. Cached on the frame by the query's static identity,
+    so the jitted loop compiles once per query shape; each run installs
+    its scan order, and :meth:`run` dispatches chunks of up to
+    ``sync_every``/``chunk_rounds`` rounds with one scalar host sync
+    between them."""
 
     def __init__(self, frame: "FastFrame", q: AggQuery, slot: _ScanViews,
                  qci: "_QueryIntervals", probe: bool, lookahead: int,
@@ -726,131 +904,61 @@ class _DeviceLoop:
                  shards: Optional[adist.BlockShards] = None):
         require_x64("the device-resident round loop")
         cfg = frame.config
-        sc = frame.scramble
-        nb = sc.n_blocks
-        cover_cap = cfg.round_blocks * cfg.cover_cap_factor
-        window = _round_window(nb, lookahead, cover_cap)
+        nb = frame.scramble.n_blocks
+        window = _round_window(nb, lookahead,
+                               cfg.round_blocks * cfg.cover_cap_factor)
         self.nb = nb
         self.window = window
-        self.use_hist = slot.use_hist
-        self.nbins = cfg.hist_bins
-        self.chunk = cfg.sync_every or cfg.chunk_rounds
+        self.probe = probe
         self.max_rounds = max_rounds
         self.shards = shards
-        self.cadence = shards is not None and shards.merge_every > 1
-        words = (slot.group_bm.words if probe
-                 else np.zeros((1, 1), np.uint32))
-        # scan-order-independent buffers; order_pad / cum_rows are filled
-        # per run (the instance is cached on the frame across runs, so
-        # the jitted loop compiles once per query shape). When sharded,
-        # the three data slabs are row-sharded over the mesh and every
-        # other buffer is placed replicated.
-        # The block-id buffers are padded here, on the host, once per
-        # loop: see kfused.pad_blocks.
-        rep = lambda a: adist.place_replicated(shards, a)
-        pad = lambda a: rep(kfused.pad_blocks(a, window))
-        self._base_bufs = kfused.QueryLoopBuffers(
-            values=frame._device_values(slot.value_src, shards),
-            gids=frame._device_gids(slot.gcol, shards),
-            mask=frame._device_mask(q.filters, shards),
-            words=pad(words) if probe else rep(words),
-            order_pad=None, static_ok=pad(slot.static_ok),
-            presence=pad(slot.presence),
-            presence_total=rep(slot.presence_total.astype(np.int32)),
-            cum_rows=None)
-        refresh_fn = _make_device_refresh(
-            q, qci, slot.a, slot.b, qci.use_hist, float(qci.R),
-            slot.valid)
-        self._chunk_fn = kfused.build_query_loop(
-            nb=nb, window=window, budget=cfg.round_blocks,
-            center=float(slot.center), a=float(slot.a), b=float(slot.b),
-            num_groups=slot.G, nbins=cfg.hist_bins,
-            use_hist=slot.use_hist, probe=probe,
-            n_words=words.shape[1], impl=kops.resolve_impl(cfg.impl),
-            lookahead=lookahead, cover_cap=cover_cap,
-            max_rounds=max_rounds, chunk=self.chunk,
-            refresh_fn=refresh_fn,
-            shard=shards.info if shards is not None else None)
+        # scan-order-independent buffers, padded on the host once per
+        # loop (see kfused.pad_blocks); order_pad / cum_rows are set per
+        # run. When sharded, the data slabs are row-sharded over the
+        # mesh and every other buffer is placed replicated.
+        self.columns = _slot_columns(frame, slot, probe, window, shards)
+        self.mask = frame._device_mask(q.filters, shards)
+        self.static_ok = adist.place_replicated(
+            shards, kfused.pad_blocks(slot.static_ok, window))
+        self.nbins = cfg.hist_bins
+        self._chunk_fn, self.presence, self.presence_total = _build_pass(
+            frame, [_PassSlot(slot, [qci], probe, *self.columns)],
+            window=window, lookahead=lookahead, max_rounds=max_rounds,
+            chunk=cfg.sync_every or cfg.chunk_rounds, shards=shards)
 
-    def set_order(self, start: int, cum_rows: np.ndarray) -> None:
-        """Install this run's scan order, the rotation starting at block
-        ``start`` (the only run-dependent input)."""
+    def start(self, views: _ScanViews, qci: "_QueryIntervals", start: int,
+              cum_rows: np.ndarray) -> Tuple[_PassSlot, kfused.PassCarry]:
+        """This run's one-query slot and first carry, with the scan order
+        installed: the rotation starting at block ``start``, the only
+        run-dependent input."""
+        slot = _PassSlot(views, [qci], self.probe, *self.columns)
         rep = lambda a: adist.place_replicated(self.shards, a)
-        self.bufs = self._base_bufs._replace(
-            order_pad=rep(kfused.rotation_order_pad(start, self.nb,
-                                                    self.window)),
-            cum_rows=rep(cum_rows.astype(np.int64)))
+        self.bufs = _pass_buffers(
+            [slot], self.mask,
+            rep(kfused.rotation_order_pad(start, self.nb, self.window)),
+            self.static_ok, rep(cum_rows.astype(np.int64)), self.presence,
+            self.presence_total)
+        return slot, _pass_carry([slot], rounds=0, n_live=1,
+                                 nbins=self.nbins, shards=self.shards)
 
-    def init_carry(self, slot: _ScanViews,
-                   qci: "_QueryIntervals") -> kfused.QueryLoopCarry:
-        """Fresh carry from the (just-initialized) host-side state."""
-        G = slot.G
-        f64 = lambda x: jnp.asarray(x, jnp.float64)
-        i64 = lambda v: jnp.asarray(v, jnp.int64)
-        pend = {}
-        if self.cadence:
-            # collective-cadence pending slots: empty local delta
-            pend = dict(
-                pend_sums=jnp.zeros((3, G), jnp.float64),
-                pend_vmin=jnp.full((G,), np.inf, jnp.float64),
-                pend_vmax=jnp.full((G,), -np.inf, jnp.float64),
-                pend_hist=(jnp.zeros((G, self.nbins), jnp.float64)
-                           if self.use_hist else None),
-                pend_rounds=jnp.asarray(0, jnp.int32))
-        return kfused.QueryLoopCarry(
-            pos=jnp.asarray(0, jnp.int32),
-            rounds=jnp.asarray(0, jnp.int32),
-            it=jnp.asarray(0, jnp.int32),
-            live=jnp.asarray(True),
-            stopped_early=jnp.asarray(False),
-            state=MomentState(*(f64(f) for f in slot.state)),
-            hist=(f64(slot.hist) if self.use_hist else None),
-            processed=jnp.asarray(slot.processed),
-            seen_presence=jnp.asarray(
-                slot.seen_presence.astype(np.int32)),
-            tainted=jnp.asarray(slot.tainted),
-            exact=jnp.asarray(slot.exact),
-            lo=f64(qci.lo), hi=f64(qci.hi), est=f64(qci.est),
-            refreshed=jnp.asarray(qci.refreshed),
-            active=jnp.asarray(qci.active),
-            blocks_fetched=i64(slot.blocks_fetched),
-            skipped_static=i64(0), skipped_active=i64(0), probes=i64(0),
-            **pend)
-
-    def run(self, carry: kfused.QueryLoopCarry,
-            on_sync: Optional[Callable] = None) -> kfused.QueryLoopCarry:
+    def run(self, carry: kfused.PassCarry,
+            on_sync: Optional[Callable] = None) -> kfused.PassCarry:
         """Dispatch chunks until the loop terminates; between dispatches
         the host pulls one scalar (plus the streaming snapshot for
         ``on_sync`` subscribers when ``sync_every`` is set)."""
         while True:
             carry = self._chunk_fn(self.bufs, carry)
+            (sc,), ((qc,),) = carry.slots, carry.queries
             if on_sync is not None:
                 on_sync(dict(
-                    rounds=int(carry.rounds), pos=int(carry.pos),
-                    lo=np.asarray(carry.lo, np.float64),
-                    hi=np.asarray(carry.hi, np.float64),
-                    est=np.asarray(carry.est, np.float64),
-                    live=bool(carry.live)))
-            if (not bool(carry.live) or int(carry.pos) >= self.nb
+                    rounds=int(carry.rounds), pos=int(sc.pos),
+                    lo=np.asarray(qc.lo, np.float64),
+                    hi=np.asarray(qc.hi, np.float64),
+                    est=np.asarray(qc.est, np.float64),
+                    live=not bool(qc.finished)))
+            if (bool(qc.finished) or int(sc.pos) >= self.nb
                     or int(carry.rounds) >= self.max_rounds):
                 return carry
-
-    def writeback(self, carry: kfused.QueryLoopCarry, slot: _ScanViews,
-                  qci: "_QueryIntervals", metrics: Dict[str, int]) -> None:
-        """Copy the final carry into the host-side bookkeeping (one sync
-        at termination): after this, recovery / result construction run
-        the exact host-loop code on identical state."""
-        _restore_views_from_carry(
-            slot, carry.state, carry.hist, carry.processed,
-            carry.seen_presence, carry.tainted, carry.exact,
-            carry.blocks_fetched, metrics, carry.skipped_static,
-            carry.skipped_active)
-        metrics["probes"] += int(carry.probes)
-        qci.lo = _host_copy(carry.lo, np.float64)
-        qci.hi = _host_copy(carry.hi, np.float64)
-        qci.est = _host_copy(carry.est, np.float64)
-        qci.refreshed = _host_copy(carry.refreshed)
-        qci.active = _host_copy(carry.active)
 
 
 class FastFrame:
@@ -1366,15 +1474,20 @@ class FastFrame:
                     key,
                     lambda: _DeviceLoop(self, q, slot, qci, probe,
                                         lookahead, max_rounds, shards))
-                dloop.set_order(start, cum_rows)
-                carry = dloop.init_carry(slot, qci)
+                pslot, carry = dloop.start(slot, qci, start, cum_rows)
             with TraceAnnotation("aqp:loop"):
                 carry = dloop.run(carry, on_sync)
             with TraceAnnotation("aqp:writeback"):
-                dloop.writeback(carry, slot, qci, metrics)
-                pos = int(carry.pos)
-                rounds = int(carry.rounds)
-                stopped_early = bool(carry.stopped_early)
+                _write_back([pslot], carry)
+                (qcar,), = carry.queries
+                metrics = pslot.metrics
+                pos = pslot.pos
+                # a query that stopped answers from the round it stopped
+                # on: a collective-cadence loop counts one more round,
+                # frozen, before it exits
+                rounds = int(qcar.finish_rounds if qci.finished
+                             else carry.rounds)
+                stopped_early = qci.finished and bool(qcar.stopped_early)
             with TraceAnnotation("aqp:recovery"):
                 rounds = self._recovery_pass(slot, [qci], rounds,
                                              max_rounds)

@@ -37,9 +37,11 @@ slot folds its own moment/histogram state from the shared gather — still
 one device dispatch and one host sync per round for the whole batch.
 
 **Device-resident round loop** (``EngineConfig(device_loop=True)``):
-:func:`build_query_loop` / :func:`build_pass_loop` go one step further
-and remove the per-round host sync entirely. The whole OptStop round —
-the :func:`fused_round` scan/fold, the float64 running-state merge, the
+:func:`build_pass_loop` goes one step further and removes the per-round
+host sync entirely. It is the one device round loop: ``FastFrame.run``
+drives it as a pass of one slot holding one query, and
+:class:`repro.serve.SharedPass` as a pass of many. The whole OptStop
+round — the :func:`fused_round` scan/fold, the float64 running-state merge, the
 skip/taint/coverage accounting, the device CI refresh (the ``*_device``
 bounder twins from :mod:`repro.core.bounders`) and the jittable stopping
 conditions — runs inside one ``lax.while_loop`` whose carry holds every
@@ -52,7 +54,7 @@ running moments, intervals and CI math are float64, exactly like the
 host loop they replace.
 
 **Sharded round loop** (``EngineConfig(shard_rows=True)`` /
-:class:`ShardInfo`): the same loops run under ``shard_map`` over a
+:class:`ShardInfo`): the same loop runs under ``shard_map`` over a
 device mesh with the scan *divided*. The within-block row axis of the
 value/group/mask slabs is sliced into ``n_shards`` equal pieces (the
 block axis stays whole on every device), so the round body each shard
@@ -384,12 +386,13 @@ def fused_round(values: jax.Array, gids: jax.Array, mask: jax.Array,
     Args (device arrays unless noted):
       values/gids/mask: ``(nb, block_rows)`` materialized value column
         (f32), group codes (i32) and predicate*valid mask (f32);
-      words: ``(nb, W)`` uint32 group-bitmap words (unused when
-        ``probe=False``);
+      words: ``(nb, W)`` uint32 group-bitmap words (None, or unused,
+        when ``probe=False``);
       order_pad: ``(nb + window,)`` i32 scan order, zero-padded;
       static_ok: ``(nb,)`` bool static-prefilter verdict per block;
       pos: i32 scalar scan cursor (device-resident across rounds);
-      active_words: ``(W,)`` uint32 packed active-group mask.
+      active_words: ``(W,)`` uint32 packed active-group mask (None, or
+        unused, when ``probe=False``).
 
     Static config: ``window`` is the round's maximum cursor coverage
     (the reference path's ``lookahead``-batched cover cap, rounded up to
@@ -419,11 +422,11 @@ def fused_round(values: jax.Array, gids: jax.Array, mask: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "nb", "window", "budget", "meta", "impl"))
+    "nb", "window", "budget", "specs", "impl"))
 def fused_round_multi(mask: jax.Array, order_pad: jax.Array,
                       static_ok: jax.Array, pos: jax.Array,
                       values, gids, words, active, *, nb: int, window: int,
-                      budget: int, meta, impl: str, anchors=None):
+                      budget: int, specs, impl: str, anchors=None):
     """One fused scan round shared by several queries (one device
     dispatch per round for a whole :class:`repro.serve.FrameServer`
     pass). All queries share the predicate mask and static prefilter;
@@ -445,19 +448,17 @@ def fused_round_multi(mask: jax.Array, order_pad: jax.Array,
         lap is ``[anchors[s], anchors[s] + nb)``);
       values / gids: length-S tuples of ``(nb, block_rows)`` per-slot
         value (f32) / group-code (i32) columns;
-      words: length-S tuple of ``(nb, W_s)`` uint32 bitmap words — the
-        slot's group bitmap, or an all-ones ``(nb, 1)`` engagement bitmap
-        for slots that do not activity-skip (their queries then gate
-        selection with a single engaged/finished bit);
+      words: length-S tuple of ``(nb, W_s)`` uint32 group-bitmap words,
+        None for a slot without the probe (it selects on its static
+        verdicts alone);
       active: length-S tuple of ``(Q_s, W_s)`` uint32 per-query
-        active-word stacks;
+        active-word stacks (None where ``words`` is);
       anchors: ``(S,)`` i32 pass-coordinate admission positions
         (``None`` = all zero, the static-batch case) — dynamic, so
         admission epochs with the same shape profile hit the jit cache.
 
-    Static config: ``meta`` is a length-S tuple of per-slot
-    ``(num_groups, nbins, use_hist, a, b, center)`` tuples; ``nb`` /
-    ``window`` / ``budget`` as in :func:`fused_round`.
+    Static config: ``specs`` is a length-S tuple of :class:`SlotSpec`;
+    ``nb`` / ``window`` / ``budget`` as in :func:`fused_round`.
 
     Because each slot selects with its own flags at its own cursor, a
     slot's selection/fold sequence is the same computation as
@@ -473,29 +474,30 @@ def fused_round_multi(mask: jax.Array, order_pad: jax.Array,
     Returns ``(states, hists, flag_stacks, oks, new_pos)``: per-slot
     mergeable deltas (``hists[s]`` is None when the slot has no
     histogram), per-slot ``(Q_s, window)`` bool per-query activity
-    verdicts, per-slot ``(window,)`` static verdicts and the ``(S,)``
-    advanced cursors.
+    verdicts (``(1, window)`` for a slot without the probe), per-slot
+    ``(window,)`` static verdicts and the ``(S,)`` advanced cursors.
     """
     if anchors is None:
-        anchors = jnp.zeros((len(meta),), jnp.int32)
+        anchors = jnp.zeros((len(specs),), jnp.int32)
     offs = jnp.arange(window, dtype=jnp.int32)
     states, hists, flag_stacks, oks, new_positions = [], [], [], [], []
-    for s, (num_groups, nbins, use_hist, a, b, center) in enumerate(meta):
+    for s, spec in enumerate(specs):
         le = anchors[s] + nb
         p = pos[s]
         in_range = (p + offs) < le
         start = jax.lax.rem(p, jnp.int32(nb))
         win = jax.lax.dynamic_slice(order_pad, (start,), (window,))
         ok = static_ok[win] & in_range
-        act = kops.active_blocks_multi(words[s][win], active[s],
-                                       impl=impl) > 0
-        fl = ok[None, :] & act
+        fl = ok[None, :]
+        if spec.probe:
+            fl = fl & (kops.active_blocks_multi(words[s][win], active[s],
+                                                impl=impl) > 0)
         flags = fl.any(axis=0)
         _, csum, new_p = _budget_select(flags, p, le, window, budget)
         v, g, m = _gather_rows(values[s], gids[s], mask, csum, win, window,
                                budget)
-        st, h = _fold(v, g, m, center, a, b, num_groups, nbins,
-                      use_hist, impl)
+        st, h = _fold(v, g, m, spec.center, spec.a, spec.b,
+                      spec.num_groups, spec.nbins, spec.use_hist, impl)
         states.append(st)
         hists.append(h)
         flag_stacks.append(fl)
@@ -646,64 +648,6 @@ def _fold_processed(processed: jax.Array, nb: int) -> jax.Array:
         return processed[:nb] | tail.reshape(laps, nb).any(axis=0)
 
 
-class QueryLoopBuffers(NamedTuple):
-    """Device-resident inputs of the single-query loop (constant across
-    rounds; passed as jit arguments so reuse never retraces). The
-    block-id buffers ``words``, ``static_ok`` and ``presence`` are
-    :func:`pad_blocks`-padded, ``order_pad`` is
-    :func:`rotation_order_pad`'s."""
-
-    values: jax.Array          # (nb, block_rows) f32 value column
-    gids: jax.Array            # (nb, block_rows) i32 group codes
-    mask: jax.Array            # (nb, block_rows) f32 predicate*valid
-    words: jax.Array           # (nb + window, W) uint32 group-bitmap words
-                               # (a (1, 1) placeholder without the probe)
-    order_pad: jax.Array       # (nb + window,) i32 scan order (rotation)
-    static_ok: jax.Array       # (nb + window,) bool static prefilter
-    presence: jax.Array        # (nb + window, G) bool view presence
-    presence_total: jax.Array  # (G,) i32 blocks containing each view
-    cum_rows: jax.Array        # (nb,) i64 cumulative valid rows in order
-
-
-class QueryLoopCarry(NamedTuple):
-    """``lax.while_loop`` carry: every piece of per-query round state the
-    host loop keeps in numpy, device-resident across rounds."""
-
-    pos: jax.Array             # i32 scan cursor
-    rounds: jax.Array          # i32 completed OptStop rounds (k)
-    it: jax.Array              # i32 rounds inside the current dispatch
-    live: jax.Array            # bool: some view still active
-    stopped_early: jax.Array   # bool: stop fired before exhaustion
-    state: MomentState         # f64 (G,) running moments
-    hist: Optional[jax.Array]  # f64 (G, K) running histogram (or None)
-    processed: jax.Array       # (nb,) bool
-    seen_presence: jax.Array   # (G,) i32 processed blocks per view
-    tainted: jax.Array         # (G,) bool
-    exact: jax.Array           # (G,) bool
-    lo: jax.Array              # (G,) f64 running interval
-    hi: jax.Array              # (G,) f64
-    est: jax.Array             # (G,) f64
-    refreshed: jax.Array       # (G,) bool
-    active: jax.Array          # (G,) bool
-    blocks_fetched: jax.Array  # i64 scan metrics
-    skipped_static: jax.Array  # i64
-    skipped_active: jax.Array  # i64
-    probes: jax.Array          # i64
-    # -- collective-cadence slots (``ShardInfo.merge_every > 1`` only;
-    # None otherwise, so the K=1 carry pytree — and its trace — is
-    # unchanged). The pending slots hold this shard's raw additive fold
-    # delta accumulated since the last full merge; they are zeroed by
-    # every merge and every dispatch exits freshly merged (flush), so
-    # the out-spec replication of the carry still holds.
-    pend_sums: Optional[jax.Array] = None    # (3, G) f64 local delta
-    pend_vmin: Optional[jax.Array] = None    # (G,) f64 local extremes
-    pend_vmax: Optional[jax.Array] = None    # (G,) f64
-    pend_hist: Optional[jax.Array] = None    # (G, K) f64 local hist delta
-    pend_rounds: Optional[jax.Array] = None  # i32 rounds since last merge
-                                             # (replicated: the merge
-                                             # schedule is deterministic)
-
-
 def _round_scan(bufs, pos, flags_src, *, nb: int, window: int,
                 budget: int, bound: Optional[int] = None,
                 wrap: bool = False):
@@ -768,241 +712,8 @@ def _account(c, presence, presence_total, scan, *, lim, probe: bool,
             probes=probes)
 
 
-def _query_carry_spec(use_hist: bool, cadence: bool = False
-                      ) -> "QueryLoopCarry":
-    """Fully-replicated shard_map partition spec of the query carry.
-    The cadence pending slots are per-shard state, but every dispatch
-    exits with them zeroed (flush), so they too are replicated at the
-    shard_map boundary."""
-    rep = P()
-    pend = rep if cadence else None
-    return QueryLoopCarry(
-        pos=rep, rounds=rep, it=rep, live=rep, stopped_early=rep,
-        state=MomentState(rep, rep, rep, rep, rep),
-        hist=(rep if use_hist else None), processed=rep,
-        seen_presence=rep, tainted=rep, exact=rep, lo=rep, hi=rep,
-        est=rep, refreshed=rep, active=rep, blocks_fetched=rep,
-        skipped_static=rep, skipped_active=rep, probes=rep,
-        pend_sums=pend, pend_vmin=pend, pend_vmax=pend,
-        pend_hist=(rep if cadence and use_hist else None),
-        pend_rounds=pend)
-
-
-def build_query_loop(*, nb: int, window: int, budget: int, center: float,
-                     a: float, b: float, num_groups: int, nbins: int,
-                     use_hist: bool, probe: bool, n_words: int, impl: str,
-                     lookahead: int, cover_cap: int, max_rounds: int,
-                     chunk: Optional[int], refresh_fn: Callable,
-                     shard: Optional[ShardInfo] = None) -> Callable:
-    """Build the jitted device-resident round loop for one query.
-
-    Returns ``chunk_fn(bufs: QueryLoopBuffers, carry: QueryLoopCarry) ->
-    QueryLoopCarry`` executing up to ``chunk`` OptStop rounds (``None`` =
-    until the stop test fires, the scramble is exhausted or
-    ``max_rounds`` is hit) in a single ``lax.while_loop`` dispatch. Each
-    round is the exact device twin of the host round: ``fused_round``'s
-    scan/fold, the f64 state merge, ``_fused_accounting``'s skip/taint/
-    probe bookkeeping, ``_ScanViews.update_exact`` and the caller's
-    ``refresh_fn`` (CI refresh + stopping condition; see
-    ``engine._make_device_refresh``).
-
-    ``refresh_fn(k, r, state, hist, tainted, exact, lo, hi, est,
-    refreshed, active)`` returns the updated ``(lo, hi, est, refreshed,
-    active)``.
-
-    With ``shard`` the whole loop runs under ``shard_map`` on
-    ``shard.mesh``: the within-block row axis of
-    ``bufs.values/gids/mask`` is sliced over the mesh (equal-shape
-    padded slabs, see :class:`ShardInfo`) while every other buffer AND
-    the entire carry stay replicated. Each shard runs the IDENTICAL
-    round body on its own row slice — global block ids index the local
-    slab directly, so the gather materializes and the fold touches only
-    ``1/n_shards`` of each selected block's rows. Selection, the
-    cursor, coverage/taint accounting and the CI refresh are replicated
-    computations over replicated inputs — identical on every device and
-    identical to the single-device loop — and only the fold delta
-    crosses the mesh (``psum``/``pmin``/``pmax`` inside :func:`_fold`,
-    one collective set per round, no host sync).
-
-    ``shard.merge_every = K > 1`` amortizes that collective set over K
-    rounds (the *collective cadence*; see ``docs/architecture.md``).
-    Each round folds only into the carry's f64 pending slots (this
-    shard's raw additive delta since the last merge) and the reported
-    intervals / active mask stay frozen at their last fully-merged
-    values — stale by at most K rounds but still anytime-valid (frozen
-    intersected CIs can only be supersets of the fresher ones, the same
-    trick the host uses with ``sync_every``). The full merge fires at
-    the START of a round — on data the current round's scan does not
-    depend on, so XLA can overlap the collective with the gather/fold —
-    on a DETERMINISTIC schedule: exactly when K rounds of delta are
-    pending, decided from the replicated ``pend_rounds`` counter. No
-    per-round hint, no scalar ``pmax`` — between merges there is zero
-    cross-shard communication. Termination is merge-then-confirm
-    (decisions only ever read fully-merged stats). It never comes
-    before the K=1 loop's, but can come more than K-1 rounds after it:
-    the intervals are intersected only at merges, a subset of the K=1
-    looks, and a later look's interval need not be narrower than an
-    earlier one (RangeTrim's lower bound on a few rows can sit above
-    its lower bound on more), so the K=1 loop may stop on a look the
-    cadence skips. Every dispatch flushes its pending delta on exit, so host
-    syncs, ``on_sync`` snapshots and termination always observe
-    fully-merged state. With ``merge_every=1`` (default) this path is
-    not even traced — the per-round-merge loop above survives bitwise
-    as the oracle.
-    """
-    cadence = shard is not None and shard.merge_every > 1
-    acct_kw = dict(lim=nb, probe=probe, window=window, budget=budget,
-                   lookahead=lookahead, cover_cap=cover_cap)
-
-    def scan_round(bufs, pos, sel_active):
-        def flags_src(ok, b0):
-            if not probe:
-                return ok
-            aw = pack_active_device(sel_active, n_words)
-            act = kops.active_blocks(_window_rows(bufs.words, b0, window),
-                                     aw, impl=impl) > 0
-            return ok & act
-
-        return _round_scan(bufs, pos, flags_src, nb=nb, window=window,
-                           budget=budget)
-
-    def refresh(bufs, c: QueryLoopCarry, k, pos, state, hist, tainted,
-                exact) -> dict:
-        """CI refresh + stopping condition (engine-supplied) at round
-        ``k`` with the cursor at ``pos``."""
-        with jax.named_scope("refresh"):
-            r = jnp.where(pos > 0, bufs.cum_rows[jnp.maximum(pos - 1, 0)],
-                          0).astype(jnp.float64)
-            lo, hi, est, refreshed, active = refresh_fn(
-                k, r, state, hist, tainted, exact, c.lo, c.hi, c.est,
-                c.refreshed, c.active)
-            live = active.any()
-            stopped_early = c.stopped_early | (~live & (pos < nb))
-        return dict(lo=lo, hi=hi, est=est, refreshed=refreshed,
-                    active=active, live=live, stopped_early=stopped_early)
-
-    def body(bufs, c: QueryLoopCarry) -> QueryLoopCarry:
-        k = c.rounds + 1
-        scan = scan_round(bufs, c.pos, c.active)
-        win, ok, flags, take, csum, new_pos, covmask = scan
-        # Under shard_map the local slab is this shard's row slice of
-        # every block, so the global block ids gather exactly the
-        # shard's 1/n_shards of the selection — no translation needed.
-        v, g, m = _gather_rows(bufs.values, bufs.gids, bufs.mask, csum, win,
-                               window, budget)
-        dstate, dhist = _fold(v, g, m, center, a, b, num_groups, nbins,
-                              use_hist, impl,
-                              shard_axes=shard.axes if shard else None)
-        state, hist = _merge_round(c.state, c.hist, dstate, dhist, use_hist)
-        acct = _account(c, bufs.presence, bufs.presence_total, scan,
-                        **acct_kw)
-        return c._replace(
-            pos=new_pos, rounds=k, it=c.it + 1, state=state, hist=hist,
-            **acct, **refresh(bufs, c, k, new_pos, state, hist,
-                              acct["tainted"], acct["exact"]))
-
-    # -- collective cadence (shard.merge_every = K > 1) ------------------
-
-    def _merge_refresh(bufs, c: QueryLoopCarry) -> QueryLoopCarry:
-        """Fire the collective set on the pending multi-round delta,
-        fold it into the merged running state and re-evaluate the CIs /
-        stopping condition on fully-merged stats. Valid both at a round
-        start (delta-schedule index ``c.rounds`` — the rounds whose data
-        the merged state now covers) and at the dispatch-exit flush;
-        merges zero the pending slots, so each index is consumed at most
-        once (the schedule stays a subset of the K=1 one and the union
-        bound over ``delta`` holds)."""
-        with jax.named_scope("merge"):
-            sums = jax.lax.psum(c.pend_sums, shard.axes)
-            vmin, vmax = _pmin_pmax_f64(c.pend_vmin, c.pend_vmax,
-                                        shard.axes)
-            dstate = kops.moments_from_sums(sums, vmin, vmax, center)
-            state = merge_moments(c.state, dstate)
-            hist = (c.hist + jax.lax.psum(c.pend_hist, shard.axes)
-                    if use_hist else c.hist)
-        return c._replace(
-            state=state, hist=hist, pend_rounds=jnp.asarray(0, jnp.int32),
-            **_zeroed_pending(c, use_hist),
-            **refresh(bufs, c, c.rounds, c.pos, state, hist, c.tainted,
-                      c.exact))
-
-    def cadence_body(bufs, c: QueryLoopCarry) -> QueryLoopCarry:
-        # Selection runs on the PRE-merge active mask, so this round's
-        # scan/gather/fold has no data dependence on the merge and XLA
-        # is free to overlap the collective with the compute (the merge
-        # gates round k+1). The merge schedule is deterministic — fire
-        # exactly when K rounds of delta are pending — and pend_rounds
-        # is replicated, so every shard takes the same branch and the
-        # collectives inside the cond rendezvous; between merges no
-        # cross-shard communication happens at all.
-        sel_active = c.active
-        c = jax.lax.cond(c.pend_rounds >= shard.merge_every,
-                         functools.partial(_merge_refresh, bufs),
-                         lambda x: x, c)
-        scan = scan_round(bufs, c.pos, sel_active)
-        win, ok, flags, take, csum, new_pos, covmask = scan
-        v, g, m = _gather_rows(bufs.values, bufs.gids, bufs.mask, csum, win,
-                               window, budget)
-        dsums, dvmin, dvmax, dhist = _fold_local(
-            v, g, m, center, a, b, num_groups, nbins, use_hist, impl)
-        # accounting: replicated, every round (same as the K=1 body)
-        return c._replace(
-            pos=new_pos, rounds=c.rounds + 1, it=c.it + 1,
-            pend_rounds=c.pend_rounds + 1,
-            **_pend_round(c, dsums, dvmin, dvmax, dhist, use_hist),
-            **_account(c, bufs.presence, bufs.presence_total, scan,
-                       **acct_kw))
-
-    def flush(bufs, carry: QueryLoopCarry) -> QueryLoopCarry:
-        # every dispatch exits fully merged: termination / sync_every
-        # snapshots never see stale stats, and the pending slots leave
-        # the shard_map as replicated zeros. pend_rounds == 0 implies
-        # the pending slots are already zero.
-        return jax.lax.cond(carry.pend_rounds > 0,
-                            functools.partial(_merge_refresh, bufs),
-                            lambda x: x, carry)
-
-    loop_body = cadence_body if cadence else body
-
-    def cond(c: QueryLoopCarry):
-        go = c.live & (c.pos < nb) & (c.rounds < max_rounds)
-        if chunk is not None:
-            go = go & (c.it < chunk)
-        return go
-
-    def chunk_body(bufs: QueryLoopBuffers,
-                   carry: QueryLoopCarry) -> QueryLoopCarry:
-        carry = carry._replace(
-            it=jnp.asarray(0, jnp.int32),
-            processed=_pad_processed(carry.processed, window))
-        carry = jax.lax.while_loop(cond,
-                                   functools.partial(loop_body, bufs),
-                                   carry)
-        if cadence:
-            carry = flush(bufs, carry)
-        return carry._replace(processed=_fold_processed(carry.processed,
-                                                        nb))
-
-    if shard is None:
-        return jax.jit(chunk_body)
-
-    rep = P()
-    data = P(None, shard.axes)  # row-axis sliced, block axis whole
-    bufs_spec = QueryLoopBuffers(
-        values=data, gids=data, mask=data, words=rep, order_pad=rep,
-        static_ok=rep, presence=rep, presence_total=rep, cum_rows=rep)
-    carry_spec = _query_carry_spec(use_hist, cadence)
-    # check_vma=False: replication of the carry holds by construction
-    # (replicated inputs -> replicated selection/accounting; the fold
-    # delta is re-replicated by its psum) but the checker cannot see
-    # through while_loop + axis_index.
-    return jax.jit(jax.shard_map(
-        chunk_body, mesh=shard.mesh, in_specs=(bufs_spec, carry_spec),
-        out_specs=carry_spec, check_vma=False))
-
-
 class SlotSpec(NamedTuple):
-    """Static per-slot configuration of the multi-query pass loop."""
+    """Static per-slot configuration of the pass loop."""
 
     num_groups: int
     nbins: int
@@ -1015,8 +726,11 @@ class SlotSpec(NamedTuple):
 
 
 class PassLoopBuffers(NamedTuple):
-    """Device-resident inputs of the multi-query pass loop; the per-slot
-    fields are length-S tuples."""
+    """Device-resident inputs of the pass loop (constant across rounds;
+    passed as jit arguments so reuse never retraces); the per-slot fields
+    are length-S tuples. The block-id buffers ``static_ok``, ``words``
+    and ``presence`` are :func:`pad_blocks`-padded, ``order_pad`` is
+    :func:`rotation_order_pad`'s."""
 
     mask: jax.Array            # (nb, block_rows) shared predicate mask
     order_pad: jax.Array       # (nb + window,) i32 (rotation_order_pad)
@@ -1024,17 +738,19 @@ class PassLoopBuffers(NamedTuple):
     cum_rows: jax.Array        # (nb,) i64
     values: Tuple[jax.Array, ...]          # per-slot value columns
     gids: Tuple[jax.Array, ...]            # per-slot group codes
-    words: Tuple[jax.Array, ...]           # per-slot (nb + window, W_s)
+    words: Tuple[Optional[jax.Array], ...]  # per-slot (nb + window, W_s)
+                                            # group-bitmap words; None
+                                            # for a slot without the probe
     presence: Tuple[jax.Array, ...]        # per-slot (nb + window, G_s)
     presence_total: Tuple[jax.Array, ...]  # per-slot (G_s,) i32
 
 
 class SlotCarry(NamedTuple):
     """Per-slot scan state inside the pass carry. Every slot owns its
-    cursor, selection, fold, coverage and metrics — the device twin of a
-    solo query-loop carry — so a slot's scan replays its solo run
-    exactly regardless of what else is co-resident in the pass (the
-    slot-level bitwise co-residency contract; see docs/serving.md)."""
+    cursor, selection, fold, coverage and metrics, so a slot's scan
+    replays its solo run exactly regardless of what else is co-resident
+    in the pass (the slot-level bitwise co-residency contract; see
+    docs/serving.md)."""
 
     pos: jax.Array             # i32 slot cursor (pass coordinates; the
                                # slot's lap is [anchor, anchor + nb))
@@ -1050,9 +766,12 @@ class SlotCarry(NamedTuple):
     probes: jax.Array          # i64
     lap_rounds: jax.Array      # i32 round the slot's lap ended (-1 while
                                # still inside the lap)
-    # collective-cadence pending slots (merge_every > 1 only, else None;
-    # see QueryLoopCarry — this shard's raw additive delta since the
-    # last full merge, zeroed by every merge)
+    # -- collective-cadence slots (``ShardInfo.merge_every > 1`` only;
+    # None otherwise, so the K=1 carry pytree — and its trace — is
+    # unchanged). They hold this shard's raw additive fold delta
+    # accumulated since the last full merge; they are zeroed by every
+    # merge and every dispatch exits freshly merged (flush), so the
+    # out-spec replication of the carry still holds.
     pend_sums: Optional[jax.Array] = None    # (3, G_s) f64
     pend_vmin: Optional[jax.Array] = None    # (G_s,) f64
     pend_vmax: Optional[jax.Array] = None    # (G_s,) f64
@@ -1063,7 +782,8 @@ class PassQueryCarry(NamedTuple):
     """Per-query OptStop state + finish-time snapshots. A query's result
     is a consistent snapshot of the slot state at the round it finished
     (the slot keeps scanning for the pass's remaining queries), so the
-    carry records the slot/metric state the moment ``finished`` flips."""
+    carry records the slot/metric state the moment ``finished`` flips.
+    The snapshots are meaningful only once ``finished`` is set."""
 
     lo: jax.Array              # (G_s,) f64
     hi: jax.Array              # (G_s,) f64
@@ -1084,9 +804,9 @@ class PassQueryCarry(NamedTuple):
 
 
 class PassCarry(NamedTuple):
-    """``lax.while_loop`` carry of the multi-query pass loop. All
-    per-scan state lives in the per-slot :class:`SlotCarry` entries —
-    the pass itself only keeps the shared round clock and liveness."""
+    """``lax.while_loop`` carry of the pass loop. All per-scan state
+    lives in the per-slot :class:`SlotCarry` entries — the pass itself
+    only keeps the shared round clock and liveness."""
 
     rounds: jax.Array          # i32 pass rounds (shared clock)
     it: jax.Array              # i32 rounds inside the current dispatch
@@ -1102,9 +822,10 @@ class PassCarry(NamedTuple):
 def _pass_carry_spec(slot_specs: Sequence[SlotSpec],
                      n_queries: Sequence[int],
                      cadence: bool = False) -> "PassCarry":
-    """Fully-replicated shard_map partition spec of the pass carry (the
-    cadence pending slots leave every dispatch zeroed — see
-    :func:`_query_carry_spec`)."""
+    """Fully-replicated shard_map partition spec of the pass carry. The
+    cadence pending slots are per-shard state, but every dispatch exits
+    with them zeroed (flush), so they too are replicated at the
+    shard_map boundary."""
     rep = P()
     pend = rep if cadence else None
     qspec = PassQueryCarry(*([rep] * len(PassQueryCarry._fields)))
@@ -1136,8 +857,6 @@ def carry_nonfinite_slots(carry: PassCarry) -> Tuple[bool, ...]:
     touched yet, so only NaN counts as poison there. The serving layer
     uses this to quarantine a poison query's slot at a chunk boundary
     without inspecting co-resident slots (membership independence)."""
-    import numpy as np
-
     flags = []
     for slot in carry.slots:
         count, mean, m2, vmin, vmax = (
@@ -1161,19 +880,30 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
                     round_offsets: Optional[Sequence[int]] = None,
                     row_offsets: Optional[Sequence[int]] = None
                     ) -> Callable:
-    """Build the jitted device-resident loop for one FrameServer pass
-    (S slots, each with its own queries and its OWN cursor walk).
+    """Build the jitted device-resident round loop of one pass: S slots,
+    each with its own queries and its OWN cursor walk. ``FastFrame.run``
+    builds it with one slot holding one query, at anchor 0;
+    :class:`repro.serve.SharedPass` with every slot of a served pass.
+
+    Returns ``chunk_fn(bufs: PassLoopBuffers, carry: PassCarry) ->
+    PassCarry`` executing up to ``chunk`` pass rounds (``None`` = until
+    every query stopped, every slot's lap ended or ``max_rounds`` is hit)
+    in a single ``lax.while_loop`` dispatch.
 
     Every slot advances independently each pass round: its own window
     slice at its own cursor, its own activity flags (the union over the
     slot's queries only), its own budgeted selection, gather, fold and
-    coverage/taint/metric accounting — the exact device twin of a solo
-    :func:`build_query_loop` run on the scan order rotated to the slot's
-    anchor. Per-query CI refresh / stop tests use slot-local round/row
-    counts, with finish-time snapshots recorded in the carry (the host
-    materializes each query's result after the loop from the snapshot
-    taken the round it finished). ``refresh_fns[s][q]`` has the
-    :func:`build_query_loop` ``refresh_fn`` signature.
+    coverage/taint/metric accounting. Each slot round is the exact device
+    twin of the host round: ``fused_round``'s scan/fold, the f64 state
+    merge, ``_fused_accounting``'s skip/taint/probe bookkeeping,
+    ``_ScanViews.update_exact`` and each query's ``refresh_fns[s][q]``
+    (CI refresh + stopping condition; see
+    ``engine._make_device_refresh``). ``refresh_fn(k, r, state, hist,
+    tainted, exact, lo, hi, est, refreshed, active)`` returns the updated
+    ``(lo, hi, est, refreshed, active)``; it is called with slot-local
+    round/row counts, and finish-time snapshots are recorded in the
+    carry (the host materializes each query's result after the loop from
+    the snapshot taken the round it finished).
 
     Because nothing is shared between slots but the round clock, a
     slot's selection/fold/refresh sequence is bitwise identical to its
@@ -1182,7 +912,14 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
     slot-level bitwise co-residency contract, docs/serving.md). A slot
     whose lap ended (``pos >= anchor + nb``) or whose queries all
     finished is frozen in place; the loop exits when no slot can make
-    progress.
+    progress. A slot without the probe selects on its static verdicts
+    alone: while it is live it has an unfinished query.
+
+    The builder specialises on what it can see: with one slot, the loop
+    runs only while that slot is live, so its rounds freeze nothing;
+    with one query in it, the loop runs only while that query is
+    unfinished and exits the round it finishes, so its refresh keeps
+    nothing and its snapshots are that round's values.
 
     ``anchors[s]`` is the slot's static admission position in pass
     coordinates (``None`` = all zero, the static-batch case): the slot's
@@ -1195,21 +932,38 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
     needs no extension). Mid-scan admission is therefore just another
     anchor — carousel passes, sharded or not, run this same loop.
 
-    ``shard`` shards the pass exactly like :func:`build_query_loop`:
+    With ``shard`` the whole loop runs under ``shard_map`` on
+    ``shard.mesh``, the scan divided as the module docstring describes:
     every slot's value/group columns and the shared mask are
-    row-slice-sharded slabs, each slot's selection / accounting /
-    refreshes stay replicated, each shard gathers and folds only its
-    ``1/n_shards`` row slice of the slot's selected blocks, and the
-    per-round fold delta merges across the mesh inside :func:`_fold`
-    (one collective set per slot per round). ``shard.merge_every = K >
-    1`` applies the deterministic collective cadence of
-    :func:`build_query_loop` to the whole pass: one shared
-    ``pend_rounds`` schedule (merges fire at a round start exactly when
-    K rounds of delta are pending — zero cross-shard communication
-    between merges), per-slot pending delta slots, per-query intervals /
-    finished flags frozen between merges (selection gates on the stale
-    flags — at most K rounds of extra blocks for a query that just
-    finished), and finish-time snapshots recorded at merges. The cadence
+    row-slice-sharded slabs (see :class:`ShardInfo`), every other buffer
+    AND the entire carry stay replicated, and each shard runs the
+    IDENTICAL round body on its own row slice. Only the fold delta
+    crosses the mesh (``psum``/``pmin``/``pmax`` inside :func:`_fold`,
+    one collective set per slot per round, no host sync).
+
+    ``shard.merge_every = K > 1`` amortizes that collective set over K
+    rounds (the *collective cadence*; see ``docs/architecture.md``).
+    Each round folds only into the slots' f64 pending slots (this
+    shard's raw additive delta since the last merge) and the reported
+    intervals / finished flags stay frozen at their last fully-merged
+    values — stale by at most K rounds but still anytime-valid (frozen
+    intersected CIs can only be supersets of the fresher ones).
+    Selection gates on the stale flags, so a query that just finished
+    pulls at most K rounds of extra blocks. The full merge fires at the
+    START of a round — on data the round's scan does not depend on, so
+    XLA can overlap the collective with the gather/fold — exactly when K
+    rounds of delta are pending, decided from the replicated
+    ``pend_rounds`` counter: between merges there is zero cross-shard
+    communication. Termination is merge-then-confirm (decisions only
+    ever read fully-merged stats), and finish-time snapshots are
+    recorded at merges. It never comes before the K=1 loop's, but can
+    come more than K-1 rounds after it: the intervals are intersected
+    only at merges, a subset of the K=1 looks, and a later look's
+    interval need not be narrower than an earlier one (RangeTrim's lower
+    bound on a few rows can sit above its lower bound on more). Every
+    dispatch flushes its pending delta on exit, so host syncs,
+    ``on_sync`` snapshots and termination always observe fully-merged
+    state. With ``merge_every=1`` the cadence is not even traced. It
     requires all anchors at zero: a mid-lap joiner's observable round
     boundaries would be merge boundaries, up to K rounds apart, so its
     delta schedule could not match its solo run.
@@ -1238,34 +992,36 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         take. Returns ``_round_scan``'s tuple."""
 
         def flags_src(ok, b0):
-            if spec.probe:
-                rows = [pack_active_device(qc.active, spec.n_words)
-                        for qc in sel_queries[s]]
-            else:
-                rows = [(~qc.finished).astype(jnp.uint32).reshape(1)
-                        for qc in sel_queries[s]]
-            stack = jnp.stack(rows)
-            act = kops.active_blocks_multi(
-                _window_rows(bufs.words[s], b0, window), stack,
-                impl=impl) > 0
+            if not spec.probe:
+                return ok
+            words = _window_rows(bufs.words[s], b0, window)
+            rows = [pack_active_device(qc.active, spec.n_words)
+                    for qc in sel_queries[s]]
+            if len(rows) == 1:
+                return ok & (kops.active_blocks(words, rows[0],
+                                                impl=impl) > 0)
+            act = kops.active_blocks_multi(words, jnp.stack(rows),
+                                           impl=impl) > 0
             return (ok[None, :] & act).any(axis=0)
 
+        # a lap that starts at 0 ends at nb: its cursor needs no wrap
         return _round_scan(bufs, sc.pos, flags_src, nb=nb, window=window,
-                           budget=budget, bound=lap_ends[s], wrap=True)
+                           budget=budget, bound=lap_ends[s],
+                           wrap=anchors[s] > 0)
 
     def _slot_account(bufs, sc, s, spec, k, scan):
-        """Slot-local coverage / taint / metric accounting for one round
-        (twin of the solo loop's accounting block); returns the updated
-        SlotCarry fields as a dict."""
+        """Slot-local coverage / taint / metric accounting for one round;
+        returns the updated SlotCarry fields as a dict."""
         le = lap_ends[s]
         acct = _account(sc, bufs.presence[s], bufs.presence_total[s], scan,
                         lim=le, probe=spec.probe, window=window,
                         budget=budget, lookahead=lookahead,
                         cover_cap=cover_cap)
-        new_pos = scan[5]
-        with jax.named_scope("account"):
-            acct["lap_rounds"] = jnp.where(
-                (sc.pos < le) & (new_pos >= le), k, sc.lap_rounds)
+        if S > 1:   # one slot: _settle records it at exit
+            new_pos = scan[5]
+            with jax.named_scope("account"):
+                acct["lap_rounds"] = jnp.where(
+                    (sc.pos < le) & (new_pos >= le), k, sc.lap_rounds)
         return acct
 
     def _slot_rows(bufs, s, p_end):
@@ -1273,14 +1029,33 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         refresh: rows over pass positions are periodic with period
         ``nb`` (one lap = the whole scramble), so laps + ``cum_rows``
         suffice; ``row_offsets[s]`` rebases to the slot's own lap."""
-        p_end = jnp.minimum(p_end, lap_ends[s])
-        pm1 = p_end - 1
-        rows_abs = jnp.where(
-            p_end > 0,
-            (pm1 // nb).astype(i64) * bufs.cum_rows[nb - 1]
-            + bufs.cum_rows[pm1 % nb],
-            jnp.asarray(0, i64))
-        return (rows_abs - row_offsets[s]).astype(jnp.float64)
+        if anchors[s] == 0:
+            # a lap from 0 ends at nb, where the cursor stops
+            rows = bufs.cum_rows[jnp.maximum(p_end - 1, 0)]
+        else:  # a lap that starts mid-scan wraps past nb
+            pm1 = jnp.minimum(p_end, lap_ends[s]) - 1
+            rows = ((pm1 // nb).astype(i64) * bufs.cum_rows[nb - 1]
+                    + bufs.cum_rows[pm1 % nb] - row_offsets[s])
+        return jnp.where(p_end > 0, rows, 0).astype(jnp.float64)
+
+    def _snapshot(qc, take, k_s, p_end, le, metrics, count, exact,
+                  tainted):
+        """``qc`` with its finish-time snapshot set where ``take`` (a
+        traced bool, or True) holds: slot round ``k_s``, the slot's cursor
+        ``p_end`` and scan counters ``metrics``, and its counts, exact and
+        tainted views."""
+        snap = ((lambda new, old: new) if take is True
+                else (lambda new, old: jnp.where(take, new, old)))
+        return qc._replace(**{
+            f: snap(new, getattr(qc, f)) for f, new in (
+                ("stopped_early", p_end < le), ("finish_rounds", k_s),
+                ("finish_pos", p_end),
+                ("finish_blocks_fetched", metrics["blocks_fetched"]),
+                ("finish_skipped_static", metrics["skipped_static"]),
+                ("finish_skipped_active", metrics["skipped_active"]),
+                ("finish_probes", metrics["probes"]),
+                ("snap_counts", count), ("snap_exact", exact),
+                ("snap_tainted", tainted))})
 
     def _slot_refresh(bufs, s, queries, k_s, p_end, state, hist, tainted,
                       exact, metrics, live, n_live):
@@ -1289,9 +1064,13 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         snapshots (``metrics``: the slot's scan counters) of queries that
         finish now. A frozen slot (``live`` False) stops refreshing: a
         lapped slot's solo twin exited the loop at exhaustion, and its
-        still-active queries await the host recovery pass. Returns the
-        new query carries and the pass's live-query count."""
-        le = lap_ends[s]
+        still-active queries await the host recovery pass. ``live`` is
+        None in the one-slot body, which runs only while its slot is
+        live: :func:`_settle` counts its live queries at exit, and a lone
+        query's snapshot too (it is unfinished on entry and the loop
+        exits the round it finishes). Returns the new query carries and
+        the pass's live-query count."""
+        lone = live is None and len(queries) == 1
         with jax.named_scope("refresh"):
             r_s = _slot_rows(bufs, s, p_end)
             out = []
@@ -1299,36 +1078,49 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
                 nlo, nhi, nest, nrefr, nact = refresh_fns[s][qi](
                     k_s, r_s, state, hist, tainted, exact, qc.lo, qc.hi,
                     qc.est, qc.refreshed, qc.active)
-                fin = qc.finished
-                skip = fin | ~live
+                if lone:
+                    out.append(qc._replace(
+                        lo=nlo, hi=nhi, est=nest, refreshed=nrefr,
+                        active=nact, finished=~nact.any()))
+                    continue
+                skip = qc.finished if live is None else (qc.finished
+                                                         | ~live)
                 keep = lambda old, new: jnp.where(skip, old, new)
                 active = keep(qc.active, nact)
-                now_fin = live & ~fin & ~active.any()
-                n_live = n_live - now_fin.astype(i32)
-                snap = lambda new, old: jnp.where(now_fin, new, old)
-                out.append(qc._replace(
-                    lo=keep(qc.lo, nlo), hi=keep(qc.hi, nhi),
-                    est=keep(qc.est, nest),
-                    refreshed=keep(qc.refreshed, nrefr),
-                    active=active, finished=fin | now_fin,
-                    stopped_early=snap(p_end < le, qc.stopped_early),
-                    finish_rounds=snap(k_s, qc.finish_rounds),
-                    finish_pos=snap(p_end, qc.finish_pos),
-                    finish_blocks_fetched=snap(
-                        metrics["blocks_fetched"],
-                        qc.finish_blocks_fetched),
-                    finish_skipped_static=snap(
-                        metrics["skipped_static"],
-                        qc.finish_skipped_static),
-                    finish_skipped_active=snap(
-                        metrics["skipped_active"],
-                        qc.finish_skipped_active),
-                    finish_probes=snap(metrics["probes"],
-                                       qc.finish_probes),
-                    snap_counts=snap(state.count, qc.snap_counts),
-                    snap_exact=snap(exact, qc.snap_exact),
-                    snap_tainted=snap(tainted, qc.snap_tainted)))
+                now_fin = ~skip & ~active.any()
+                if live is not None:
+                    n_live = n_live - now_fin.astype(i32)
+                out.append(_snapshot(
+                    qc._replace(lo=keep(qc.lo, nlo), hi=keep(qc.hi, nhi),
+                                est=keep(qc.est, nest),
+                                refreshed=keep(qc.refreshed, nrefr),
+                                active=active,
+                                finished=qc.finished | now_fin),
+                    now_fin, k_s, p_end, lap_ends[s], metrics, state.count,
+                    exact, tainted))
         return tuple(out), n_live
+
+    def _slot_live(c, s):
+        """Whether slot ``s`` can progress: inside its lap with an
+        unfinished query. A slot that cannot is frozen in place: its solo
+        twin would have exited its loop, so letting the cursor run on
+        would diverge the slot's metrics (and, with every query finished,
+        cover ground without selecting — spuriously tainting the
+        views)."""
+        unfin = functools.reduce(
+            jnp.logical_or, [~qc.finished for qc in c.queries[s]])
+        return (c.slots[s].pos < lap_ends[s]) & unfin
+
+    def _advanced(sc, new, slot_live):
+        """``sc`` with the round's ``new`` fields, or unchanged where the
+        slot is frozen (``slot_live`` None: the slot is live)."""
+        new = {f: x for f, x in new.items() if x is not None}
+        with jax.named_scope("account"):
+            if slot_live is not None:
+                new = {f: jax.tree.map(
+                    lambda a, b: jnp.where(slot_live, a, b), x,
+                    getattr(sc, f)) for f, x in new.items()}
+            return sc._replace(**new)
 
     def body(bufs, c: PassCarry) -> PassCarry:
         k = c.rounds + 1
@@ -1337,15 +1129,8 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
         n_live = c.n_live
         for s, spec in enumerate(slot_specs):
             sc = c.slots[s]
-            le = lap_ends[s]
-            any_unfin = functools.reduce(
-                jnp.logical_or, [~qc.finished for qc in c.queries[s]])
-            # a slot whose lap ended or whose queries all finished is
-            # frozen in place: its solo twin would have exited its loop,
-            # so letting the cursor run on would diverge the slot's
-            # metrics (and, with every query finished, cover ground
-            # without selecting — spuriously tainting the views)
-            slot_live = (sc.pos < le) & any_unfin
+            # one slot: the loop's cond already requires it to be live
+            slot_live = None if S == 1 else _slot_live(c, s)
             scan = _slot_select(bufs, sc, s, spec, c.queries)
             win, ok, flags, take, csum, new_pos, covmask = scan
             # Under shard_map the local slab is this shard's row slice
@@ -1361,46 +1146,33 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
                                        spec.use_hist)
             acct = _slot_account(bufs, sc, s, spec, k, scan)
             tainted, exact = acct["tainted"], acct["exact"]
-
-            frz = lambda new, old: jnp.where(slot_live, new, old)
-            with jax.named_scope("account"):
-                new_slots.append(SlotCarry(
-                    pos=frz(new_pos, sc.pos),
-                    state=jax.tree.map(frz, state, sc.state),
-                    hist=(frz(hist, sc.hist) if spec.use_hist else None),
-                    seen_presence=frz(acct["seen_presence"],
-                                      sc.seen_presence),
-                    tainted=frz(tainted, sc.tainted),
-                    exact=frz(exact, sc.exact),
-                    processed=frz(acct["processed"], sc.processed),
-                    blocks_fetched=frz(acct["blocks_fetched"],
-                                       sc.blocks_fetched),
-                    skipped_static=frz(acct["skipped_static"],
-                                       sc.skipped_static),
-                    skipped_active=frz(acct["skipped_active"],
-                                       sc.skipped_active),
-                    probes=frz(acct["probes"], sc.probes),
-                    lap_rounds=frz(acct["lap_rounds"], sc.lap_rounds)))
+            new_slots.append(_advanced(
+                sc, dict(pos=new_pos, state=state, hist=hist, **acct),
+                slot_live))
 
             slot_queries, n_live = _slot_refresh(
                 bufs, s, c.queries[s], k - round_offsets[s], new_pos, state,
                 hist, tainted, exact, acct, slot_live, n_live)
             new_queries.append(slot_queries)
 
-        return PassCarry(
+        return c._replace(
             rounds=k, it=c.it + 1, n_live=n_live,
             slots=tuple(new_slots), queries=tuple(new_queries))
 
     # -- collective cadence (shard.merge_every = K > 1) ------------------
 
-    def _merge_refresh_pass(bufs, c: PassCarry) -> PassCarry:
-        """Pass twin of build_query_loop's ``_merge_refresh``: one
-        collective set per slot on the pending multi-round deltas, then
+    def _merge_refresh(bufs, c: PassCarry) -> PassCarry:
+        """Fire one collective set per slot on the pending multi-round
+        deltas, fold them into the merged running states and re-evaluate
         every unfinished query's CI refresh / stop test on fully-merged
-        stats (delta-schedule index ``c.rounds``), with finish-time
-        snapshots taken from the merged values. Frozen slots carry
-        zeroed pending deltas (they stopped folding when they froze),
-        so their collectives are no-ops and their queries are already
+        stats (delta-schedule index ``c.rounds`` — the rounds whose data
+        the merged state now covers), with finish-time snapshots taken
+        from the merged values. Valid both at a round start and at the
+        dispatch-exit flush; merges zero the pending slots, so each index
+        is consumed at most once (the schedule stays a subset of the K=1
+        one and the union bound over ``delta`` holds). Frozen slots carry
+        zeroed pending deltas (they stopped folding when they froze), so
+        their collectives are no-ops and their queries are already
         finished or awaiting the dispatch-exit flush."""
         new_slots = []
         new_queries = []
@@ -1430,24 +1202,23 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
             pend_rounds=jnp.asarray(0, i32))
 
     def cadence_body(bufs, c: PassCarry) -> PassCarry:
-        # see build_query_loop.cadence_body: the merge fires at the
-        # round start on the replicated pend_rounds counter (a
-        # deterministic schedule — no per-round hint, no pmax, zero
-        # cross-shard communication between merges); selection gates on
-        # the PRE-merge per-query flags so the merge collective overlaps
-        # the scan, and intervals / finished flags only change at
-        # merges.
+        # Selection gates on the PRE-merge per-query flags, so this
+        # round's scan/gather/fold has no data dependence on the merge
+        # and XLA is free to overlap the collective with the compute (the
+        # merge gates round k+1). The merge schedule is deterministic —
+        # fire exactly when K rounds of delta are pending — and
+        # pend_rounds is replicated, so every shard takes the same branch
+        # and the collectives inside the cond rendezvous; between merges
+        # no cross-shard communication happens at all. Intervals and
+        # finished flags only change at merges.
         sel_queries = c.queries
         c = jax.lax.cond(c.pend_rounds >= shard.merge_every,
-                         functools.partial(_merge_refresh_pass, bufs),
+                         functools.partial(_merge_refresh, bufs),
                          lambda x: x, c)
         k = c.rounds + 1
         new_slots = []
         for s, spec in enumerate(slot_specs):
             sc = c.slots[s]
-            any_unfin = functools.reduce(
-                jnp.logical_or, [~qc.finished for qc in c.queries[s]])
-            slot_live = (sc.pos < lap_ends[s]) & any_unfin
             scan = _slot_select(bufs, sc, s, spec, sel_queries)
             win, ok, flags, take, csum, new_pos, covmask = scan
             v, g, m = _gather_rows(bufs.values[s], bufs.gids[s], bufs.mask,
@@ -1455,52 +1226,51 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
             dsums, dvmin, dvmax, dhist = _fold_local(
                 v, g, m, spec.center, spec.a, spec.b, spec.num_groups,
                 spec.nbins, spec.use_hist, impl)
-            pend = _pend_round(sc, dsums, dvmin, dvmax, dhist,
-                               spec.use_hist)
-            acct = _slot_account(bufs, sc, s, spec, k, scan)
-
-            frz = lambda new, old: jnp.where(slot_live, new, old)
-            with jax.named_scope("account"):
-                new_slots.append(sc._replace(
-                    pos=frz(new_pos, sc.pos),
-                    seen_presence=frz(acct["seen_presence"],
-                                      sc.seen_presence),
-                    tainted=frz(acct["tainted"], sc.tainted),
-                    exact=frz(acct["exact"], sc.exact),
-                    processed=frz(acct["processed"], sc.processed),
-                    blocks_fetched=frz(acct["blocks_fetched"],
-                                       sc.blocks_fetched),
-                    skipped_static=frz(acct["skipped_static"],
-                                       sc.skipped_static),
-                    skipped_active=frz(acct["skipped_active"],
-                                       sc.skipped_active),
-                    probes=frz(acct["probes"], sc.probes),
-                    lap_rounds=frz(acct["lap_rounds"], sc.lap_rounds),
-                    pend_sums=frz(pend["pend_sums"], sc.pend_sums),
-                    pend_vmin=frz(pend["pend_vmin"], sc.pend_vmin),
-                    pend_vmax=frz(pend["pend_vmax"], sc.pend_vmax),
-                    pend_hist=(frz(pend["pend_hist"], sc.pend_hist)
-                               if spec.use_hist else None)))
+            # accounting: replicated, every round (same as the K=1 body);
+            # the merge may have finished the slot's last query
+            new_slots.append(_advanced(
+                sc, dict(pos=new_pos,
+                         **_pend_round(sc, dsums, dvmin, dvmax, dhist,
+                                       spec.use_hist),
+                         **_slot_account(bufs, sc, s, spec, k, scan)),
+                _slot_live(c, s)))
 
         return c._replace(
             rounds=k, it=c.it + 1, slots=tuple(new_slots),
             pend_rounds=c.pend_rounds + 1)
 
     def flush(bufs, carry: PassCarry) -> PassCarry:
-        # see build_query_loop.flush
+        # every dispatch exits fully merged: termination / sync_every
+        # snapshots never see stale stats, and the pending slots leave
+        # the shard_map as replicated zeros. pend_rounds == 0 implies
+        # the pending slots are already zero.
         return jax.lax.cond(carry.pend_rounds > 0,
-                            functools.partial(_merge_refresh_pass, bufs),
+                            functools.partial(_merge_refresh, bufs),
                             lambda x: x, carry)
 
     loop_body = cadence_body if cadence else body
 
+    def _settle(c: PassCarry) -> PassCarry:
+        """One slot: the loop exits the round that slot stops being
+        live, so the round its lap ended, the live-query count and, in
+        the K=1 body, a lone query's finish-time snapshot are the carry
+        at exit, recorded here once rather than every round. A snapshot
+        is read only once its query has finished."""
+        sc, qcs = c.slots[0], c.queries[0]
+        le = lap_ends[0]
+        sc = sc._replace(lap_rounds=jnp.where(
+            (sc.pos >= le) & (sc.lap_rounds < 0), c.rounds, sc.lap_rounds))
+        if len(qcs) == 1 and not cadence:
+            qcs = (_snapshot(qcs[0], True, c.rounds - round_offsets[0],
+                             sc.pos, le, sc._asdict(), sc.state.count,
+                             sc.exact, sc.tainted),)
+        n_live = functools.reduce(
+            jnp.add, [(~qc.finished).astype(i32) for qc in qcs])
+        return c._replace(n_live=n_live, slots=(sc,), queries=(qcs,))
+
     def cond(c: PassCarry):
-        progressable = jnp.asarray(False)
-        for s in range(S):
-            unfin = functools.reduce(
-                jnp.logical_or, [~qc.finished for qc in c.queries[s]])
-            progressable = progressable | (
-                (c.slots[s].pos < lap_ends[s]) & unfin)
+        progressable = functools.reduce(
+            jnp.logical_or, [_slot_live(c, s) for s in range(S)])
         go = progressable & (c.rounds < max_rounds) & (c.n_live > 0)
         if chunk is not None:
             go = go & (c.it < chunk)
@@ -1517,6 +1287,8 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
                                    carry)
         if cadence:
             carry = flush(bufs, carry)
+        if S == 1:
+            carry = _settle(carry)
         return carry._replace(slots=tuple(
             sc._replace(processed=_fold_processed(sc.processed, nb))
             for sc in carry.slots))
@@ -1529,13 +1301,16 @@ def build_pass_loop(*, nb: int, window: int, budget: int, impl: str,
     ns = len(slot_specs)
     bufs_spec = PassLoopBuffers(
         mask=data, order_pad=rep, static_ok=rep, cum_rows=rep,
-        values=(data,) * ns, gids=(data,) * ns, words=(rep,) * ns,
+        values=(data,) * ns, gids=(data,) * ns,
+        words=tuple(rep if spec.probe else None for spec in slot_specs),
         presence=(rep,) * ns, presence_total=(rep,) * ns)
     carry_spec = _pass_carry_spec(slot_specs,
                                   [len(fns) for fns in refresh_fns],
                                   cadence)
-    # check_vma=False: see build_query_loop — carry replication holds by
-    # construction but is opaque to the checker.
+    # check_vma=False: replication of the carry holds by construction
+    # (replicated inputs -> replicated selection/accounting; the fold
+    # delta is re-replicated by its psum) but the checker cannot see
+    # through while_loop + axis_index.
     return jax.jit(jax.shard_map(
         chunk_body, mesh=shard.mesh, in_specs=(bufs_spec, carry_spec),
         out_specs=carry_spec, check_vma=False))

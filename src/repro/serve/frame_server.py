@@ -92,11 +92,12 @@ import numpy as np
 
 from repro.aqp import distributed as adist
 from repro.aqp.bitmap import pack_mask
-from repro.aqp.engine import (FastFrame, _QueryIntervals, _ScanViews,
-                              _host_copy, _make_device_refresh,
-                              _restore_views_from_carry, _round_window)
+from repro.aqp.engine import (FastFrame, _PassSlot, _QueryIntervals,
+                              _ScanViews, _build_pass, _host_copy,
+                              _pass_buffers, _pass_carry, _round_window,
+                              _slot_columns, _write_back)
 from repro.aqp.query import AggQuery, QueryResult
-from repro.core.state import MomentState, moments_nonfinite
+from repro.core.state import moments_nonfinite
 from repro.kernels import fused_scan as kfused
 from repro.kernels import ops as kops
 from repro.serve.checkpoint import PassCheckpoint, SlotCheckpoint
@@ -116,17 +117,13 @@ class UnsupportedPassConfig(RuntimeError):
     keeps its own late check as a backstop)."""
 
 
-class _SlotExec:
-    """One (filters, column, group-by) signature inside a pass: the shared
-    fold state plus the device buffers and per-query interval states.
-
-    ``anchor`` is the pass-coordinate position where the slot was
-    admitted (its lap is ``[anchor, anchor + n_blocks)``; 0 for a static
-    batch) and ``join_round`` the pass round count at admission, so
-    slot-local OptStop rounds are ``pass_rounds - join_round``. ``pos``
-    is the slot's OWN cursor (every slot advances independently; the
-    pass tracks only the frontier ``max(pos)`` for anchoring new
-    admissions).
+class _SlotExec(_PassSlot):
+    """One (filters, column, group-by) signature inside a pass: a
+    :class:`~repro.aqp.engine._PassSlot` (the shared fold state, the
+    per-query interval states, the slot's OWN cursor — every slot
+    advances independently; the pass tracks only the frontier
+    ``max(pos)`` for anchoring new admissions — and its device columns)
+    plus what the per-round host loop needs.
 
     ``shards`` (a :class:`repro.aqp.distributed.BlockShards`) row-shards
     the slot's value/group slabs over the mesh for the sharded device
@@ -138,40 +135,24 @@ class _SlotExec:
                  anchor: int = 0, join_round: int = 0,
                  row_offset: int = 0):
         use_hist_any = any(q.needs_hist for q in queries)
-        self.views = _ScanViews(frame, rep_q, use_hist=use_hist_any,
-                                anchor=anchor)
-        self.qcis = [_QueryIntervals(frame, q, self.views) for q in queries]
-        self.anchor = anchor
-        self.join_round = join_round
-        self.row_offset = row_offset   # rows before anchor, pass coords
-        self.pos = anchor              # this slot's cursor, pass coords
-        self.lap_done_round = None     # pass round when the lap completed
-        v = self.views
-        # probe slots activity-test their real group bitmap; non-probe
-        # slots (no GROUP BY, or non-skipping sampling) carry an all-ones
-        # engagement bitmap so a finished query stops pulling blocks
-        # without changing which blocks it saw while running
-        self.probe = skipping and v.group_bm is not None
-        self.values = frame._device_values(v.value_src, shards)
-        self.gids = frame._device_gids(v.gcol, shards)
-        nb = frame.scramble.n_blocks
-        words = (v.group_bm.words if self.probe
-                 else np.ones((nb, 1), np.uint32))
-        self.words = adist.place_replicated(
-            shards, kfused.pad_blocks(words, window))
-        self.meta = (v.G, frame.config.hist_bins, v.use_hist,
-                     float(v.a), float(v.b), float(v.center))
-        self.metrics = {"skipped_static": 0, "skipped_active": 0,
-                        "probes": v.probes0}
+        v = _ScanViews(frame, rep_q, use_hist=use_hist_any, anchor=anchor)
+        # probe slots activity-test their group bitmap; the others (no
+        # GROUP BY, or non-skipping sampling) select on the static
+        # verdicts alone
+        probe = skipping and v.group_bm is not None
+        super().__init__(v, [_QueryIntervals(frame, q, v) for q in queries],
+                         probe, *_slot_columns(frame, v, probe, window,
+                                               shards),
+                         anchor=anchor, join_round=join_round,
+                         row_offset=row_offset)
 
-    def active_stack(self) -> jnp.ndarray:
-        """(Q, W) uint32 per-query active words for this round."""
-        if self.probe:
-            rows = [pack_mask(qc.active) for qc in self.qcis]
-        else:
-            rows = [np.asarray([0 if qc.finished else 1], np.uint32)
-                    for qc in self.qcis]
-        return jnp.asarray(np.stack(rows))
+    def active_stack(self) -> Optional[jnp.ndarray]:
+        """(Q, W) uint32 per-query active words for this round (None
+        without the probe)."""
+        if not self.probe:
+            return None
+        return jnp.asarray(np.stack([pack_mask(qc.active)
+                                     for qc in self.qcis]))
 
 
 class SharedPass:
@@ -561,7 +542,8 @@ class SharedPass:
                 tuple(s.words for s in self.slots), stacks,
                 nb=self.nb, window=self.window,
                 budget=cfg.round_blocks,
-                meta=tuple(s.meta for s in self.slots), impl=self.impl,
+                specs=tuple(s.spec(cfg.hist_bins) for s in self.slots),
+                impl=self.impl,
                 anchors=jnp.asarray([s.anchor for s in self.slots],
                                     jnp.int32))
         new_pos_v = np.asarray(new_pos_d)
@@ -650,14 +632,8 @@ class SharedPass:
         (and LRU-cached) per membership epoch — anchors and round
         offsets are static in the trace."""
         frame = self.frame
-        cfg = self.cfg
-        nb = self.nb
         slots = self.slots
         shards = self.shards
-        f64 = lambda x: jnp.asarray(x, jnp.float64)
-        i32 = lambda v: jnp.asarray(v, jnp.int32)
-        i64 = lambda v: jnp.asarray(v, jnp.int64)
-        rep = self._rep
 
         # the compiled pass loop (+ its order-independent device buffers)
         # is cached on the frame by the pass's static identity: repeat
@@ -673,106 +649,17 @@ class SharedPass:
                if shards is not None else None,
                tuple(s.anchor for s in slots),
                tuple(s.join_round for s in slots))
-
-        def build():
-            slot_specs = tuple(
-                kfused.SlotSpec(
-                    num_groups=s.views.G, nbins=cfg.hist_bins,
-                    use_hist=s.views.use_hist, a=float(s.views.a),
-                    b=float(s.views.b), center=float(s.views.center),
-                    probe=s.probe, n_words=int(s.words.shape[1]))
-                for s in slots)
-            refresh_fns = tuple(
-                tuple(_make_device_refresh(qc.q, qc, s.views.a,
-                                           s.views.b, qc.use_hist,
-                                           float(qc.R), s.views.valid)
-                      for qc in s.qcis)
-                for s in slots)
-            chunk_fn = kfused.build_pass_loop(
-                nb=nb, window=self.window, budget=cfg.round_blocks,
-                impl=self.impl, lookahead=self.lookahead,
-                cover_cap=self.cover_cap, max_rounds=self.max_rounds,
-                chunk=self.chunk, slot_specs=slot_specs,
-                refresh_fns=refresh_fns,
-                shard=shards.info if shards is not None else None,
-                anchors=tuple(s.anchor for s in slots),
-                round_offsets=tuple(s.join_round for s in slots),
-                row_offsets=tuple(s.row_offset for s in slots))
-            presence = tuple(rep(kfused.pad_blocks(s.views.presence,
-                                                   self.window))
-                             for s in slots)
-            presence_total = tuple(
-                rep(s.views.presence_total.astype(np.int32))
-                for s in slots)
-            return chunk_fn, presence, presence_total
-
-        chunk_fn, presence_t, presence_total_t = \
-            frame.device_loops.get_or_build(key, build)
-
-        bufs = kfused.PassLoopBuffers(
-            mask=self.mask_dev, order_pad=self.order_pad_dev,
-            static_ok=self.static_ok_dev,
-            cum_rows=rep(self.cum_rows.astype(np.int64)),
-            values=tuple(s.values for s in slots),
-            gids=tuple(s.gids for s in slots),
-            words=tuple(s.words for s in slots),
-            presence=presence_t, presence_total=presence_total_t)
-        cadence = shards is not None and shards.merge_every > 1
-
-        def _slot_pend(s):
-            # collective-cadence pending slots: empty local delta
-            if not cadence:
-                return {}
-            G = s.views.G
-            return dict(
-                pend_sums=jnp.zeros((3, G), jnp.float64),
-                pend_vmin=jnp.full((G,), np.inf, jnp.float64),
-                pend_vmax=jnp.full((G,), -np.inf, jnp.float64),
-                pend_hist=(jnp.zeros((G, cfg.hist_bins), jnp.float64)
-                           if s.views.use_hist else None))
-
-        # per-slot cursor + coverage/metrics, held ABSOLUTE in the carry
-        # (initialized from host state, written back as-is)
-        slot_carries = tuple(
-            kfused.SlotCarry(
-                pos=i32(s.pos),
-                state=MomentState(*(f64(x) for x in s.views.state)),
-                hist=(f64(s.views.hist) if s.views.use_hist else None),
-                seen_presence=jnp.asarray(
-                    s.views.seen_presence.astype(np.int32)),
-                tainted=jnp.asarray(s.views.tainted),
-                exact=jnp.asarray(s.views.exact),
-                processed=jnp.asarray(s.views.processed),
-                blocks_fetched=i64(s.views.blocks_fetched),
-                skipped_static=i64(s.metrics["skipped_static"]),
-                skipped_active=i64(s.metrics["skipped_active"]),
-                probes=i64(s.metrics["probes"]),
-                lap_rounds=i32(s.lap_done_round
-                               if s.lap_done_round is not None else -1),
-                **_slot_pend(s))
-            for s in slots)
-        query_carries = tuple(
-            tuple(kfused.PassQueryCarry(
-                lo=f64(qc.lo), hi=f64(qc.hi), est=f64(qc.est),
-                refreshed=jnp.asarray(qc.refreshed),
-                active=jnp.asarray(qc.active
-                                   & ~np.asarray(qc.finished)),
-                finished=jnp.asarray(bool(qc.finished)),
-                stopped_early=jnp.asarray(False),
-                finish_rounds=i32(0), finish_pos=i32(0),
-                finish_blocks_fetched=i64(0),
-                finish_skipped_static=i64(0),
-                finish_skipped_active=i64(0), finish_probes=i64(0),
-                snap_counts=jnp.zeros(s.views.G, jnp.float64),
-                snap_exact=jnp.zeros(s.views.G, bool),
-                snap_tainted=jnp.zeros(s.views.G, bool))
-                for qc in s.qcis)
-            for s in slots)
-        pend = dict(pend_rounds=i32(0)) if cadence else {}
-        carry = kfused.PassCarry(
-            rounds=i32(self.rounds), it=i32(0),
-            n_live=i32(self.n_live),
-            slots=slot_carries, queries=query_carries, **pend)
+        chunk_fn, presence, presence_total = frame.device_loops.get_or_build(
+            key, lambda: _build_pass(
+                frame, slots, window=self.window, lookahead=self.lookahead,
+                max_rounds=self.max_rounds, chunk=self.chunk,
+                shards=shards))
+        bufs = _pass_buffers(slots, self.mask_dev, self.order_pad_dev,
+                             self.static_ok_dev,
+                             self._rep(self.cum_rows.astype(np.int64)),
+                             presence, presence_total)
+        carry = _pass_carry(slots, rounds=self.rounds, n_live=self.n_live,
+                            nbins=self.cfg.hist_bins, shards=shards)
 
         while True:
             carry = chunk_fn(bufs, carry)
@@ -793,64 +680,36 @@ class SharedPass:
         # fetched carry, consumed by quarantine() at this boundary
         self._sentinel = kfused.carry_nonfinite_slots(carry)
 
-        # -- writeback: slots' cursor + shared fold state + metrics -------
         self.rounds = int(carry.rounds)
         self.n_live = int(carry.n_live)
-        host = _host_copy
-        for s, scarry in zip(slots, carry.slots):
-            _restore_views_from_carry(
-                s.views, scarry.state, scarry.hist, scarry.processed,
-                scarry.seen_presence, scarry.tainted, scarry.exact,
-                scarry.blocks_fetched, s.metrics, 0, 0)
-            s.metrics["skipped_static"] = int(scarry.skipped_static)
-            s.metrics["skipped_active"] = int(scarry.skipped_active)
-            s.metrics["probes"] = int(scarry.probes)
-            s.pos = int(scarry.pos)
-            if (s.pos >= s.views.lap_end
-                    and s.lap_done_round is None):
-                s.lap_done_round = int(scarry.lap_rounds)
+        newly_done = _write_back(slots, carry)
         self.pos = max([self.pos] + [s.pos for s in slots])
 
-        # -- per-query interval state + finish-time snapshot results ------
+        # finish-time snapshot results
+        host = _host_copy
         newly: List[AggQuery] = []
-        for s, qcarries in zip(slots, carry.queries):
-            le = s.views.lap_end
-            for qc, qcar in zip(s.qcis, qcarries):
-                if id(qc) in self.finished:
-                    continue  # result already materialized; carry frozen
-                qc.lo = host(qcar.lo, np.float64)
-                qc.hi = host(qcar.hi, np.float64)
-                qc.est = host(qcar.est, np.float64)
-                qc.refreshed = host(qcar.refreshed)
-                qc.active = host(qcar.active)
-                qc.finished = bool(qcar.finished)
-                if not qc.finished:
-                    continue
-                snap_counts = host(qcar.snap_counts, np.float64)
-                fpos = int(qcar.finish_pos)
-                rows_cov = self._rows_at(min(fpos, le)) - s.row_offset
-                skipped_static = int(qcar.finish_skipped_static)
-                skipped_active = int(qcar.finish_skipped_active)
-                probes = int(qcar.finish_probes)
-                self.finished[id(qc)] = QueryResult(
-                    group_codes=np.arange(s.views.G),
-                    estimate=host(qcar.est, np.float64),
-                    lo=host(qcar.lo, np.float64),
-                    hi=host(qcar.hi, np.float64),
-                    count_seen=snap_counts,
-                    nonempty=snap_counts > 0,
-                    exact=host(qcar.snap_exact),
-                    tainted=host(qcar.snap_tainted),
-                    rows_covered=rows_cov,
-                    blocks_fetched=int(qcar.finish_blocks_fetched),
-                    blocks_skipped_active=skipped_active,
-                    blocks_skipped_static=skipped_static,
-                    bitmap_probes=probes,
-                    rounds=int(qcar.finish_rounds),
-                    wall_time_s=(time.perf_counter()
-                                 - self._t0[id(qc)]),
-                    stopped_early=bool(qcar.stopped_early))
-                newly.append(qc.q)
+        for s, qc, qcar in newly_done:
+            snap_counts = host(qcar.snap_counts, np.float64)
+            fpos = int(qcar.finish_pos)
+            self.finished[id(qc)] = QueryResult(
+                group_codes=np.arange(s.views.G),
+                estimate=host(qcar.est, np.float64),
+                lo=host(qcar.lo, np.float64),
+                hi=host(qcar.hi, np.float64),
+                count_seen=snap_counts,
+                nonempty=snap_counts > 0,
+                exact=host(qcar.snap_exact),
+                tainted=host(qcar.snap_tainted),
+                rows_covered=(self._rows_at(min(fpos, s.views.lap_end))
+                              - s.row_offset),
+                blocks_fetched=int(qcar.finish_blocks_fetched),
+                blocks_skipped_active=int(qcar.finish_skipped_active),
+                blocks_skipped_static=int(qcar.finish_skipped_static),
+                bitmap_probes=int(qcar.finish_probes),
+                rounds=int(qcar.finish_rounds),
+                wall_time_s=time.perf_counter() - self._t0[id(qc)],
+                stopped_early=bool(qcar.stopped_early))
+            newly.append(qc.q)
         return newly
 
 

@@ -184,7 +184,7 @@ def scenario_last_block_start():
     """A scan that starts at the last block: every round's window wraps
     from block nb - 1 to block 0 at its first lane (probe on, exhaustion
     so every block is processed), bitwise on exactly-representable data,
-    for the query loop and for the pass loop."""
+    through ``FastFrame.run`` and through ``FrameServer``."""
     sc = _integer_scramble()
     nb = sc.n_blocks
     q = AggQuery(agg="avg", column="v", group_by="g",
@@ -428,6 +428,33 @@ def scenario_cadence_server_pass():
         assert_sharded_matches_oracle(r_k, r_1)
 
 
+def scenario_cadence_slot_queries_finish_apart():
+    """One slot without the probe holding two ungrouped queries that stop
+    on different rounds, through the cadence pass loop (merge_every 2):
+    the slot scans on after the first query stops. Each query's answer
+    is bitwise its solo run at the same cadence: the slot selects on its
+    static verdicts whoever is in it, and a query answers from the merge
+    that confirmed its stop."""
+    sc = flights_scramble()
+    queries = [
+        AggQuery(agg="avg", column="dep_delay",
+                 stop=AbsoluteWidth(eps=30.0), delta=1e-9),
+        AggQuery(agg="avg", column="dep_delay",
+                 stop=AbsoluteWidth(eps=8.0), delta=1e-9),
+    ]
+
+    def frame():
+        return FastFrame(sc, EngineConfig(shard_rows=True, merge_every=2,
+                                          **CFG))
+
+    served = FrameServer(frame()).run_batch(queries, start_block=0, seed=1)
+    for q, r in zip(queries, served):
+        assert_sharded_matches_oracle(
+            r, frame().run(q, start_block=0, seed=1), bitwise_ci=True)
+    assert all(r.stopped_early for r in served)
+    assert served[0].rounds < served[1].rounds
+
+
 ALL = [
     scenario_groupby_topk,
     scenario_groupby_threshold_2d_mesh,
@@ -444,4 +471,5 @@ ALL = [
     scenario_cadence_exhaustion,
     scenario_cadence_early_stop,
     scenario_cadence_server_pass,
+    scenario_cadence_slot_queries_finish_apart,
 ]

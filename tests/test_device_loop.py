@@ -176,11 +176,18 @@ def test_device_loop_taint_propagates_out_of_while_loop(sampling):
     assert r_d.tainted[0] and not r_d.tainted[1]
 
 
-def test_device_loop_serve_pass_matches_host_pass(sc):
-    """The multi-query pass loop (shared cursor, per-slot folds,
-    finish-time snapshots recorded in the carry) must reproduce the host
-    pass for every query of a mixed batch."""
-    queries = [
+# A slot without the probe holding two ungrouped queries that stop on
+# different rounds: the slot scans on after the first one finishes, so
+# its intervals and snapshots must stay as they were when it finished.
+UNGROUPED_SLOT = [
+    AggQuery(agg="avg", column="dep_delay", stop=AbsoluteWidth(eps=30.0),
+             delta=1e-9),
+    AggQuery(agg="avg", column="dep_delay", stop=AbsoluteWidth(eps=8.0),
+             delta=1e-9),
+]
+
+SERVED_BATCHES = {
+    "mixed": [
         AggQuery(agg="avg", column="dep_delay", group_by="origin",
                  stop=TopKSeparated(k=2), delta=1e-9),
         AggQuery(agg="avg", column="dep_delay", group_by="origin",
@@ -192,7 +199,18 @@ def test_device_loop_serve_pass_matches_host_pass(sc):
         AggQuery(agg="avg", column="dep_delay", bounder="anderson_dkw",
                  rangetrim=False, stop=AbsoluteWidth(eps=30.0),
                  delta=1e-9),
-    ]
+    ],
+    "ungrouped-slot": UNGROUPED_SLOT,
+}
+
+
+@pytest.mark.parametrize("batch", sorted(SERVED_BATCHES))
+def test_device_loop_serve_pass_matches_host_pass(sc, batch):
+    """The multi-query pass loop (per-slot cursors and folds,
+    finish-time snapshots recorded in the carry) must reproduce the host
+    pass for every query of a mixed batch, and of one slot whose queries
+    finish on different rounds."""
+    queries = SERVED_BATCHES[batch]
     kw = dict(round_blocks=16, lookahead_blocks=64, hist_bins=256)
     res_d = FrameServer(FastFrame(
         sc, EngineConfig(device_loop=True, **kw))).run_batch(
@@ -202,11 +220,38 @@ def test_device_loop_serve_pass_matches_host_pass(sc):
         queries, start_block=0, seed=1)
     for r_d, r_h in zip(res_d, res_h):
         assert_device_matches_host(r_d, r_h)
+    if batch == "ungrouped-slot":
+        assert all(r.stopped_early for r in res_d)
+        assert res_d[0].rounds < res_d[1].rounds
+
+
+def test_run_drives_a_one_slot_pass(sc, monkeypatch):
+    """``FastFrame.run``'s device path is the pass loop of one slot
+    holding one query."""
+    from repro.kernels import fused_scan as kfused
+
+    built = []
+    build = kfused.build_pass_loop
+
+    def spied(**kw):
+        built.append(kw)
+        return build(**kw)
+
+    monkeypatch.setattr(kfused, "build_pass_loop", spied)
+    q = AggQuery(agg="avg", column="dep_delay", group_by="origin",
+                 stop=ThresholdSide(threshold=0.0), delta=1e-9)
+    FastFrame(sc, EngineConfig(device_loop=True, round_blocks=16,
+                               lookahead_blocks=64)).run(
+        q, seed=1, start_block=0)
+    assert len(built) == 1
+    assert len(built[0]["slot_specs"]) == 1
+    assert [len(fns) for fns in built[0]["refresh_fns"]] == [1]
+    assert built[0]["slot_specs"][0].probe
 
 
 def test_device_loop_served_singleton_matches_run(sc):
     """A served singleton through the device pass loop stays identical to
-    ``FastFrame.run`` through the device query loop."""
+    ``FastFrame.run``, the same loop driven as a one-slot pass."""
     q = AggQuery(agg="avg", column="dep_delay", group_by="origin",
                  stop=ThresholdSide(threshold=0.0), delta=1e-9)
     cfg = dict(device_loop=True, round_blocks=16, lookahead_blocks=64)

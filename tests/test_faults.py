@@ -622,7 +622,7 @@ def test_probe_coresidency_bitwise(ds, scramble):
     """Pin the documented contract (docs/serving.md): a GROUP BY probe
     slot sharing a pass with other queries is BITWISE identical to its
     solo run — every slot advances its own cursor with its own activity
-    flags, so a co-resident's engagement bits never perturb the probe's
+    flags, so a co-resident slot's queries never perturb the probe's
     selection. (Before per-slot cursors this was only promised sound,
     not bitwise.)"""
     probe = AggQuery(agg="avg", column="dep_delay", group_by="airline",

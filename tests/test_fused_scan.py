@@ -299,8 +299,9 @@ def _slice_round(bufs, presence, presence_total, c, aw, *, nb, window,
     return c._replace(pos=scan[5], **acct)
 
 
-# (nb, window, start, pos, anchor): anchor None is the query loop (cursor
-# limit nb), else a pass-loop slot whose lap is [anchor, anchor + nb)
+# (nb, window, start, pos, anchor): anchor None is a slot whose lap starts
+# at 0, as FastFrame.run's (cursor limit nb, no wrap), else a slot whose
+# lap is [anchor, anchor + nb)
 _WINDOWS = {
     "start-0": (300, 256, 0, 0, None),
     "start-last": (300, 256, 299, 0, None),

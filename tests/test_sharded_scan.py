@@ -42,6 +42,7 @@ def _scenarios():
     "scenario_cadence_superset_sync", "scenario_cadence_merge_confirm",
     "scenario_cadence_exhaustion", "scenario_cadence_early_stop",
     "scenario_cadence_server_pass",
+    "scenario_cadence_slot_queries_finish_apart",
 ])
 def test_sharded_scenario(name, x64_module):
     getattr(_scenarios(), name)()

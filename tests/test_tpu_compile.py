@@ -102,6 +102,16 @@ def test_active_blocks_compiles(one_chip, x64_mode, groups):
         _shape(one_chip, (words,), jnp.uint32))
 
 
+def _solo_loop(frame, q, slot, qci):
+    """``FastFrame.run``'s device loop of ``q`` (one slot, one query,
+    with the probe) from scan start 0, and its first carry."""
+    loop = engine._DeviceLoop(frame, q, slot, qci, probe=True,
+                              lookahead=1024, max_rounds=100_000)
+    order = np.arange(frame.scramble.n_blocks)
+    _, carry = loop.start(slot, qci, 0, np.cumsum(frame._valid_counts[order]))
+    return loop, carry
+
+
 @pytest.mark.parametrize("bounder", ["bernstein", "anderson_dkw"])
 def test_query_loop_compiles(one_chip, x64, bounder):
     """The f64 device round loop of one GROUP BY query with the bitmap
@@ -114,11 +124,7 @@ def test_query_loop_compiles(one_chip, x64, bounder):
     q = fq.f_q9(bounder=bounder, rangetrim=bounder == "bernstein")
     slot = engine._ScanViews(frame, q)
     qci = engine._QueryIntervals(frame, q, slot)
-    loop = engine._DeviceLoop(frame, q, slot, qci, probe=True,
-                              lookahead=1024, max_rounds=100_000)
-    order = np.arange(sc.n_blocks)
-    loop.set_order(0, np.cumsum(frame._valid_counts[order]))
-    carry = loop.init_carry(slot, qci)
+    loop, carry = _solo_loop(frame, q, slot, qci)
     as_shapes = lambda tree: jax.tree.map(
         lambda x: _shape(one_chip, x.shape, x.dtype), tree)
     compiled = loop._chunk_fn.lower(as_shapes(loop.bufs),
@@ -156,15 +162,11 @@ def test_query_loop_names_scopes_and_kernels(one_chip, x64, bounder,
     q = fq.f_q9(bounder=bounder, rangetrim=bounder == "bernstein")
     slot = engine._ScanViews(frame, q)
     qci = engine._QueryIntervals(frame, q, slot)
-    loop = engine._DeviceLoop(frame, q, slot, qci, probe=True,
-                              lookahead=1024, max_rounds=100_000)
-    order = np.arange(sc.n_blocks)
-    loop.set_order(0, np.cumsum(frame._valid_counts[order]))
+    loop, carry = _solo_loop(frame, q, slot, qci)
     as_shapes = lambda tree: jax.tree.map(
         lambda x: _shape(one_chip, x.shape, x.dtype), tree)
     text = loop._chunk_fn.lower(as_shapes(loop.bufs),
-                                as_shapes(loop.init_carry(slot, qci))
-                                ).as_text(debug_info=True)
+                                as_shapes(carry)).as_text(debug_info=True)
     for scope in fused_scan.SCOPES:
         assert f"/{scope}/" in text, scope
     for name in kernels:

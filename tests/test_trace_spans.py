@@ -4,9 +4,10 @@ named scopes of the device round loops.
 Spans are ``jax.profiler.TraceAnnotation`` events, recorded here under a
 CPU profiler trace and read back with ``jax.profiler.ProfileData``.
 Scopes are ``jax.named_scope`` components of every HLO ``op_name``,
-checked in the lowered text of each round loop the engine builds: the
-single-query loop, its collective-cadence body (over a one-device mesh)
-and the serving pass loop.
+checked in the lowered text of the one round loop the engine builds, as
+``FastFrame.run`` drives it (one slot, one query), as its
+collective-cadence body (over a one-device mesh) and as the serving pass
+loop.
 """
 
 import re
@@ -110,7 +111,7 @@ def _scopes_in(text: str) -> set:
 
 
 def test_query_loop_carries_every_scope(scramble, monkeypatch):
-    texts = _spy(monkeypatch, "build_query_loop")
+    texts = _spy(monkeypatch, "build_pass_loop")
     _frame(scramble, device_loop=True).run(fq.f_q9(), start_block=0)
     assert _scopes_in(texts[0]) == set(kfused.SCOPES)
 
@@ -127,8 +128,8 @@ def _sharded_lowered(scramble, q, merge_every: int):
                               lookahead=1024, max_rounds=100_000,
                               shards=shards)
     order = np.arange(scramble.n_blocks)
-    loop.set_order(0, np.cumsum(frame._valid_counts[order]))
-    return loop._chunk_fn.lower(loop.bufs, loop.init_carry(slot, qci))
+    _, carry = loop.start(slot, qci, 0, np.cumsum(frame._valid_counts[order]))
+    return loop._chunk_fn.lower(loop.bufs, carry)
 
 
 def test_cadence_loop_carries_every_scope(scramble):
@@ -173,16 +174,16 @@ def test_pass_loop_carries_every_scope(scramble, monkeypatch):
 _SCATTER_OP_NAME = re.compile(r' scatter\(.*op_name="([^"]*)"')
 
 
-@pytest.mark.parametrize("build_fn", ["build_query_loop", "build_pass_loop"])
-def test_gather_scope_holds_no_scatter(scramble, monkeypatch, build_fn):
+@pytest.mark.parametrize("driver", ["run", "batch"])
+def test_gather_scope_holds_no_scatter(scramble, monkeypatch, driver):
     """The round's selected blocks are compacted without a scatter: the
     TPU runs a scatter's colliding updates one by one, and
     ``jnp.nonzero``'s scatter-add over the window cost more per round
-    than the fold. No HLO scatter of either round loop carries the
-    ``gather`` scope."""
-    texts = _spy(monkeypatch, build_fn, dialect="hlo")
+    than the fold. No HLO scatter of the round loop, as ``FastFrame.run``
+    or ``FrameServer`` drives it, carries the ``gather`` scope."""
+    texts = _spy(monkeypatch, "build_pass_loop", dialect="hlo")
     frame = _frame(scramble, device_loop=True)
-    if build_fn == "build_query_loop":
+    if driver == "run":
         frame.run(fq.f_q9(), start_block=0)
     else:
         FrameServer(frame).run_batch([fq.f_q9(), fq.f_q2(8.0)],
@@ -196,14 +197,14 @@ def test_gather_scope_holds_no_scatter(scramble, monkeypatch, build_fn):
 _GATHER_OP_NAME = re.compile(r' gather\(.*op_name="([^"]*)"')
 
 
-@pytest.mark.parametrize("loop", ["build_query_loop", "build_pass_loop",
-                                  "cadence"])
+@pytest.mark.parametrize("loop", ["run", "batch", "cadence"])
 def test_select_and_account_read_the_window_as_slices(scramble, monkeypatch,
                                                       loop):
     """Every scan order is a rotation, so each window read of a block-id
     buffer is one ``dynamic_slice`` and the ``processed`` update one
-    ``dynamic_update_slice``: no HLO gather of the query loop, its
-    collective-cadence body or the pass loop carries the ``select`` or
+    ``dynamic_update_slice``: no HLO gather of the round loop as
+    ``FastFrame.run`` drives it, its collective-cadence body or the
+    serving pass carries the ``select`` or
     ``account`` scope, and no scatter carries ``account``. The TPU runs a
     window-long gather at tens of GB/s and a scatter one update at a
     time."""
@@ -212,9 +213,10 @@ def test_select_and_account_read_the_window_as_slices(scramble, monkeypatch,
         texts = [low.as_text(dialect="hlo", debug_info=True),
                  low.compile().as_text()]
     else:
-        texts = _spy(monkeypatch, loop, dialect="hlo", compiled=True)
+        texts = _spy(monkeypatch, "build_pass_loop", dialect="hlo",
+                     compiled=True)
         frame = _frame(scramble, device_loop=True)
-        if loop == "build_query_loop":
+        if loop == "run":
             frame.run(fq.f_q9(), start_block=0)
         else:
             FrameServer(frame).run_batch([fq.f_q9(), fq.f_q2(8.0)],
